@@ -1,7 +1,7 @@
 """paddle_tpu — a TPU-native deep-learning framework with PaddlePaddle's
 capability surface, built from scratch on JAX/XLA/Pallas/pjit.
 
-Blueprint: /root/repo/SURVEY.md (structural analysis of the reference at
+Blueprint: SURVEY.md (structural analysis of the reference at
 /root/reference). The engine is XLA: ops are jax compositions + Pallas
 kernels, autograd is a define-by-run tape over jax.vjp closures, to_static
 compiles whole train steps with jax.jit, and distributed training is
@@ -11,23 +11,12 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-import os as _os
-
 import jax as _jax
 
 # Full dtype surface (int64 labels, float64 CPU math — paddle defaults int64
 # for integer tensors). Framework default float dtype stays float32; creation
 # ops always pass explicit dtypes, so x64 never leaks into TPU programs.
 _jax.config.update("jax_enable_x64", True)
-
-# Honor JAX_PLATFORMS even when a site hook imported jax before us (env is
-# read once at jax import; re-apply so `JAX_PLATFORMS=cpu python app.py`
-# behaves as documented regardless of interpreter-startup hooks).
-if _os.environ.get("JAX_PLATFORMS"):
-    try:
-        _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
-    except Exception:
-        pass
 
 from .core import dtype as _dtype_mod
 from .core.dtype import (

@@ -159,7 +159,7 @@ def _walk_eqns(jaxpr, mult=1.0):
 #: higher-order prims whose own eqn must not ALSO be charged when the
 #: walk descends into the body (the body already carries the cost)
 _HOP_TRANSPARENT = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
+    "jit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr", "scan",
     "while", "cond", "shard_map", "named_call"})
 

@@ -63,11 +63,13 @@ def norm_vmem_bytes(block_rows: int, hidden: int, itemsize: int = 2,
                     fused_add: bool = False) -> int:
     """Working-set estimate for one grid step of the fused norm kernels
     (ops/pallas_norm): x (+residual) input blocks and y (+summed stream)
-    outputs at [block_rows, Hp] in the caller's dtype, one f32 compute
-    copy, parameter rows and per-row stats."""
+    outputs at [block_rows, Hp] in the caller's dtype, each DOUBLE-buffered
+    by the grid pipeline, one f32 compute copy, parameter rows and per-row
+    stats. (At 256 rows, H=4096, bf16, fused add this gives 20.3 MiB; the
+    chip's compiler reports 16.25 MiB — the estimate errs high.)"""
     hp = _ceil128(hidden)
     n_stream = 2 if fused_add else 1
-    io = n_stream * 2 * block_rows * hp * itemsize      # in + out
+    io = 2 * n_stream * 2 * block_rows * hp * itemsize  # (in + out) x 2 bufs
     f32_work = block_rows * hp * 4                      # xf accumulation
     params = 2 * 8 * hp * itemsize                      # w/b lane blocks
     stats = 2 * block_rows * 128 * 4                    # rstd/mean
@@ -211,11 +213,15 @@ def audit_decode_config(head_dim: int, block_size: int, group: int = 16,
 def audit_norm_config(hidden_size: int, itemsize: int = 2,
                       block_rows: int | None = None, limit_mb=None,
                       loc: str = "pallas-norm-config") -> list[Finding]:
-    """D5 for the norm kernels' static launch config at a model width."""
-    from ..ops.pallas_norm import DEFAULT_BLOCK_ROWS
+    """D5 for the norm kernels' launch config at a model width: the row
+    block the kernels size for themselves (pallas_norm.block_rows), or an
+    explicit `block_rows` to be judged."""
+    from ..ops.pallas_norm import block_rows as kernel_rows
 
     limit = _limit_bytes(limit_mb)
-    br = block_rows or DEFAULT_BLOCK_ROWS
+    # rows=1<<30: a tensor tall enough that only the width bounds the block
+    br = block_rows or kernel_rows(1 << 30, _ceil128(hidden_size), 4,
+                                   itemsize)
     est = norm_vmem_bytes(br, hidden_size, itemsize, fused_add=True)
     if est <= 0.8 * limit:
         return []

@@ -4,7 +4,9 @@ The hot runtime pieces that are C++ in the reference stay C++ here
 (SURVEY §2.1): csrc/*.cpp are compiled with g++ on first use into cached
 shared objects and bound via ctypes (pybind11 isn't vendored in this
 image). Every native component has a pure-Python fallback — load() returns
-None when the toolchain is unavailable and callers degrade gracefully.
+None when the toolchain is unavailable and callers degrade gracefully; the
+reason is warned once and kept in `build_errors` (chip_smoke.py prints it),
+so a machine without a compiler says so instead of just running slower.
 """
 from __future__ import annotations
 
@@ -21,9 +23,23 @@ _BUILD = os.path.join(_CSRC, "_build")
 # the atomic-rename cache), so this lock is NOT marked hot
 _lock = lockdep.make_lock("core.native._lock")
 _cache: dict[str, object] = {}    # guarded-by: _lock
+#: component -> why its build or load failed here
+build_errors: dict[str, str] = {}  # guarded-by: _lock
 
 
-def _compile(name: str) -> str | None:
+def _failed(name: str, e: Exception) -> None:  # requires-lock: _lock
+    import warnings
+
+    # a failed g++ run carries its last words in CalledProcessError.stderr
+    tail = (getattr(e, "stderr", None) or b"").decode(
+        errors="replace").strip().splitlines()[-1:]
+    build_errors[name] = f"{type(e).__name__}: {e}" + \
+        (f" ({tail[0]})" if tail else "")
+    warnings.warn(f"native component '{name}' unavailable — "
+                  f"{build_errors[name]}; using the pure-Python fallback")
+
+
+def _compile(name: str) -> str | None:  # requires-lock: _lock
     src = os.path.join(_CSRC, f"{name}.cpp")
     if not os.path.exists(src):
         return None
@@ -39,7 +55,8 @@ def _compile(name: str) -> str | None:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)  # atomic: concurrent builders race safely
         return so
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        _failed(name, e)
         return None
 
 
@@ -54,8 +71,8 @@ def load(name: str):
         if so is not None:
             try:
                 lib = ctypes.CDLL(so)
-            except OSError:
-                lib = None
+            except OSError as e:
+                _failed(name, e)
         _cache[name] = lib
         return lib
 

@@ -23,10 +23,9 @@ stays UNMODIFIED:
      (`_audit_mesh`) so `analysis.audit_compiled` judges D9 coverage
      without the caller re-declaring it.
 
-CPU-virtual fallback: when the host exposes fewer devices than the
-config needs, `partition` degrades to an UNSHARDED `to_static` step with
-a named warning — one config runs from laptop to pod (SNIPPETS.md [1]
-pjit_with_cpu_fallback, lifted to the whole step).
+A host that exposes fewer devices than the config needs is an error
+(`MeshConfig.build_mesh` raises): numbers from an unsharded run say
+nothing about the sharded config.
 """
 from __future__ import annotations
 
@@ -227,9 +226,8 @@ def maybe_sep_attention(query, key, value, is_causal, attn_mask=None,
         impl = "ring"               # ulysses needs heads % sep == 0
     from ..meta_parallel.ring_attention import (ring_attention,
                                                 ulysses_attention)
-    from jax.experimental.shard_map import shard_map
-
     import numpy as np
+    from jax import shard_map
 
     sizes = config.axis_sizes
     baxes = tuple(a for a in config.batch_axes if sizes.get(a, 1) > 1)
@@ -244,7 +242,7 @@ def maybe_sep_attention(query, key, value, is_causal, attn_mask=None,
             lambda a, b_, c: kernel(a, b_, c, axis_name="sep",
                                     causal=is_causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+            check_vma=False)
         return fn(q, k, v)
 
     return op_call(f, query, key, value, name="sep_attention", n_diff=3)
@@ -268,20 +266,8 @@ def partition(fn, config: MeshConfig, *, model=None, static=True,
     Returns the compiled step with `.plan`, `.mesh`, `.config` and
     `_audit_mesh` attached (analysis.audit_compiled picks the mesh up
     automatically)."""
-    mesh = config.maybe_mesh()
-    plan = None
-    if mesh is None:
-        from ...obs.logging import get_logger
-
-        get_logger(__name__).warning(
-            f"partition: MeshConfig {config.describe()} needs "
-            f"{config.num_devices} devices, "
-            f"{len(jax.devices())} visible — running UNSHARDED "
-            "(cpu-virtual fallback); numbers from this run say nothing "
-            "about the sharded config",
-            key=f"partition-fallback:{config.describe()}", also_warn=True)
-    elif model is not None:
-        plan = shard_model(model, config, mesh=mesh)
+    mesh = config.build_mesh()
+    plan = None if model is None else shard_model(model, config, mesh=mesh)
 
     def _arg_spec(i, shape, ndim):
         if arg_specs and i in arg_specs:
@@ -301,8 +287,6 @@ def partition(fn, config: MeshConfig, *, model=None, static=True,
         return out
 
     def wrapped(*args, **kwargs):
-        if mesh is None:
-            return fn(*args, **kwargs)
         with _activate(config, mesh):
             return fn(*args, **kwargs)
 
@@ -311,13 +295,10 @@ def partition(fn, config: MeshConfig, *, model=None, static=True,
         from ...jit.api import to_static
 
         out = to_static(wrapped, donate_buffers=donate_buffers,
-                        in_shardings=None if mesh is None
-                        else _leaf_shardings,
+                        in_shardings=_leaf_shardings,
                         **to_static_kwargs)
     else:
         def eager(*args, **kwargs):
-            if mesh is None:
-                return fn(*args, **kwargs)
             # same leaf enumeration as the static path's in_shardings
             # resolver (jit._flatten order over (args, kwargs)), so
             # arg_specs indexes mean the same thing either way and

@@ -76,13 +76,11 @@ def restore_partitioned(root, model=None, optimizer=None, config=None,
     info = ckpt.manifest_shardings(r.manifest)
     plan = None
     if config is not None and model is not None:
-        mesh = config.maybe_mesh()
-        if mesh is not None:
-            # set_value swapped replicated host buffers into the params;
-            # placement is re-derived from the RESTORING config — this
-            # IS the reshard (v2's recorded specs are provenance, not a
-            # constraint on where the bytes may live next)
-            plan = shard_model(model, config, mesh=mesh)
+        # set_value swapped replicated host buffers into the params;
+        # placement is re-derived from the RESTORING config — this IS
+        # the reshard (v2's recorded specs are provenance, not a
+        # constraint on where the bytes may live next)
+        plan = shard_model(model, config)
     if info["version"] < 2:
         reason = "manifest_v1_replicated"
     elif plan is not None:

@@ -16,11 +16,9 @@ per-model mp_layers); here one frozen dataclass names the mesh axes
             attention-time exchange rides ring_attention /
             ulysses_attention (meta_parallel/ring_attention.py)
 
-and their degrees. `build_mesh()` materializes the jax Mesh;
-`maybe_mesh()` is the CPU-virtual fallback: a host with fewer devices
-than the config asks for degrades to an unpartitioned run (same config,
-same code path, zero sharding) instead of crashing — the
-pjit_with_cpu_fallback behavior, per-config.
+and their degrees. `build_mesh()` materializes the jax Mesh and raises
+on a host with fewer devices than the config asks for: a step that was
+asked to shard never runs unsharded in silence.
 """
 from __future__ import annotations
 
@@ -126,17 +124,6 @@ class MeshConfig:
                 "virtual platform (--xla_force_host_platform_device_count)")
         dims = [self.axis_sizes[n] for n in self.axis_names]
         return Mesh(np.array(devs[:need]).reshape(dims), self.axis_names)
-
-    def maybe_mesh(self):
-        """CPU-virtual fallback (SNIPPETS.md [1] pjit_with_cpu_fallback,
-        per config): the Mesh when the host can carry it, else None —
-        `partition()` then runs the step unsharded with a named note so
-        ONE config works from a laptop to the pod."""
-        import jax
-
-        if self.num_devices > len(jax.devices()):
-            return None
-        return self.build_mesh()
 
     # ------------------------------------------------------ serialization
     def to_dict(self) -> dict:

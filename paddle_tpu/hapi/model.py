@@ -90,8 +90,8 @@ class Model:
     def _apply_mesh(self, mesh):
         """Place the network per a declarative MeshConfig (ZeRO-3 fsdp +
         tensor axes from the logical-axis rules); training inputs get
-        batch-sharded in train_batch. CPU-virtual fallback: a host too
-        small for the config trains unsharded with a named warning."""
+        batch-sharded in train_batch. A host too small for the config
+        raises (MeshConfig.build_mesh)."""
         from ..distributed.partitioner import MeshConfig, shard_model
 
         if not isinstance(mesh, MeshConfig):
@@ -99,17 +99,7 @@ class Model:
                 f"mesh must be a distributed.partitioner.MeshConfig, got "
                 f"{type(mesh).__name__}")
         self._mesh_config = mesh
-        m = mesh.maybe_mesh()
-        if m is None:
-            import warnings
-
-            warnings.warn(
-                f"Model.prepare/fit(mesh=...): MeshConfig "
-                f"{mesh.describe()} needs {mesh.num_devices} devices — "
-                "running unsharded (cpu-virtual fallback)")
-            self._mesh_plan = None
-            return
-        self._mesh_plan = shard_model(self.network, mesh, mesh=m)
+        self._mesh_plan = shard_model(self.network, mesh)
 
     def _mesh_place_input(self, t):
         """Shard one training input onto the prepared mesh — the SAME

@@ -523,15 +523,15 @@ class CompiledFunction:
         # partitioner, so call 2 no longer matches the replicated
         # shardings call 1 compiled for — jit would transparently
         # recompile, the AOT executable raises. Demote to the jit path
-        # on that mismatch only (ValueError "input sharding(s) does not
-        # match" / TypeError "Argument types differ", both raised at
+        # on that mismatch only (ValueError "compiled for input shardings
+        # that disagree" / TypeError "Argument types differ", both raised at
         # argument validation BEFORE execution or donation, so the
         # retry re-reads intact buffers); genuine runtime errors
         # propagate — retrying them would double host side effects and
         # mask the real failure behind donated-buffer errors.
         if _aot is not None:
             _MISMATCH_MARKS = (
-                "input sharding(s) does not match",
+                "that disagree with the",
                 "for which this computation was compiled",
             )
 

@@ -66,8 +66,10 @@ def _pallas_available() -> bool:
 def _use_pallas(q):
     if FORCE_PALLAS is not None:
         return FORCE_PALLAS
+    from ...ops._pallas_common import auto_partitioned
+
     return (jax.default_backend() == "tpu" and q.shape[1] >= 128
-            and _pallas_available())
+            and _pallas_available() and not auto_partitioned())
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,

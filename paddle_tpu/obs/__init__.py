@@ -39,14 +39,15 @@ within 2% tok/s of uninstrumented steady-state decode, PERF.md round 11);
 from __future__ import annotations
 
 from .costs import (ProgramCost, audit_cost_regressions, clear_ledger,
-                    extract_cost, ledger, peak_gbps, record_program,
+                    extract_cost, ledger, record_program,
                     reset_exec_stats, roofline_rows, write_baseline)
 from .flight import FlightRecorder, RequestFlight, validate_trace
-from .goodput import (GoodputLedger, audit_train_steps, peak_tflops)
+from .goodput import GoodputLedger, audit_train_steps
 from .http import MetricsServer, serve_metrics, shared_server
 from .logging import ObsLogger, get_logger
 from .metrics import (DEFAULT_BUCKETS, OVERFLOW, Counter, Gauge, Histogram,
                       Registry, dump_registry, log_event)
+from .peaks import DEVICE_PEAKS, device_peaks, peak_gbps, peak_tflops
 from .trace import (capture_trace, clear_spans, span, span_events,
                     step_span)
 from .train_flight import (StepFlight, TrainFlightRecorder,
@@ -93,8 +94,9 @@ __all__ = [
     "serve_metrics", "MetricsServer", "shared_server",
     "FlightRecorder", "RequestFlight", "validate_trace",
     "TrainFlightRecorder", "StepFlight", "validate_train_trace",
-    "GoodputLedger", "audit_train_steps", "peak_tflops",
+    "GoodputLedger", "audit_train_steps",
+    "DEVICE_PEAKS", "device_peaks", "peak_gbps", "peak_tflops",
     "ProgramCost", "record_program", "ledger", "clear_ledger",
-    "reset_exec_stats", "roofline_rows", "extract_cost", "peak_gbps",
+    "reset_exec_stats", "roofline_rows", "extract_cost",
     "write_baseline", "audit_cost_regressions",
 ]

@@ -45,13 +45,7 @@ import time
 from collections import deque
 
 from ..core.flags import flag
-
-#: per-backend peak-compute defaults (bf16 TFLOP/s) when
-#: FLAGS_obs_peak_tflops is 0 — the off-chip figure makes the smoke-test
-#: plumbing produce finite gauges, not quotable numbers (same contract
-#: as obs/costs.py PEAK_GBPS_FALLBACK)
-PEAK_TFLOPS_DEFAULTS = {"tpu": 275.0}
-PEAK_TFLOPS_FALLBACK = 0.5
+from .peaks import peak_tflops
 
 #: goodput categories (the label set of train_goodput_seconds_total)
 CATEGORIES = ("productive", "data_wait", "compile", "ckpt", "replay")
@@ -62,27 +56,6 @@ MFU_HISTORY = 256
 #: train_mfu gets the same widened label cap as roofline_utilization —
 #: a step dispatching several compiled programs is legitimate
 _GAUGE_LABEL_CAP = 256
-
-
-#: (flag_value, resolved) memo — observe_step runs per train step; the
-#: backend never changes mid-process and the flag rarely does
-_peak_memo: tuple = (None, None)
-
-
-def peak_tflops() -> float:
-    global _peak_memo
-
-    v = float(flag("FLAGS_obs_peak_tflops"))
-    if _peak_memo[0] == v:
-        return _peak_memo[1]
-    if v > 0:
-        out = v
-    else:
-        from .trace import _backend
-
-        out = PEAK_TFLOPS_DEFAULTS.get(_backend(), PEAK_TFLOPS_FALLBACK)
-    _peak_memo = (v, out)
-    return out
 
 
 class GoodputLedger:

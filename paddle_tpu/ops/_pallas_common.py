@@ -14,16 +14,7 @@ from __future__ import annotations
 import contextlib
 
 import jax
-
-try:  # pltpu imports fail cleanly on backends without TPU support
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-try:  # jax >= 0.5 exposes the x64 context manager at top level
-    _enable_x64 = jax.enable_x64
-except AttributeError:  # pragma: no cover — 0.4.x
-    from jax.experimental import enable_x64 as _enable_x64
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401 — re-exported
 
 
 def interpret() -> bool:
@@ -33,7 +24,21 @@ def interpret() -> bool:
 
 def x64_guard():
     """x64-off context for REAL-TPU traces only (see module docstring)."""
-    return contextlib.nullcontext() if interpret() else _enable_x64(False)
+    return contextlib.nullcontext() if interpret() else jax.enable_x64(False)
+
+
+def auto_partitioned() -> bool:
+    """True while a `partition()` step over more than one device is
+    traced or run. GSPMD cannot split a Mosaic kernel — the chip's
+    compiler refuses the program with "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" — so
+    the routers keep such a step on the XLA compositions, which GSPMD can
+    partition. (The sep-axis ring/ulysses attention calls its kernel
+    INSIDE a shard_map and is not affected.)"""
+    from ..distributed.partitioner.api import active_config
+
+    ctx = active_config()
+    return ctx is not None and ctx[1].size > 1
 
 
 def ceil_to(x: int, m: int) -> int:

@@ -22,8 +22,7 @@ TPU-native design (NOT a kernel translation):
   - GQA packing: all `H_q/H_kv` query heads sharing a KV head ride ONE
     [group, D] tile (padded to the sublane minimum), so the whole group's
     scores come from one MXU pass per cache block. Decode is pure HBM
-    bandwidth (~103 GB/s effective on this target, PERF.md round 4):
-    every cache byte is read exactly once per step.
+    bandwidth: every cache byte is read exactly once per step.
   - Optional int8 KV: the cache stores int8 with ONE f32 scale per block
     (text/paged_cache.py maintains them by block requantization on
     append); the kernel reads per-(seq, page) scales from scalar-prefetch
@@ -90,7 +89,9 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, has_scale,
     n_p = pl.num_programs(2)
 
     def unpack(p):
-        lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
+        # widened first: v5e's Mosaic legalizes no shift on vector<i8>
+        p = p.astype(jnp.int32)
+        lo = jnp.right_shift(jnp.left_shift(p, 28), 28)
         hi = jnp.right_shift(p, 4)
         return jnp.concatenate([lo, hi], axis=0)       # [bs, D]
 
@@ -224,7 +225,7 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((s_n, hkv, gp, d), q.dtype)],
-        interpret=_interpret(),
+        interpret=_interpret(), name="paged_decode",
     )(*args, q4, k_cache, v_cache)
     return out[:, :, :g].reshape(s_n, hq, d)
 

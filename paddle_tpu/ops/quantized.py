@@ -144,8 +144,9 @@ def dequant_int4(packed, scale, k, dtype=jnp.float32):
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, k):
     """One N-tile: unpack the packed int4 block and scale INSIDE the kernel
     so the packed bytes are the only HBM weight traffic for this tile."""
-    p = w_ref[...]                                     # [K/2, bn] int8
-    lo = jnp.right_shift(jnp.left_shift(p, 4), 4)
+    # widened first: v5e's Mosaic legalizes no shift on vector<i8>
+    p = w_ref[...].astype(jnp.int32)                   # [K/2, bn]
+    lo = jnp.right_shift(jnp.left_shift(p, 28), 28)
     hi = jnp.right_shift(p, 4)
     q = jnp.concatenate([lo, hi], axis=0)[:k]          # [K, bn]
     x = x_ref[...].astype(jnp.float32)                 # [Mp, K]
@@ -182,7 +183,7 @@ def _qmm_x32(x, packed, scale, k):
         ],
         out_specs=pl.BlockSpec((mp, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
-        interpret=_interpret(),
+        interpret=_interpret(), name="quant_matmul_int4",
     )(x, packed, s2)
     return out[:m]
 

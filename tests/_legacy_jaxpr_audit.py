@@ -276,11 +276,11 @@ def _chase_to_mul(jaxpr, idx, var, depth=6):
 
 
 #: consumer plumbing between decode scores and their softmax (scale
-#: divide, length-mask select/where — possibly wrapped in a pjit — dtype
+#: divide, length-mask select/where — possibly wrapped in a jit — dtype
 #: widening); producer plumbing between the cache gather and the score
 #: matmul (layout + GQA head repeat)
 _SOFTMAX_THROUGH = _TRANSPARENT | {"div", "mul", "sub", "max", "min",
-                                   "select_n", "pjit", "stop_gradient",
+                                   "select_n", "jit", "stop_gradient",
                                    "custom_jvp_call",
                                    "custom_jvp_call_jaxpr"}
 _SOFTMAX_ANCHORS = {"reduce_max", "exp"}

@@ -1,0 +1,200 @@
+"""Compile-for-v5e tests: every Pallas kernel of the train and serve main
+paths, at LLaMA-7B widths, handed to the TPU's own compiler for a chip that
+is described, not attached (on-chip-measurement guide, section 2 step 3).
+
+Interpret-mode parity tests cannot see what Mosaic refuses — a lane slice
+off the tiling, an i8 shift v5e does not legalize, a working set over the
+16 MiB scoped-VMEM default. These compiles can, at no chip time. Nothing
+runs: a pass here says the chip's compiler accepts the kernel, never that
+its result is right or fast.
+
+All of them live in THIS file and the topology is described inside a
+module-scoped fixture: only one process may hold the TPU library, so the
+call must not happen at import/collection time, and a second file could
+land on another xdist worker where the fixture would skip in silence.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# LLaMA-2-7B published widths (text/models/llama.py:llama_7b_config)
+HIDDEN, FFN, HEADS, HEAD_DIM = 4096, 11008, 32, 128
+ROWS = 4096                      # tokens per step: b2 x s2048
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / lock held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(monkeypatch, one_chip):
+    """Steer the kernels off the interpreter (the described chip is not the
+    default backend, so `_interpret()` would say True) and keep these
+    compiles out of the persistent cache: an executable for an unattached
+    chip cannot be read back and would warn on the next run. conftest.py
+    pins matmul precision to "highest" for CPU parity; the chip runs the
+    default, and Mosaic refuses an fp32-precision matmul on bf16 operands."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.ops import (_pallas_common, pallas_attention,
+                                pallas_decode, pallas_norm, quantized)
+
+    for mod in (pallas_attention, pallas_decode, pallas_norm, quantized):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(_pallas_common, "interpret", lambda: False)
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_default_matmul_precision)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        compiled = jax.jit(fn).lower(*args).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text, "kernel not in the compiled program"
+        return compiled
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_default_matmul_precision", was[1])
+    cc.reset_cache()
+
+
+def _sum32(x):
+    return jnp.sum(x.astype(jnp.float32))
+
+
+# ------------------------------------------------------------ attention
+
+@pytest.mark.parametrize("d,s", [(128, 2048), (64, 1024)],
+                         ids=["d128_s2048", "d64_s1024"])
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash(for_chip, d, s, bwd):
+    from paddle_tpu.ops.pallas_attention import flash_attention_raw
+
+    def fwd(q, k, v):
+        return flash_attention_raw(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: _sum32(fwd(*a)), argnums=(0, 1, 2))(
+            q, k, v)
+
+    shp = ((2, HEADS, s, d), BF16)
+    for_chip(fwd_bwd if bwd else fwd, shp, shp, shp)
+
+
+def test_flash_varlen(for_chip):
+    from paddle_tpu.ops.pallas_attention import flash_attention_varlen_raw
+
+    shp = ((2, HEADS, 2048, HEAD_DIM), BF16)
+    for_chip(lambda q, k, v, lens: flash_attention_varlen_raw(
+        q, k, v, lens, causal=True), shp, shp, shp, ((2,), jnp.int32))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+def test_paged_decode(for_chip, kv):
+    from paddle_tpu.ops.pallas_decode import paged_decode_attention_raw
+
+    slots, blocks, bs, pages = 8, 512, 16, 128
+    if kv == "int4":
+        bs = 32                               # packed tile holds bs/2 rows
+    cache_dt = BF16 if kv == "bf16" else jnp.int8
+    cache = ((blocks, HEADS, bs // 2 if kv == "int4" else bs, HEAD_DIM),
+             cache_dt)
+    shapes = [((slots, HEADS, HEAD_DIM), BF16), cache, cache,
+              ((slots, pages), jnp.int32), ((slots,), jnp.int32)]
+    if kv == "bf16":
+        fn = paged_decode_attention_raw
+    else:
+        shapes += [((blocks,), jnp.float32)] * 2
+
+        def fn(q, k, v, tab, lens, ks, vs):
+            return paged_decode_attention_raw(q, k, v, tab, lens, ks, vs,
+                                              kv_int4=(kv == "int4"))
+    for_chip(fn, *shapes)
+
+
+# ------------------------------------------------- fused norm/rope/swiglu
+
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_add_rms_norm(for_chip, bwd):
+    from paddle_tpu.ops.pallas_norm import add_rms_norm_raw
+
+    def fwd(x, r, w):
+        return add_rms_norm_raw(x, r, w, 1e-6)
+
+    def fwd_bwd(x, r, w):
+        return jax.grad(lambda *a: sum(map(_sum32, fwd(*a))),
+                        argnums=(0, 1, 2))(x, r, w)
+
+    row = ((ROWS, HIDDEN), BF16)
+    for_chip(fwd_bwd if bwd else fwd, row, row, ((HIDDEN,), BF16))
+
+
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_rms_norm(for_chip, bwd):
+    from paddle_tpu.ops.pallas_norm import rms_norm_raw
+
+    def fwd(x, w):
+        return rms_norm_raw(x, w, 1e-6)
+
+    def fwd_bwd(x, w):
+        return jax.grad(lambda *a: _sum32(fwd(*a)), argnums=(0, 1))(x, w)
+
+    for_chip(fwd_bwd if bwd else fwd, ((ROWS, HIDDEN), BF16),
+             ((HIDDEN,), BF16))
+
+
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_swiglu(for_chip, bwd):
+    from paddle_tpu.ops.pallas_norm import swiglu_raw
+
+    def fwd_bwd(g, u):
+        return jax.grad(lambda *a: _sum32(swiglu_raw(*a)),
+                        argnums=(0, 1))(g, u)
+
+    shp = ((ROWS, FFN), BF16)
+    for_chip(fwd_bwd if bwd else swiglu_raw, shp, shp)
+
+
+@pytest.mark.parametrize("d", [128, 64], ids=["d128", "d64"])
+@pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_rope_qk(for_chip, bwd, d):
+    """d128 rotates a full lane tile by half; d64 sits in half a tile and
+    takes the two-rotation select."""
+    from paddle_tpu.ops.pallas_norm import rope_qk_raw
+
+    def fwd_bwd(q, k, c, s):
+        return jax.grad(lambda a, b: sum(map(_sum32, rope_qk_raw(a, b, c, s))),
+                        argnums=(0, 1))(q, k)
+
+    qk = ((2, 2048, HEADS, d), BF16)
+    tab = ((1, 2048, 1, d), BF16)
+    for_chip(fwd_bwd if bwd else rope_qk_raw, qk, qk, tab, tab)
+
+
+# ------------------------------------------------------- int4 dequant GEMM
+
+@pytest.mark.parametrize("k,n", [(HIDDEN, FFN), (FFN, HIDDEN)],
+                         ids=["up_proj", "down_proj"])
+def test_quant_matmul_int4(for_chip, k, n):
+    from paddle_tpu.ops.quantized import quant_matmul_raw
+
+    for_chip(lambda x, w, s: quant_matmul_raw(x, w, s, k),
+             ((8, k), BF16), ((k // 2, n), jnp.int8), ((n,), jnp.float32))

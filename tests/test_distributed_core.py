@@ -12,10 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5
-    from jax import shard_map
-except ImportError:  # pragma: no cover — 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist

@@ -12,6 +12,8 @@ import subprocess
 import sys
 import textwrap
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 TRAIN = textwrap.dedent("""
     import json, os, signal, sys, time
     sys.path.insert(0, {repo!r})
@@ -82,14 +84,14 @@ def test_kill_rank_relaunch_resume(tmp_path):
     work = str(tmp_path)
     script = os.path.join(work, "train.py")
     with open(script, "w") as f:
-        f.write(TRAIN.format(repo="/root/repo", work=work))
+        f.write(TRAIN.format(repo=REPO, work=work))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", "--np", "1:2", "--elastic_level", "1",
          "--log_dir", os.path.join(work, "log"), script],
-        cwd="/root/repo", env=env, capture_output=True, text=True,
+        cwd=REPO, env=env, capture_output=True, text=True,
         timeout=600)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
     assert "elastic" in r.stderr and "world size 1" in r.stderr, r.stderr

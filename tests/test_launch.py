@@ -79,7 +79,8 @@ class TestLauncher:
             [sys.executable, "-m", "paddle_tpu.distributed.launch",
              "--nproc_per_node", "1", "--log_dir", str(tmp_path / "log"),
              str(script)],
-            cwd="/root/repo", capture_output=True, text=True, timeout=120)
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stderr
 
 

@@ -36,11 +36,7 @@ mesh = Mesh(devs, ("dp",))
 # each process contributes a shard holding its RANK; psum must see both
 local = np.full((1, 4), float(rank), np.float32)
 garr = multihost_utils.host_local_array_to_global_array(local, mesh, P("dp"))
-try:  # jax >= 0.5 top-level; 0.4.x keeps it in experimental
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-f = shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
+f = jax.shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
               in_specs=(P("dp"),), out_specs=P("dp"))
 psum_skip = ""
 try:

@@ -621,6 +621,42 @@ class TestOverheadDiscipline:
         assert per < 20e-6, f"{per * 1e6:.1f}us per sample"
 
 
+# ----------------------------------------------------------------- peaks
+class TestPeaks:
+    """obs/peaks.py: one table keyed by device_kind; an unknown device is
+    an error unless the flag says what to divide by."""
+
+    @pytest.fixture
+    def no_flags(self):
+        old = paddle.get_flags(["FLAGS_obs_peak_gbps",
+                                "FLAGS_obs_peak_tflops"])
+        paddle.set_flags({"FLAGS_obs_peak_gbps": 0.0,
+                          "FLAGS_obs_peak_tflops": 0.0})
+        yield old
+        paddle.set_flags(old)
+
+    @pytest.mark.parametrize("fn", [obs.peak_gbps, obs.peak_tflops])
+    def test_unknown_device_raises(self, no_flags, fn):
+        # this suite's device is the CPU, which is in no table
+        with pytest.raises(LookupError, match="device_kind"):
+            fn()
+
+    def test_flag_says_what_to_divide_by(self, no_flags):
+        # conftest passes both flags for the whole suite
+        assert no_flags["FLAGS_obs_peak_gbps"] > 0
+        assert no_flags["FLAGS_obs_peak_tflops"] > 0
+        paddle.set_flags({"FLAGS_obs_peak_gbps": 7.0,
+                          "FLAGS_obs_peak_tflops": 3.0})
+        assert obs.peak_gbps() == 7.0 and obs.peak_tflops() == 3.0
+
+    def test_v5e_row(self):
+        row = obs.device_peaks("TPU v5 lite")
+        assert row == {"bf16_tflops": 197.0, "int8_tops": 393.0,
+                       "hbm_gbps": 819.0, "hbm_gb": 16.0}
+        with pytest.raises(LookupError):
+            obs.device_peaks("TPU v0")
+
+
 def test_quick_tier_registration():
     """test_obs.py must ride the quick tier (conftest QUICK_MODULES)."""
     import conftest

@@ -126,13 +126,13 @@ def test_functional_sdpa_uses_pallas_and_matches():
 
 def _run_sharded(fn, n, *arrays):
     """shard_map fn over a sep axis of size n; arrays sharded on dim 1 (seq)."""
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:n]), ("sep",))
     spec = P(None, "sep")
     shard = shard_map(fn, mesh=mesh, in_specs=(spec,) * len(arrays),
-                      out_specs=spec, check_rep=False)
+                      out_specs=spec, check_vma=False)
     return shard(*arrays)
 
 
@@ -184,8 +184,8 @@ def test_ring_attention_grad_matches_full():
     k = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
     v = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
 
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("sep",))
     spec = P(None, "sep")
@@ -194,7 +194,7 @@ def test_ring_attention_grad_matches_full():
     def loss_ring(q, k, v):
         f = shard_map(
             lambda q, k, v: ring_attention(q, k, v, "sep", causal=True),
-            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_rep=False)
+            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
         return jnp.sum(f(q, k, v) ** 2)
 
     def loss_ref(q, k, v):

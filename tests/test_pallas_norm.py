@@ -173,7 +173,8 @@ def test_add_layer_norm_parity_and_grads():
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("B,S,H,D", [(2, 32, 4, 16), (1, 40, 2, 64)])
+@pytest.mark.parametrize("B,S,H,D", [(2, 32, 4, 16), (1, 40, 2, 64),
+                                     (1, 24, 2, 128)])
 def test_rope_qk_parity_and_grads(B, S, H, D, dtype):
     rs = np.random.RandomState(4)
     q = _rand(rs, (B, S, H, D), dtype)

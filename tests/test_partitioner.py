@@ -97,9 +97,8 @@ class TestMeshConfig:
         mesh = MeshConfig(data=4, tp=2).build_mesh()
         assert dict(mesh.shape) == {"data": 4, "fsdp": 1, "tp": 2}
 
-    def test_maybe_mesh_fallback(self):
-        assert MeshConfig(data=16).maybe_mesh() is None
-        with pytest.raises(ValueError):
+    def test_too_few_devices_raises(self):
+        with pytest.raises(ValueError, match="needs 16 devices"):
             MeshConfig(data=16).build_mesh()
 
     def test_dict_roundtrip(self):
@@ -382,14 +381,11 @@ class TestPartitionTraining:
         # tensor passed as KWARG still gets its leaf constraint
         assert np.isfinite(float(estep(ids, labels=labels)))
 
-    def test_cpu_virtual_fallback_runs_unsharded(self):
-        """A config too big for this host degrades to an unsharded run
-        with a named warning — one config from laptop to pod."""
-        with pytest.warns(UserWarning, match="UNSHARDED"):
-            model, _opt, step = _tiny_llama_setup(MeshConfig(data=16))
-        assert step.mesh is None and step.plan is None
-        losses = _drive(step, _batches(3))
-        assert all(np.isfinite(losses))
+    def test_config_too_big_for_host_raises(self):
+        """A mesh that was asked for and cannot be built is an error:
+        the step never runs unsharded under a sharded config's name."""
+        with pytest.raises(ValueError, match="needs 16 devices"):
+            _tiny_llama_setup(MeshConfig(data=16))
 
 
 # --------------------------------------------- sharding-aware checkpoints
@@ -629,11 +625,10 @@ class TestHapiMesh:
         with pytest.raises(TypeError):
             m.prepare(mesh={"data": 4})
 
-    def test_mesh_fallback_warns(self):
+    def test_mesh_too_big_for_host_raises(self):
         m = paddle.hapi.Model(paddle.nn.Linear(4, 4))
-        with pytest.warns(UserWarning, match="cpu-virtual fallback"):
+        with pytest.raises(ValueError, match="needs 64 devices"):
             m.prepare(mesh=MeshConfig(data=64))
-        assert m._mesh_plan is None
 
 
 def test_partitioner_in_quick_tier():

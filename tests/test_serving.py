@@ -167,12 +167,23 @@ class TestPagedEngineParity:
         prompt = np.random.RandomState(11).randint(0, 128,
                                                    (2, 5)).astype("int64")
         fp = generate_paged(m, prompt, 5)
-        for mode in ("int8", "int4"):
-            q = generate_paged(m, prompt, 5, weight_quant=mode)
-            assert q.shape == fp.shape
-            # per-channel weight quant on a tiny random model: most
-            # tokens agree with the full-precision engine
-            assert (fp == q).mean() > 0.7, (mode, fp, q)
+        q8 = generate_paged(m, prompt, 5, weight_quant="int8")
+        # per-channel int8 on a tiny random model: most tokens agree with
+        # the full-precision engine
+        assert q8.shape == fp.shape and (fp == q8).mean() > 0.7, (fp, q8)
+        # 15 int4 levels on 32-wide random weights flip near-tied argmaxes
+        # (the draw decides how many), so int4 is held to what it must
+        # equal: the full-precision engine over the dequantized weights
+        from paddle_tpu.ops.quantized import dequant_int4, quantize_int4
+
+        q4 = generate_paged(m, prompt, 5, weight_quant="int4")
+        ref = _tiny()
+        for name, p in ref.named_parameters():
+            if p.ndim == 2 and "embed" not in name:
+                packed, scale = quantize_int4(p._data)
+                p._assign_raw(dequant_int4(packed, scale, p.shape[0],
+                                           p._data.dtype))
+        np.testing.assert_array_equal(q4, generate_paged(ref, prompt, 5))
         with pytest.raises(ValueError):
             m.generate(paddle.to_tensor(np.zeros((1, 4), "int64")),
                        max_new_tokens=2, engine="paged",

@@ -207,10 +207,13 @@ class TestRejectionSampling:
 
 
 class TestCacheRewind:
-    def test_prefix_cache_bit_identical(self):
+    def test_prefix_cache_bit_identical(self):  # to an ulp: see below
         # rejected candidates' K/V must never leak into registered
         # prefix blocks: the published block contents after a spec run
-        # equal the non-spec run's, bit for bit
+        # equal the non-spec run's. Tokens a K+1 verify window wrote went
+        # through other matmul shapes than one-token decode, so the CPU
+        # backend may round them an ulp apart (4.8e-7 seen); a leaked
+        # candidate is another token's K/V and differs at O(1).
         model = _tiny()
         prompt = _repetitive(128)
         engs = {}
@@ -230,8 +233,8 @@ class TestCacheRewind:
         vs = np.asarray(engs["spec"].cache.v)
         for h in shared:
             bp, bs_ = pc_p._map[h], pc_s._map[h]
-            assert np.array_equal(kp[:, bp], ks[:, bs_])
-            assert np.array_equal(vp[:, bp], vs[:, bs_])
+            np.testing.assert_allclose(kp[:, bp], ks[:, bs_], atol=1e-5)
+            np.testing.assert_allclose(vp[:, bp], vs[:, bs_], atol=1e-5)
 
     def test_prefix_hit_after_spec_run_stays_token_identical(self):
         model = _tiny()

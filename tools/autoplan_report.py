@@ -78,7 +78,17 @@ def main(argv=None):
         jax.config.update("jax_platforms", "cpu")
 
     import paddle_tpu as paddle
+    from paddle_tpu import obs
     from paddle_tpu.distributed.partitioner import autoplan
+
+    if os.environ["JAX_PLATFORMS"] == "cpu":
+        # the plan is FOR the chip: price it at the v5e row of the peaks
+        # table, which this host's CPU is not in — unless flags say else
+        row = obs.device_peaks("TPU v5 lite")
+        for name, val in (("FLAGS_obs_peak_tflops", row["bf16_tflops"]),
+                          ("FLAGS_obs_peak_gbps", row["hbm_gbps"])):
+            if not paddle.get_flags(name)[name]:
+                paddle.set_flags({name: val})
     from paddle_tpu.text.models import LlamaForCausalLM, llama_tiny_config
 
     paddle.seed(0)

@@ -78,7 +78,7 @@ def run(batch, seq, mode, layers=8, hidden=1024, inter=2816, heads=16,
     toks = batch * seq / dt
     n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
     # 6ND decoder flops + attention term 12*L*H*S^2... report plain 6ND for
-    # comparability with BENCH_r02 plus the attention-inclusive number
+    # comparability with the round-2 row plus the attention-inclusive number
     flops6nd = 6 * n_params * toks
     attn = 12 * layers * hidden * seq * (batch * seq / dt)
     return {"batch": batch, "seq": seq, "mode": mode, "recompute": recompute,
@@ -89,7 +89,7 @@ def run(batch, seq, mode, layers=8, hidden=1024, inter=2816, heads=16,
 
 
 VARIANTS = {
-    "base": lambda: run(4, 512, "o1"),            # BENCH_r02 shape
+    "base": lambda: run(4, 512, "o1"),            # the round-2 shape
     "b8s1024": lambda: run(8, 1024, "o1"),
     "b16s1024": lambda: run(16, 1024, "o1"),
     "b8s1024_o2": lambda: run(8, 1024, "o2"),

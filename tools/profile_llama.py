@@ -31,8 +31,10 @@ def main(batch=8, seq=1024, logdir="/tmp/llama_trace", config="168m",
     `python tools/profile_llama.py 4 1024 /tmp/t 1b flash_resident`) — the
     round-6 xplane capture that drives the PERF.md breakdown."""
     import paddle_tpu as paddle
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
 
+    enable_compile_cache()
     paddle.seed(0)
     if config == "1b":
         cfg = LlamaConfig(vocab_size=32000, hidden_size=2048,

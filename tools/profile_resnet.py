@@ -45,8 +45,9 @@ def emit(name, ms, extra=None):
 
 
 def main(batch=256):
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_ccache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # --- conv roofline: 3x3 conv on a mid-stage shape, bf16
     rs = np.random.RandomState(0)

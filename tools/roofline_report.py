@@ -113,9 +113,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from paddle_tpu import obs
+
+    if os.environ["JAX_PLATFORMS"] == "cpu":
+        obs.peaks.set_off_chip_peaks()  # the CPU is in no peaks table
     if args.smoke or args.write_baseline:
         run_smoke()
-    from paddle_tpu import obs
 
     rows = obs.roofline_rows(args.site)
     if args.write_baseline:
@@ -128,7 +131,8 @@ def main(argv=None):
                          indent=2))
     else:
         print(f"peak bandwidth: {obs.peak_gbps():g} GB/s "
-              "(FLAGS_obs_peak_gbps; 0 = backend default)")
+              "(FLAGS_obs_peak_gbps; 0 = this device_kind's published "
+              "peak, obs/peaks.py)")
         print(render_table(rows) if rows else
               "cost ledger is empty — run with --smoke, or call from a "
               "process that compiled programs")
