@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import DEFAULT_EXACT_CAP
+from ..obs.trace import span as _span
 from ..ops._pallas_common import ceil_to as _ceil_to
 from ..text.generation import (_GenSpec, _gpt_layer_prefill,
                                _layer_forward_prefill, _layer_norm,
@@ -630,7 +632,7 @@ class Request:
 
     def __init__(self, rid, prompt, max_new_tokens, do_sample, temperature,
                  top_k, top_p, eos_token_id, max_time_ms=None,
-                 speculative=None):
+                 speculative=None, arrival_s=None):
         self.rid = rid
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.max_new_tokens = int(max_new_tokens)
@@ -640,7 +642,11 @@ class Request:
         self.top_p = float(top_p)
         self.eos_token_id = -1 if eos_token_id is None else int(eos_token_id)
         self.tokens: list[int] = []
-        self.arrival_s = time.perf_counter()
+        # when the request reached the SYSTEM (a perf_counter time): the
+        # caller's, where it kept one (a router's mailbox, an open-loop
+        # generator's due time), else now
+        self.arrival_s = time.perf_counter() if arrival_s is None \
+            else float(arrival_s)
         self.admitted_s = None      # set when a slot + block budget land
         self.first_token_s = None
         self.finished = False
@@ -847,8 +853,11 @@ class ServingEngine:
         self.active_slot_steps = 0
         self.completed: dict[int, np.ndarray] = {}
         self.finish_reasons: dict[int, str] = {}
-        self.ttfts: list[float] = []
-        self.queue_waits: list[float] = []
+        # the newest samples only, as many as the histograms' exact ring
+        # keeps: a long-lived server does not grow, stats() copies a
+        # bounded list
+        self.ttfts: deque[float] = deque(maxlen=DEFAULT_EXACT_CAP)
+        self.queue_waits: deque[float] = deque(maxlen=DEFAULT_EXACT_CAP)
         # ---- telemetry (obs): the serving stats ARE a metrics registry
         # now — stats() is a thin view over it. Per-ENGINE registry so
         # concurrent engines/tests never share counters; always on (the
@@ -871,11 +880,12 @@ class ServingEngine:
             "serving_decode_step_seconds", "one decode tick (all active "
             "slots advance one token)")
         self._m_tpot = reg.histogram(
-            "serving_tpot_seconds", "time per output token, observed "
-            "ONCE PER EMITTED TOKEN: tick wall / tokens the tick "
-            "emitted (a speculative verify window divides by its "
-            "accepted count — multi-token ticks report real TPOT, not "
-            "a fake per-tick win)")
+            "serving_tpot_seconds", "time per output token as the "
+            "request's user sees it, observed ONCE PER EMITTED TOKEN: "
+            "the gap since that request's previous token (a speculative "
+            "verify window that emits n tokens observes gap / n, n "
+            "times — multi-token ticks report real TPOT, not a fake "
+            "per-tick win)")
         self._m_decode_tokens = reg.counter(
             "serving_decode_tokens_total", "tokens emitted by decode ticks")
         self._m_prefill_tokens = reg.counter(
@@ -1032,9 +1042,14 @@ class ServingEngine:
     def add_request(self, prompt, max_new_tokens=32, do_sample=False,
                     temperature=1.0, top_k=0, top_p=1.0,
                     eos_token_id=None, max_time_ms=None,
-                    speculative=None) -> int:
+                    speculative=None, arrival_s=None) -> int:
         """Queue a request. Raises when it could NEVER be served (context
         or pool too small); otherwise it waits for admission.
+        `arrival_s` is the `time.perf_counter()` time at which the
+        request reached the system, for a caller that held it before this
+        call (a router's mailbox, an open-loop generator's due time):
+        the deadline, the flight's enqueue mark and the TTFT / queue-wait
+        histograms run from it. None: now.
         `max_time_ms` is a per-request wall-clock deadline from arrival:
         when it expires the request finishes with reason ``"timeout"``
         (whatever tokens it produced so far are its result) and its
@@ -1075,7 +1090,7 @@ class ServingEngine:
         self._next_id += 1
         req = Request(rid, prompt, max_new_tokens, do_sample, temperature,
                       top_k, top_p, eos_token_id, max_time_ms=max_time_ms,
-                      speculative=speculative)
+                      speculative=speculative, arrival_s=arrival_s)
         req._flight = self.flight.begin(rid, prompt.size,
                                         int(max_new_tokens),
                                         req.arrival_s)
@@ -1113,32 +1128,41 @@ class ServingEngine:
         its deadline emits a terminal ``(request_id, None, True)`` —
         streaming consumers see every completion, timeout included."""
         self.contract.check("step")
-        emitted = self._expire()
-        emitted.extend(self._admit())
-        emitted.extend(self._chunk_phase())
-        active = [i for i, r in enumerate(self._slot_req)
-                  if r is not None and r.prefill_done]
-        if active:
-            # partition: speculating slots ride the verify window, the
-            # rest (opt-outs, empty proposals, non-spec engine) take the
-            # ordinary one-token decode — both in the same tick
-            spec_slots, props = self._spec_proposals(active)
-            if spec_slots:
-                in_spec = set(spec_slots)
-                plain = [i for i in active if i not in in_spec]
-            else:
-                plain = active
-            if plain:
-                emitted.extend(self._decode(plain))
-            if spec_slots:
-                emitted.extend(self._spec_decode(spec_slots, props))
-            self.steps += 1
-            self.active_slot_steps += len(active)
-            self._m_active.set(len(active))
-        if self._draining:
-            done = sum(1 for _rid, _tok, fin in emitted if fin)
-            if done:
-                self._m_drained.inc(done)
+        # the spans of a tick (obs/trace.py): `serving.step` bounds it;
+        # inside it a site's host preparation is `.build`, its dispatch up
+        # to the result on the host `.run`, the bookkeeping after `.emit`
+        with _span("serving.step", active=self.num_active,
+                   waiting=len(self._waiting)):
+            with _span("serving.expire"):
+                emitted = self._expire()
+            waiting = len(self._waiting)
+            with _span("serving.admit") as sp:
+                emitted.extend(self._admit())
+                sp.attrs["admitted"] = waiting - len(self._waiting)
+            emitted.extend(self._chunk_phase())
+            active = [i for i, r in enumerate(self._slot_req)
+                      if r is not None and r.prefill_done]
+            if active:
+                # partition: speculating slots ride the verify window, the
+                # rest (opt-outs, empty proposals, non-spec engine) take
+                # the ordinary one-token decode — both in the same tick
+                spec_slots, props = self._spec_proposals(active)
+                if spec_slots:
+                    in_spec = set(spec_slots)
+                    plain = [i for i in active if i not in in_spec]
+                else:
+                    plain = active
+                if plain:
+                    emitted.extend(self._decode(plain))
+                if spec_slots:
+                    emitted.extend(self._spec_decode(spec_slots, props))
+                self.steps += 1
+                self.active_slot_steps += len(active)
+                self._m_active.set(len(active))
+            if self._draining:
+                done = sum(1 for _rid, _tok, fin in emitted if fin)
+                if done:
+                    self._m_drained.inc(done)
         return emitted
 
     def run(self, max_steps=100000):
@@ -1306,9 +1330,10 @@ class ServingEngine:
         if cached is None:
             from ..obs import costs as _costs
 
-            t0 = time.perf_counter()
-            compiled = jitted.lower(*args).compile()
-            compile_wall = time.perf_counter() - t0
+            with _span("serving.compile", site=site,
+                       bucket=int(bucket)) as sp:
+                compiled = jitted.lower(*args).compile()
+            compile_wall = sp.end - sp.start
             entry = _costs.record_program(
                 site, self._prog_group(site), keystr,
                 compiled=compiled, wall_s=compile_wall, bucket=int(bucket))
@@ -1565,25 +1590,26 @@ class ServingEngine:
         bucket = min(_ceil_to(default_buckets(s), self.block_size),
                      self.max_model_len)
         bucket = max(bucket, _ceil_to(s, self.block_size))
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :s] = req.prompt
-        samp = self._samp_arrays([req])
+        at = {"rid": req.rid, "tokens": int(s), "bucket": int(bucket)}
         c = self.cache
-        from ..obs import span as _span
-
-        args = (self.spec, self.block_size, self.kv_mode, req.do_sample,
-                self.params, jnp.asarray(ids), jnp.int32(s),
-                jnp.asarray(self._tables[slot]), c.k, c.v, c.k_scale,
-                c.v_scale, samp, self._key)
-        prog, entry = self._program("serving.prefill", _prefill_step, 4,
-                                    bucket, req.do_sample, (), args)
-        t_run = time.perf_counter()
-        with _span("serving.prefill"):
+        with _span("serving.prefill.build", **at):
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :s] = req.prompt
+            samp = self._samp_arrays([req])
+            args = (self.spec, self.block_size, self.kv_mode,
+                    req.do_sample, self.params, jnp.asarray(ids),
+                    jnp.int32(s), jnp.asarray(self._tables[slot]), c.k,
+                    c.v, c.k_scale, c.v_scale, samp, self._key)
+            prog, entry = self._program("serving.prefill", _prefill_step,
+                                        4, bucket, req.do_sample, (), args)
+        with _span("serving.prefill.run", **at) as run:
             out = prog(*args[4:])
             tok_arr, ck, cv, cks, cvs, self._key = out
             c.swap(ck, cv, cks, cvs)
             tok = int(jax.device_get(tok_arr)[0])
-        req.first_token_s = time.perf_counter()
+        # the span's own clock reads: the flight recorder, the cost ledger
+        # and the lifecycle mark time this interval through them
+        t_run, req.first_token_s = run.start, run.end
         entry.observe(req.first_token_s - t_run)
         req.prefill_pos = s
         req.prefill_done = True
@@ -1621,13 +1647,13 @@ class ServingEngine:
         emitted = []
         for slot in sorted(self._slot_chunk):
             req = self._slot_req[slot]
-            tok = self._run_chunk(slot, req, self._slot_chunk[slot])
+            tok, t_end = self._run_chunk(slot, req, self._slot_chunk[slot])
             if tok is None:
                 continue
             del self._slot_chunk[slot]
             s = req.prompt.size
             req.prefill_done = True
-            req.first_token_s = time.perf_counter()
+            req.first_token_s = t_end       # the token reached the host
             self._m_prefill.observe(req.prefill_s)
             self._m_ttft.observe(req.ttft_s)
             self.ttfts.append(req.ttft_s)
@@ -1642,9 +1668,10 @@ class ServingEngine:
         return emitted
 
     def _run_chunk(self, slot, req, state):
-        """One chunk-prefill program invocation for one slot. Returns the
-        first token (int) when this was the prompt's final chunk, else
-        None. The chunk program is keyed by (chunk-length bucket,
+        """One chunk-prefill program invocation for one slot. Returns
+        (the first token when this was the prompt's final chunk, else
+        None; the end of the chunk's `.run` span). The chunk program is
+        keyed by (chunk-length bucket,
         context-pages bucket, emit_token): chunk lengths bucket like
         prompt lengths, context pages like slot counts, so a stream
         compiles O(log S * log pages) chunk programs."""
@@ -1663,24 +1690,25 @@ class ServingEngine:
         cow = state.pop("cow", None)
         cow_src, cow_dst = cow if cow is not None else (TRASH_BLOCK,
                                                         TRASH_BLOCK)
-        ids = np.zeros((1, c_bucket), np.int32)
-        ids[0, :n] = req.prompt[start:start + n]
-        samp = self._samp_arrays([req])
+        at = {"rid": req.rid, "tokens": int(n), "start": int(start),
+              "last": bool(is_last), "bucket": int(c_bucket)}
         c = self.cache
-        from ..obs import span as _span
-
-        args = (self.spec, self.block_size, self.kv_mode,
-                req.do_sample and is_last, is_last, ctx_pages,
-                self.params, jnp.asarray(ids), jnp.int32(start),
-                jnp.int32(start + n), jnp.int32(s - 1 - start),
-                jnp.asarray(self._tables[slot]), jnp.int32(cow_src),
-                jnp.int32(cow_dst), c.k, c.v, c.k_scale, c.v_scale,
-                samp, self._key)
-        prog, entry = self._program(
-            "serving.chunk_prefill", _chunk_prefill_step, 6, c_bucket,
-            req.do_sample and is_last, (ctx_pages, bool(is_last)), args)
-        t_run = time.perf_counter()
-        with _span("serving.chunk_prefill"):
+        with _span("serving.chunk.build", **at):
+            ids = np.zeros((1, c_bucket), np.int32)
+            ids[0, :n] = req.prompt[start:start + n]
+            samp = self._samp_arrays([req])
+            args = (self.spec, self.block_size, self.kv_mode,
+                    req.do_sample and is_last, is_last, ctx_pages,
+                    self.params, jnp.asarray(ids), jnp.int32(start),
+                    jnp.int32(start + n), jnp.int32(s - 1 - start),
+                    jnp.asarray(self._tables[slot]), jnp.int32(cow_src),
+                    jnp.int32(cow_dst), c.k, c.v, c.k_scale, c.v_scale,
+                    samp, self._key)
+            prog, entry = self._program(
+                "serving.chunk_prefill", _chunk_prefill_step, 6, c_bucket,
+                req.do_sample and is_last, (ctx_pages, bool(is_last)),
+                args)
+        with _span("serving.chunk.run", **at) as run:
             out = prog(*args[6:])
             tok_arr, ck, cv, cks, cvs, self._key = out
             c.swap(ck, cv, cks, cvs)
@@ -1688,12 +1716,13 @@ class ServingEngine:
                 tok = int(jax.device_get(tok_arr)[0])
             else:
                 # non-final chunks fetch no token, so without an explicit
-                # barrier t_end is async dispatch's enqueue time — block
-                # on the written cache so the observed wall (roofline
-                # utilization + the prefill_chunk span) is the program's
+                # barrier the span would end at async dispatch's enqueue
+                # time — block on the written cache so the observed wall
+                # (roofline utilization + the chunk's span) is the
+                # program's
                 tok = None
                 jax.block_until_ready(c.k)
-        t_end = time.perf_counter()
+        t_run, t_end = run.start, run.end
         entry.observe(t_end - t_run)
         fl = req._flight
         fl.chunks += 1
@@ -1710,62 +1739,66 @@ class ServingEngine:
         req.prefill_pos = start + n
         self._m_chunks.inc()
         self._m_prefill_tokens.inc(n)
-        return tok
+        return tok, t_end
 
     def _decode(self, active):
         from ..jit.api import default_buckets
 
-        t0 = time.perf_counter()
         bucket = min(default_buckets(len(active)), self.max_slots)
-        reqs = [self._slot_req[i] for i in active]
-        pad = bucket - len(active)
-        tok = np.array([r.tokens[-1] for r in reqs] + [0] * pad, np.int32)
-        pos = np.concatenate([self._slot_pos[active],
-                              np.zeros(pad, np.int64)]).astype(np.int32)
-        tables = np.concatenate(
-            [self._tables[active],
-             np.full((pad, self.pages), TRASH_BLOCK, np.int32)])
-        samp = self._samp_arrays(reqs, pad)
-        any_sample = any(r.do_sample for r in reqs)
+        at = {"active": len(active), "bucket": int(bucket)}
         c = self.cache
-        args = (self.spec, self.block_size, self.kv_mode, any_sample,
-                self.params, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(tables), c.k, c.v, c.k_scale, c.v_scale, samp,
-                self._key)
-        prog, entry = self._program("serving.decode", _decode_step, 4,
-                                    bucket, any_sample, (), args)
-        t_run = time.perf_counter()
-        out = prog(*args[4:])
-        nxt, ck, cv, cks, cvs, self._key = out
-        c.swap(ck, cv, cks, cvs)
-        nxt = np.asarray(jax.device_get(nxt))
-        t_end = time.perf_counter()
-        step_wall = t_end - t0
-        entry.observe(t_end - t_run)
-        self.flight.tick_span("decode_tick", t_run, t_end,
-                              active=len(active), bucket=int(bucket),
-                              program=entry.program)
-        self._m_decode_step.observe(step_wall)
-        # TPOT is a PER-TOKEN distribution: one observation per emitted
-        # token (count == tokens, sum == tick wall), so mixed spec /
-        # non-spec streams aggregate correctly
-        tpot = step_wall / len(active)
-        for _ in active:
-            self._m_tpot.observe(tpot)
-        emitted = []
-        for j, slot in enumerate(active):
-            req = self._slot_req[slot]
-            t = int(nxt[j])
-            req.tokens.append(t)
-            fl = req._flight
-            fl.tokens += 1
-            fl.last_token_s = t_end
-            self._slot_pos[slot] += 1
-            done = self._check_done(req, t)
-            emitted.append((req.rid, t, done))
-            if done:
-                self._finish(slot)
-        self._m_decode_tokens.inc(len(active))
+        with _span("serving.decode.build", **at) as build:
+            reqs = [self._slot_req[i] for i in active]
+            pad = bucket - len(active)
+            tok = np.array([r.tokens[-1] for r in reqs] + [0] * pad,
+                           np.int32)
+            pos = np.concatenate(
+                [self._slot_pos[active],
+                 np.zeros(pad, np.int64)]).astype(np.int32)
+            tables = np.concatenate(
+                [self._tables[active],
+                 np.full((pad, self.pages), TRASH_BLOCK, np.int32)])
+            samp = self._samp_arrays(reqs, pad)
+            any_sample = any(r.do_sample for r in reqs)
+            args = (self.spec, self.block_size, self.kv_mode, any_sample,
+                    self.params, jnp.asarray(tok), jnp.asarray(pos),
+                    jnp.asarray(tables), c.k, c.v, c.k_scale, c.v_scale,
+                    samp, self._key)
+            prog, entry = self._program("serving.decode", _decode_step, 4,
+                                        bucket, any_sample, (), args)
+        with _span("serving.decode.run", **at) as run:
+            out = prog(*args[4:])
+            nxt, ck, cv, cks, cvs, self._key = out
+            c.swap(ck, cv, cks, cvs)
+            nxt = np.asarray(jax.device_get(nxt))
+        with _span("serving.decode.emit", **at):
+            t_run, t_end = run.start, run.end
+            entry.observe(t_end - t_run)
+            self.flight.tick_span("decode_tick", t_run, t_end,
+                                  active=len(active), bucket=int(bucket),
+                                  program=entry.program)
+            # the tick as its two spans time it (the span machinery's own
+            # microsecond between them left out)
+            self._m_decode_step.observe(
+                (build.end - build.start) + (t_end - t_run))
+            emitted = []
+            for j, slot in enumerate(active):
+                req = self._slot_req[slot]
+                t = int(nxt[j])
+                req.tokens.append(t)
+                fl = req._flight
+                fl.tokens += 1
+                # TPOT as this request's user sees it: the gap since its
+                # previous token, whatever ran in between (a prefill chunk
+                # of another slot, the scheduler), one observation a token
+                self._m_tpot.observe(t_end - fl.last_token_s)
+                fl.last_token_s = t_end
+                self._slot_pos[slot] += 1
+                done = self._check_done(req, t)
+                emitted.append((req.rid, t, done))
+                if done:
+                    self._finish(slot)
+            self._m_decode_tokens.inc(len(active))
         return emitted
 
     def _spec_proposals(self, active):
@@ -1833,13 +1866,16 @@ class ServingEngine:
         prog, entry = self._program("serving.spec_verify",
                                     _spec_verify_step, 4, bucket,
                                     any_sample, (k,), args)
-        t_run = time.perf_counter()
-        out = prog(*args[4:])
-        acc, tgt, ck, cv, cks, cvs, self._key = out
-        c.swap(ck, cv, cks, cvs)
-        acc = np.asarray(jax.device_get(acc))
-        tgt = np.asarray(jax.device_get(tgt))
-        t_end = time.perf_counter()
+        # a span only so that the flight recorder's `verify_window` and
+        # the cost ledger share its clock reads; no metric reads it
+        with _span("serving.verify.run", active=len(slots),
+                   k=int(k)) as run:
+            out = prog(*args[4:])
+            acc, tgt, ck, cv, cks, cvs, self._key = out
+            c.swap(ck, cv, cks, cvs)
+            acc = np.asarray(jax.device_get(acc))
+            tgt = np.asarray(jax.device_get(tgt))
+        t_run, t_end = run.start, run.end
         step_wall = t_end - t0
         entry.observe(t_end - t_run)
         self._m_decode_step.observe(step_wall)
@@ -1868,6 +1904,10 @@ class ServingEngine:
                 done = self._check_done(req, t)
             self._slot_pos[slot] += len(new)
             fl.tokens += len(new)
+            # the window's tokens reach the user together: each takes its
+            # share of the gap since the request's previous token
+            for _ in new:
+                self._m_tpot.observe((t_end - fl.last_token_s) / len(new))
             fl.last_token_s = t_end
             n_tokens += len(new)
             n_accepted += a
@@ -1882,9 +1922,6 @@ class ServingEngine:
                                       for p in proposals))
         self._m_spec_accepted.inc(n_accepted)
         self._m_decode_tokens.inc(n_tokens)
-        tpot = step_wall / max(n_tokens, 1)
-        for _ in range(n_tokens):
-            self._m_tpot.observe(tpot)
         self.flight.tick_span("verify_window", t_run, t_end,
                               active=n_windows, k=int(k),
                               accepted=int(n_accepted),

@@ -35,6 +35,7 @@ import numpy as np
 from ..core.dispatch import TraceContext, trace_context
 from ..core.flags import flag
 from ..core.tensor import Tensor
+from ..obs.trace import span as _span
 
 _NOT_TO_STATIC: set = set()
 
@@ -148,6 +149,8 @@ class CompiledFunction:
                  in_shardings=None):
         functools.update_wrapper(self, fn)
         self._fn = fn
+        #: the `fn` attr of this function's spans (obs/trace.py)
+        self._span_fn = getattr(fn, "__name__", "to_static")
         # per-instance RLock serializing specialization bookkeeping:
         # phase counts, the compiled-spec cache and discovery contexts
         # (reads stay lock-free — a stale read only re-enters the
@@ -333,24 +336,35 @@ class CompiledFunction:
             args = self._apply_buckets(args)
         if self._segmented:
             return self._run_segmented(args, kwargs)
-        leaves: list[Tensor] = []
-        struct = _flatten((args, kwargs), leaves)
-        key = self._key(struct, leaves)
-        with self._lock:
-            n = self._state.get(key, 0)
-            self._state[key] = n + 1
-        shared = (self._share_discovery and key not in self._discovered
-                  and self._discovered)
-        if n == 0 and not shared:
-            # warm-up: lazy state creation (already through the dy2static
-            # rewrite so all phases share one code path)
-            return self._capture_fn()(*args, **kwargs)
-        if n == 1 and not shared:
-            return self._discover(key, args, kwargs)
-        spec = self._cache.get(key)
-        if spec is None:
-            return self._compile_and_run(key, struct, leaves, args, kwargs)
-        return self._run(spec, struct, leaves)
+        # `jit.call` is the host's time in one call; the span inside it
+        # says which phase the call was: `jit.warmup`, `jit.discover`,
+        # `jit.compile`, or on the cached path `jit.dispatch` (the
+        # executable up to the return of its async launch), so that the
+        # call's self time is flatten, key, lock and `_finish`
+        fn = self._span_fn
+        with _span("jit.call", fn=fn):
+            leaves: list[Tensor] = []
+            struct = _flatten((args, kwargs), leaves)
+            key = self._key(struct, leaves)
+            with self._lock:
+                n = self._state.get(key, 0)
+                self._state[key] = n + 1
+            shared = (self._share_discovery
+                      and key not in self._discovered and self._discovered)
+            if n == 0 and not shared:
+                # warm-up: lazy state creation (already through the
+                # dy2static rewrite so all phases share one code path)
+                with _span("jit.warmup", fn=fn):
+                    return self._capture_fn()(*args, **kwargs)
+            if n == 1 and not shared:
+                with _span("jit.discover", fn=fn):
+                    return self._discover(key, args, kwargs)
+            spec = self._cache.get(key)
+            if spec is None:
+                with _span("jit.compile", fn=fn):
+                    return self._compile_and_run(key, struct, leaves, args,
+                                                 kwargs)
+            return self._run(spec, struct, leaves)
 
     # ------------------------------------------------------------ phases
     def _discover(self, key, args, kwargs):
@@ -654,26 +668,20 @@ class CompiledFunction:
         arg_datas = [t._data for t in leaves]
         ro_datas = [t._data for t in spec.ro_caps]
         mut_datas = [t._data for t in spec.mut_caps]
+        with _span("jit.dispatch", fn=self._span_fn) as sp:
+            out_datas, mut_out = spec.executable(arg_datas, ro_datas,
+                                                 mut_datas)
         # training flight recorder (round 16): a compiled-step dispatch
         # during an instrumented fit becomes a span on the step timeline
-        # and its ledger flops feed the MFU gauges. One module-attr read
-        # when no recorder is active — per to_static CALL, not per op.
+        # (the `jit.dispatch` span's own clock reads) and its ledger flops
+        # feed the MFU gauges. One module-attr read when no recorder is
+        # active — per to_static CALL, not per op.
         from ..obs.train_flight import current as _tf_current
 
         rec = _tf_current()
-        if rec is None:
-            out_datas, mut_out = spec.executable(arg_datas, ro_datas,
-                                                 mut_datas)
-        else:
-            import time as _time
-
-            t0 = _time.perf_counter()
-            out_datas, mut_out = spec.executable(arg_datas, ro_datas,
-                                                 mut_datas)
-            rec.program_dispatch(
-                getattr(self._fn, "__name__", "to_static"), t0,
-                _time.perf_counter(),
-                entry=getattr(spec, "cost_entry", None))
+        if rec is not None:
+            rec.program_dispatch(self._span_fn, sp.start, sp.end,
+                                 entry=getattr(spec, "cost_entry", None))
         return self._finish(spec, out_datas, mut_out)
 
     def _finish(self, spec, out_datas, mut_out):

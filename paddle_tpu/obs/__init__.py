@@ -11,8 +11,10 @@ reference stack, rebuilt serving-grade):
     ``serve_metrics(port)`` stdlib endpoint). The serving engine owns a
     per-instance registry; the framework default (compile metrics) is
     ``default_registry()``.
-  * **trace**    — ``span("name")`` over ``jax.profiler.TraceAnnotation``
-    on TPU / wall-clock off-TPU; ``capture_trace(dir)`` on-demand xplane
+  * **trace**    — ``span("name", **attrs)``: a
+    ``jax.profiler.TraceAnnotation`` on every backend plus a record
+    ``(name, start, end, parent, attrs)`` in the one process-wide span
+    log (``span_events()``); ``capture_trace(dir)`` on-demand xplane
     capture.
   * **watchdog** — every compile/retrace (eager cache, to_static, the
     generation engine, serving buckets) becomes an event +
@@ -48,8 +50,8 @@ from .logging import ObsLogger, get_logger
 from .metrics import (DEFAULT_BUCKETS, OVERFLOW, Counter, Gauge, Histogram,
                       Registry, dump_registry, log_event)
 from .peaks import DEVICE_PEAKS, device_peaks, peak_gbps, peak_tflops
-from .trace import (capture_trace, clear_spans, span, span_events,
-                    step_span)
+from .trace import (SpanRecord, capture_trace, clear_spans, span,
+                    span_events, span_log_start)
 from .train_flight import (StepFlight, TrainFlightRecorder,
                            validate_train_trace)
 from .watchdog import (CompileEvent, audit_ckpt_stalls, audit_recompiles,
@@ -85,7 +87,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "DEFAULT_BUCKETS",
     "OVERFLOW", "default_registry", "render_prometheus", "metrics_enabled",
     "dump_registry", "log_event",
-    "span", "step_span", "span_events", "clear_spans", "capture_trace",
+    "span", "SpanRecord", "span_events", "span_log_start", "clear_spans",
+    "capture_trace",
     "CompileEvent", "record_compile", "compile_events", "compile_counts",
     "post_warmup_compiles", "clear_events", "audit_recompiles",
     "jaxpr_size",

@@ -79,12 +79,17 @@ class RouterFuture:
 
 class Submission:
     """Router-side record of one request: what to run, where results
-    go, and the placement inputs (prefix fingerprint, session)."""
+    go, the placement inputs (prefix fingerprint, session), and when the
+    router took it (`arrival_s`, a `perf_counter` time: the engine's TTFT
+    and deadline run from it, so they hold the wait in the mailbox and
+    every re-placement)."""
 
     __slots__ = ("rid", "prompt", "kwargs", "session", "fingerprint",
-                 "future", "attempts")
+                 "future", "attempts", "arrival_s")
 
-    def __init__(self, rid, prompt, kwargs, session, fingerprint):
+    def __init__(self, rid, prompt, kwargs, session, fingerprint,
+                 arrival_s):
+        self.arrival_s = float(arrival_s)
         self.rid = rid
         self.prompt = prompt
         self.kwargs = kwargs
@@ -285,7 +290,8 @@ class Replica:
 
     def _start_sub(self, sub: Submission):
         try:
-            rid = self.engine.add_request(sub.prompt, **sub.kwargs)
+            rid = self.engine.add_request(
+                sub.prompt, **{"arrival_s": sub.arrival_s, **sub.kwargs})
         except ValueError as exc:
             if self.engine.draining and self._on_reroute is not None:
                 # drain raced an already-enqueued placement: not an

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import hashlib
 import itertools
+import time
 
 import numpy as np
 
@@ -168,11 +169,12 @@ class Router:
         along). ``kwargs`` pass through to ``engine.add_request``
         (max_new_tokens, do_sample, eos_token_id, max_time_ms, ...);
         ``session`` pins follow-up turns to this request's replica."""
+        arrival_s = time.perf_counter()
         arr = np.asarray(
             prompt._data if hasattr(prompt, "_data") else prompt,
             np.int64).reshape(-1).astype(np.int32)
         sub = Submission(next(self._rids), arr, kwargs, session,
-                         self._fingerprint(arr))
+                         self._fingerprint(arr), arrival_s)
         self._place(sub)
         return sub.future
 

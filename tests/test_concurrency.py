@@ -521,7 +521,7 @@ class TestReviewPass:
                 ("paddle_tpu/inference/engine.py",
                  ("_SEEN_SERVING_PROGRAMS", "_SERVING_EXECUTABLES")),
                 ("paddle_tpu/obs/trace.py",
-                 ("_span_buf", "_backend_memo"))):
+                 ("_span_log", "_cleared_at"))):
             src = open(os.path.join(REPO, rel)).read()
             info = _GuardInfo(ast.parse(src), src.splitlines(), src)
             for name in names:

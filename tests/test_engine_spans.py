@@ -1,0 +1,373 @@
+"""The spans inside `ServingEngine.step` and the `to_static` call, read
+back from the one span log (`obs.span_events()`), and the engine metrics
+repaired beside them: a request's clock running from `arrival_s`, TPOT as
+the request's user sees it, the bounded TTFT / queue-wait samples.
+"""
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import obs
+from paddle_tpu.inference.engine import ServingEngine
+from paddle_tpu.obs.metrics import DEFAULT_EXACT_CAP
+
+RUNS = ("serving.prefill.run", "serving.chunk.run", "serving.decode.run",
+        "serving.verify.run")
+
+
+def _tiny_llama(max_pos=128):
+    from paddle_tpu.text.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(0)
+    cfg = LlamaConfig(vocab_size=128, hidden_size=32, intermediate_size=64,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      max_position_embeddings=max_pos)
+    m = LlamaForCausalLM(cfg)
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A tiny engine run that takes every path of a tick: short prompts
+    prefilled whole inside admission, long ones through the chunk ladder,
+    decode ticks with one to three slots live, a request left waiting."""
+    eng = ServingEngine(_tiny_llama(), max_slots=3, kv_block_size=8,
+                        chunked_prefill_tokens=16)
+    rs = np.random.RandomState(0)
+    obs.clear_spans()
+    before = eng.stats()
+    for ln, nt in ((5, 4), (40, 6), (12, 3), (33, 5), (7, 2)):
+        eng.add_request(rs.randint(0, 128, (ln,)), max_new_tokens=nt)
+    eng.run()
+    return eng, obs.span_events(), before, eng.stats()
+
+
+def _children(evs, parent):
+    """The records that lie inside `parent` and name it as their parent."""
+    return sorted((e for e in evs if e.parent == parent.name
+                   and parent.start <= e.start and e.end <= parent.end),
+                  key=lambda e: e.start)
+
+
+def test_every_record_is_well_formed(served):
+    _, evs, _, _ = served
+    assert evs and all(isinstance(e, obs.SpanRecord) for e in evs)
+    assert all(e.start <= e.end for e in evs)
+    assert {e.name.split(".")[0] for e in evs} == {"serving"}
+    names = {e.name for e in evs}
+    assert {"serving.step", "serving.expire", "serving.admit",
+            "serving.prefill.build", "serving.prefill.run",
+            "serving.chunk.build", "serving.chunk.run",
+            "serving.decode.build", "serving.decode.run",
+            "serving.decode.emit"} <= names
+    assert "serving.verify.run" not in names     # no speculation here
+
+
+def test_children_lie_inside_their_step_and_do_not_overlap(served):
+    eng, evs, _, _ = served
+    steps = [e for e in evs if e.name == "serving.step"]
+    assert len(steps) >= eng.steps > 0
+    assert all(s.parent is None for s in steps)
+    seen = 0
+    for st in steps:
+        kids = _children(evs, st)
+        seen += len(kids)
+        assert [k.name for k in kids[:2]] == ["serving.expire",
+                                              "serving.admit"]
+        for a, b in zip(kids, kids[1:]):
+            assert a.end <= b.start, (a, b)
+        assert set(st.attrs) == {"active", "waiting"}
+    assert seen == sum(e.parent == "serving.step" for e in evs)
+    # a short prompt is prefilled inside the admission pass
+    for e in evs:
+        if e.name.startswith("serving.prefill."):
+            assert e.parent == "serving.admit"
+    admitted = sum(e.attrs["admitted"] for e in evs
+                   if e.name == "serving.admit")
+    assert admitted == 5
+
+
+def test_prefill_spans_count_the_prefill_counters_tokens(served):
+    _, evs, before, after = served
+    runs = [e for e in evs if e.name in ("serving.chunk.run",
+                                         "serving.prefill.run")]
+    assert sum(e.attrs["tokens"] for e in runs) \
+        == after["prefill_tokens"] - before["prefill_tokens"] \
+        == 5 + 40 + 12 + 33 + 7
+    chunks = [e for e in evs if e.name == "serving.chunk.run"]
+    assert len(chunks) == after["prefill_chunks"] - before["prefill_chunks"]
+    assert all(set(e.attrs) == {"rid", "tokens", "start", "last", "bucket"}
+               for e in chunks)
+    # request 1's 40 tokens: chunks of 16, 16, 8, the last one flagged
+    mine = sorted((e for e in chunks if e.attrs["rid"] == 1),
+                  key=lambda e: e.start)
+    assert [(e.attrs["start"], e.attrs["tokens"], e.attrs["last"])
+            for e in mine] == [(0, 16, False), (16, 16, False),
+                               (32, 8, True)]
+
+
+def test_decode_spans_are_the_decode_step_observation(served):
+    """`decode.build` + `decode.run` of a tick equals what
+    `serving_decode_step_seconds` observed for it, to the microsecond."""
+    eng, evs, _, _ = served
+    build = sorted((e for e in evs if e.name == "serving.decode.build"),
+                   key=lambda e: e.start)
+    run = sorted((e for e in evs if e.name == "serving.decode.run"),
+                 key=lambda e: e.start)
+    emit = [e for e in evs if e.name == "serving.decode.emit"]
+    assert len(build) == len(run) == len(emit) == eng._m_decode_step.count
+    ticks = [(b.end - b.start) + (r.end - r.start)
+             for b, r in zip(build, run)]
+    assert sorted(ticks) == pytest.approx(sorted(eng._m_decode_step._exact),
+                                          abs=1e-6)
+    assert sum(ticks) == pytest.approx(eng._m_decode_step.sum, abs=1e-6)
+    for b, r in zip(build, run):
+        assert b.end <= r.start and b.attrs == r.attrs
+        assert set(b.attrs) == {"active", "bucket"}
+        assert 1 <= b.attrs["active"] <= b.attrs["bucket"] <= 4
+
+
+def test_flight_recorder_reads_the_spans_clock(served, tmp_path):
+    """One pair of clock reads per interval: the flight's program spans
+    and decode ticks ARE the `.run` spans' start and end, and the dump's
+    TTFT tiling assertion keeps holding."""
+    eng, evs, _, _ = served
+    runs = {(e.start, e.end) for e in evs if e.name in RUNS}
+    ticks = [t for t in eng.flight._ticks if t[0] == "decode_tick"]
+    assert ticks and all((t0, t1) in runs for _, t0, t1, _ in ticks)
+    for fl in eng.flight.flights():
+        assert fl.spans and all((t0, t1) in runs
+                                for _, t0, t1, _ in fl.spans)
+        assert fl.first_token_s in {end for _, end in runs}
+    path = str(tmp_path / "trace.json")
+    eng.dump_trace(path)                     # raises if the tiling broke
+    summary = obs.validate_trace(path)
+    assert summary["requests"] == summary["tiled_requests"] == 5
+    assert sorted(eng.stats()["ttft_s"]) == sorted(
+        fl.first_token_s - fl.arrival_s for fl in eng.flight.flights())
+
+
+def test_compile_span_on_a_program_miss(monkeypatch):
+    from paddle_tpu.inference import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_SERVING_EXECUTABLES", {})
+    monkeypatch.setattr(engine_mod, "_SEEN_SERVING_PROGRAMS", set())
+    obs.clear_events()
+    eng = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8)
+    obs.clear_spans()
+    eng.add_request(np.arange(5), max_new_tokens=3)
+    eng.run()
+    comp = [e for e in obs.span_events() if e.name == "serving.compile"]
+    assert [(e.attrs["site"], e.parent) for e in comp] == [
+        ("serving.prefill", "serving.prefill.build"),
+        ("serving.decode", "serving.decode.build")]
+    walls = sorted(e.wall_s for e in obs.compile_events()
+                   if e.site.startswith("serving."))
+    assert walls == sorted(e.end - e.start for e in comp)
+    # a second engine finds the programs: no compile span
+    obs.clear_spans()
+    eng2 = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8)
+    eng2.add_request(np.arange(5), max_new_tokens=3)
+    eng2.run()
+    assert not [e for e in obs.span_events() if e.name == "serving.compile"]
+
+
+def test_verify_window_shares_the_spans_clock():
+    eng = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8,
+                        spec_decode="ngram")
+    obs.clear_spans()
+    pat = np.array([3, 4, 5, 6] * 6)
+    eng.add_request(pat, max_new_tokens=12)
+    eng.run()
+    ver = [e for e in obs.span_events() if e.name == "serving.verify.run"]
+    wins = [t for t in eng.flight._ticks if t[0] == "verify_window"]
+    assert wins and len(ver) == len(wins)
+    assert sorted((e.start, e.end) for e in ver) \
+        == sorted((t0, t1) for _, t0, t1, _ in wins)
+    assert all(set(e.attrs) == {"active", "k"} for e in ver)
+
+
+# ------------------------------------------------------------ arrival_s
+
+def test_arrival_in_the_past_reads_as_queue_wait():
+    """A request that reached the system 0.2 s before `add_request` has
+    waited those 0.2 s: queue wait, TTFT, the flight's enqueue mark and
+    the deadline all run from `arrival_s`."""
+    eng = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8)
+    arrived = time.perf_counter() - 0.2
+    rid = eng.add_request(np.arange(6), max_new_tokens=2,
+                          arrival_s=arrived, max_time_ms=60_000)
+    req = eng._waiting[-1]
+    assert req.arrival_s == arrived
+    assert req.deadline_s == pytest.approx(arrived + 60.0)
+    eng.run()
+    st = eng.stats()
+    assert st["queue_wait_s"][0] >= 0.2 and st["ttft_s"][0] >= 0.2
+    assert eng._m_queue_wait.sum >= 0.2 and eng._m_ttft.sum >= 0.2
+    fl = eng.flight.get(rid)
+    assert fl.arrival_s == arrived and fl.ttft_s == st["ttft_s"][0]
+    eng.flight._check_tiling()
+    # without it the clock runs from the call, as before
+    eng.add_request(np.arange(6), max_new_tokens=2)
+    eng.run()
+    assert eng.stats()["queue_wait_s"][1] < 0.2
+
+
+def test_arrival_in_the_past_can_expire_before_admission():
+    eng = ServingEngine(_tiny_llama(), max_slots=1, kv_block_size=8)
+    rid = eng.add_request(np.arange(6), max_new_tokens=2, max_time_ms=50,
+                          arrival_s=time.perf_counter() - 0.2)
+    assert (rid, None, True) in eng.step()
+    assert eng.finish_reasons[rid] == "timeout"
+
+
+def test_routed_request_ttft_holds_its_inbox_wait():
+    """A request the router took while the replica's driver was inside a
+    step: the engine's TTFT and queue wait hold the time it sat in the
+    inbox, because the clock runs from when `Router.submit` took it."""
+    import threading
+
+    from paddle_tpu.serving import Router
+
+    eng = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8)
+    real_step, real_add = eng.step, eng.add_request
+    in_step, added = threading.Event(), {}
+
+    def slow_first_step():
+        if not in_step.is_set():
+            in_step.set()
+            time.sleep(0.3)
+        return real_step()
+
+    def add_request(prompt, **kw):
+        added[len(prompt)] = time.perf_counter()
+        return real_add(prompt, **kw)
+
+    eng.step, eng.add_request = slow_first_step, add_request
+    router = Router([eng])
+    try:
+        assert router.wait_ready(120)
+        first = router.submit(np.arange(5), max_new_tokens=2)
+        assert in_step.wait(120)             # the driver is in its step
+        t_submit = time.perf_counter()
+        second = router.submit(np.arange(7), max_new_tokens=2)
+        first.result(timeout=120)
+        second.result(timeout=120)
+        in_inbox = added[7] - t_submit
+        assert in_inbox >= 0.2
+        fl = eng.flight.flights()[1]
+        assert fl.prompt_len == 7
+        assert t_submit <= fl.arrival_s <= t_submit + 0.05
+        assert fl.ttft_s >= fl.admitted_s - fl.arrival_s >= in_inbox
+        assert max(eng.stats()["queue_wait_s"]) >= in_inbox
+    finally:
+        router.close()
+
+
+# ------------------------------------------------- the engine's own metrics
+
+def test_tpot_is_the_gap_a_request_sees_not_the_tick_over_the_batch():
+    """With 4 slots decoding, `serving_tpot_seconds` reads about one tick
+    a token (each request gets one token a tick), not a quarter of it."""
+    eng = ServingEngine(_tiny_llama(), max_slots=4, kv_block_size=8)
+    rs = np.random.RandomState(1)
+    for _ in range(4):                        # warm the programs
+        eng.add_request(rs.randint(0, 128, (6,)), max_new_tokens=3)
+    eng.run()
+    tp0, st0 = len(eng._m_tpot._exact), len(eng._m_decode_step._exact)
+    for _ in range(4):
+        eng.add_request(rs.randint(0, 128, (6,)), max_new_tokens=24)
+    obs.clear_spans()
+    eng.run()
+    steps = [e for e in obs.span_events() if e.name == "serving.step"
+             and e.attrs["active"] == 4]
+    tick = float(np.median([e.end - e.start for e in steps]))
+    tpot = float(np.median(eng._m_tpot._exact[tp0:]))
+    assert len(eng._m_tpot._exact) - tp0 == 4 * 23      # one a token
+    assert abs(tpot - tick) <= 0.2 * tick, (tpot, tick)
+    decode = float(np.median(eng._m_decode_step._exact[st0:]))
+    assert tpot > 0.8 * decode                # not decode / 4
+
+
+def test_ttft_and_queue_wait_samples_are_bounded():
+    eng = ServingEngine(_tiny_llama(), max_slots=2, kv_block_size=8)
+    assert eng.ttfts.maxlen == eng.queue_waits.maxlen == DEFAULT_EXACT_CAP
+    eng.ttfts.extend(range(DEFAULT_EXACT_CAP + 10))
+    eng.add_request(np.arange(5), max_new_tokens=2)
+    eng.run()
+    st = eng.stats()
+    assert len(st["ttft_s"]) == DEFAULT_EXACT_CAP
+    assert isinstance(st["ttft_s"], list) and st["ttft_s"][-1] < 100
+    assert len(st["queue_wait_s"]) == 1
+
+
+# ------------------------------------------------------------------- jit
+
+def test_to_static_spans_of_a_fresh_function():
+    """One `jit.warmup`, one `jit.discover`, one `jit.compile`, then every
+    call a `jit.call` around a `jit.dispatch`."""
+    import paddle_tpu.nn as nn
+
+    paddle.seed(0)
+    net = nn.Linear(4, 3)
+
+    def fwd_for_spans(x):
+        return net(x).sum()
+
+    step = paddle.jit.to_static(fwd_for_spans)
+    x = paddle.to_tensor(np.ones((2, 4), np.float32))
+    obs.clear_spans()
+    outs = [float(step(x)) for _ in range(6)]
+    assert len(set(outs)) == 1
+    evs = [e for e in obs.span_events()
+           if e.attrs.get("fn") == "fwd_for_spans"]
+    by = {}
+    for e in evs:
+        by.setdefault(e.name, []).append(e)
+    assert {k: len(v) for k, v in by.items()} == {
+        "jit.call": 6, "jit.warmup": 1, "jit.discover": 1,
+        "jit.compile": 1, "jit.dispatch": 3}
+    calls = sorted(by["jit.call"], key=lambda e: e.start)
+    inner = [by["jit.warmup"][0], by["jit.discover"][0],
+             by["jit.compile"][0], *sorted(by["jit.dispatch"],
+                                           key=lambda e: e.start)]
+    for c, i in zip(calls, inner):
+        assert i.parent == "jit.call"
+        assert c.start <= i.start and i.end <= c.end
+    for a, b in zip(calls, calls[1:]):
+        assert a.end <= b.start
+
+
+def test_train_flight_dispatch_is_the_dispatch_span():
+    """The training flight recorder takes the `jit.dispatch` span's own
+    start and end for its `dispatch:<fn>` span."""
+    import paddle_tpu.nn as nn
+    from paddle_tpu.obs import train_flight
+
+    net = nn.Linear(4, 3)
+
+    def fwd_for_flight(x):
+        return net(x).sum()
+
+    step = paddle.jit.to_static(fwd_for_flight)
+    x = paddle.to_tensor(np.ones((2, 4), np.float32))
+    for _ in range(3):
+        step(x)
+    rec = obs.TrainFlightRecorder(registry=obs.Registry())
+    obs.clear_spans()
+    prev = train_flight.set_current(rec)
+    try:
+        t = time.perf_counter()
+        rec.step_begin(0, 0, t, t)
+        step(x)
+        t1 = time.perf_counter()
+        rec.step_end(t1, t1 - t)
+    finally:
+        train_flight.set_current(prev)
+    (disp,) = [e for e in obs.span_events() if e.name == "jit.dispatch"]
+    spans = [s for st in rec.steps() for s in st.spans
+             if s[0] == "dispatch:fwd_for_flight"]
+    assert [(s[1], s[2]) for s in spans] == [(disp.start, disp.end)]

@@ -13,6 +13,7 @@ the queue-wait/prefill TTFT decomposition, and the regression test that
 import json
 import os
 import sys
+import time
 import urllib.request
 
 import numpy as np
@@ -160,27 +161,96 @@ class TestJsonl:
 
 # ---------------------------------------------------------------- spans
 class TestSpans:
-    def test_nesting_paths(self):
+    def test_record_holds_start_end_parent_attrs(self):
+        """(was test_nesting_paths) the record of the one span log."""
         obs.clear_spans()
-        with obs.span("outer"):
+        with obs.span("outer", rid=7, site="s"):
             with obs.span("inner"):
                 pass
         evs = obs.span_events(clear=True)
-        assert [e["path"] for e in evs] == ["outer/inner", "outer"]
-        assert [e["depth"] for e in evs] == [1, 0]
-        assert all(e["seconds"] >= 0 for e in evs)
+        assert [e.name for e in evs] == ["inner", "outer"]  # as they ended
+        inner, outer = evs
+        assert isinstance(inner, obs.SpanRecord)
+        assert inner.parent == "outer" and outer.parent is None
+        assert outer.attrs == {"rid": 7, "site": "s"} and inner.attrs == {}
+        assert all(e.start <= e.end for e in evs)
+        assert outer.start <= inner.start and inner.end <= outer.end
+        assert tuple(outer) == ("outer", outer.start, outer.end, None,
+                                outer.attrs)
 
-    def test_span_feeds_histogram(self):
-        h = Histogram("span_h", "")
-        with obs.span("timed", histogram=h):
-            pass
-        assert h.count == 1
-
-    def test_step_span_off_tpu(self):
+    def test_span_object_carries_its_clock_reads(self):
+        """(was test_span_feeds_histogram) whoever times the interval a
+        span times takes the span's own reads; attrs known only at the end
+        reach the log."""
         obs.clear_spans()
-        with obs.step_span(3):
+        h = Histogram("span_h", "")
+        with obs.span("timed") as sp:
+            sp.attrs["admitted"] = 2
+        h.observe(sp.end - sp.start)
+        (rec,) = obs.span_events(clear=True)
+        assert (rec.start, rec.end) == (sp.start, sp.end)
+        assert rec.attrs == {"admitted": 2}
+        assert h.count == 1 and h.sum == rec.end - rec.start
+
+    def test_log_says_from_when_it_is_whole(self, monkeypatch):
+        """(was test_step_span_off_tpu) a reader can tell a cut log from
+        a whole one: clearing and wrapping both move span_log_start()."""
+        from collections import deque
+
+        from paddle_tpu.obs import trace
+
+        t0 = time.perf_counter()
+        obs.clear_spans()
+        cleared = obs.span_log_start()
+        assert t0 <= cleared <= time.perf_counter()
+        monkeypatch.setattr(trace, "SPAN_LOG_CAP", 4)
+        monkeypatch.setattr(trace, "_span_log", deque(maxlen=4))
+        for i in range(3):
+            with obs.span("s", i=i):
+                pass
+        assert obs.span_log_start() == cleared     # nothing dropped yet
+        for i in range(3, 9):
+            with obs.span("s", i=i):
+                pass
+        evs = obs.span_events()
+        assert [e.attrs["i"] for e in evs] == [5, 6, 7, 8]
+        # whatever was dropped ended before the oldest record kept did
+        assert obs.span_log_start() == evs[0].end > cleared
+
+    def test_span_shows_in_a_cpu_xplane(self, tmp_path):
+        """The annotation is emitted on every backend: a capture on the
+        CPU holds the span, nested as the log has it, with its attrs."""
+        from jax.profiler import ProfileData
+
+        from benchmark import trace_reduce
+
+        with obs.capture_trace(str(tmp_path)):
+            with obs.span("t_obs.outer", rid=3):
+                with obs.span("t_obs.inner", site="here"):
+                    time.sleep(0.002)
+        got = {}
+        path = trace_reduce.find_xplane(str(tmp_path))
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("t_obs."):
+                        got[e.name] = (e.start_ns, e.start_ns
+                                       + e.duration_ns, dict(e.stats))
+        assert set(got) == {"t_obs.outer", "t_obs.inner"}
+        o, i = got["t_obs.outer"], got["t_obs.inner"]
+        assert o[0] <= i[0] and i[1] <= o[1] and i[1] - i[0] >= 2e6
+        assert o[2] == {"rid": 3} and i[2] == {"site": "here"}
+
+    def test_span_pops_its_parent_on_an_exception(self):
+        obs.clear_spans()
+        with pytest.raises(KeyError):
+            with obs.span("a"):
+                with obs.span("b"):
+                    raise KeyError("x")
+        with obs.span("c"):
             pass
-        assert obs.span_events(clear=True)[-1]["name"] == "train_step[3]"
+        assert [(e.name, e.parent) for e in obs.span_events(clear=True)] \
+            == [("b", "a"), ("a", None), ("c", None)]
 
 
 # -------------------------------------------------------------- logging
