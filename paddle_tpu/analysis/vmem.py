@@ -140,31 +140,55 @@ def audit_tune_cache(entries=None, limit_mb=None,
     return findings
 
 
+def _decode_pages_per_step(head_dim, block_size, itemsize, kv_heads, pages):
+    """The kernel's own compute block at this geometry (`pages` None: a
+    table longer than any block)."""
+    from ..ops.pallas_decode import pages_per_step
+
+    return pages_per_step(pages if pages is not None else 1 << 30,
+                          block_size, kv_heads, _ceil128(head_dim), itemsize)
+
+
 def decode_vmem_bytes(head_dim: int, block_size: int, group: int = 16,
-                      itemsize: int = 2) -> int:
+                      itemsize: int = 2, kv_heads: int = 1,
+                      pages: int | None = None) -> int:
     """Working-set estimate for one grid step of the paged flash-decode
-    kernel (ops/pallas_decode._decode_kernel): the GQA-packed query tile
-    q[gp, D] plus one k and one v cache block [block_size, D] streamed
-    per grid step (double-buffered by the pipeline), the o[gp, D] output
-    tile, and the declared f32 scratch acc[gp, D] + m/l[gp, 128]x2."""
+    kernel (ops/pallas_decode._decode_kernel). The kernel sizes its own
+    compute block — `pallas_decode.pages_per_step`, asked here with the
+    same numbers — and one step holds: the K and V stream buffers
+    [2, pages_per_step, kv_heads, block_size, D] it fills by DMA; the q
+    and o blocks [kv_heads, gp, D] the grid pipeline double-buffers; the
+    declared f32 scratch, one accumulator acc[gp, D] + m/l[gp, 128]x2 a
+    KV head; and one head's temporaries (its key and value operands over
+    the whole compute block, an f32 copy on the way when the cache is
+    quantized, and the f32 score and probability tiles). `pages` caps the
+    compute block at a table's length (None: a table longer than any
+    block)."""
     dp = _ceil128(head_dim)
     gp = max(16, (int(group) + 15) // 16 * 16)
     lanes = 128
-    io = (gp * dp                    # q
-          + 2 * block_size * dp      # k, v cache blocks
-          + gp * dp)                 # o
-    scratch = (gp * dp + 2 * gp * lanes) * 4
-    return 2 * io * itemsize + scratch
+    pps = _decode_pages_per_step(head_dim, block_size, itemsize, kv_heads,
+                                 pages)
+    t = pps * block_size
+    op_size = max(2, itemsize)          # a quantized cache computes in bf16
+    stream = 2 * 2 * pps * kv_heads * block_size * dp * itemsize
+    io = 2 * 2 * kv_heads * gp * dp * op_size
+    scratch = kv_heads * (gp * dp + 2 * gp * lanes) * 4
+    head = (2 * t * dp * op_size + (t * dp * 4 if itemsize < 2 else 0)
+            + 4 * gp * t * 4)
+    return stream + io + scratch + head
 
 
 def audit_decode_config(head_dim: int, block_size: int, group: int = 16,
                         itemsize: int = 2, limit_mb=None,
                         pool_blocks=None, slots=None, seq_pages=None,
-                        cached_blocks: int = 0,
+                        cached_blocks: int = 0, kv_heads: int = 1,
                         loc: str = "pallas-decode-config") -> list[Finding]:
     """D5 for the decode kernel's launch config at a model's head
-    geometry — an oversized kv block (FLAGS_kv_block_size) fails lint
-    here instead of Mosaic at serving time.
+    geometry. The kernel shrinks its compute block to its VMEM share by
+    itself, down to one page; a geometry whose single page (every KV
+    head's rows, K and V, double-buffered) is already over the budget
+    fails lint here instead of Mosaic at serving time.
 
     When `pool_blocks`/`slots`/`seq_pages` are given it also audits the
     BLOCK-POOL budget: a pool that cannot hold `slots` full-length
@@ -174,19 +198,26 @@ def audit_decode_config(head_dim: int, block_size: int, group: int = 16,
     pool that is too small for `slots` cold sequences can still be
     healthy under a shared-prompt workload."""
     limit = _limit_bytes(limit_mb)
-    est = decode_vmem_bytes(head_dim, block_size, group, itemsize)
+    est = decode_vmem_bytes(head_dim, block_size, group, itemsize,
+                            kv_heads, seq_pages)
     findings = []
     if est > 0.8 * limit:
         sev = "warning" if est > limit else "note"
         verdict = "exceeds" if est > limit else "is within 20% of"
+        pps = _decode_pages_per_step(head_dim, block_size, itemsize,
+                                     kv_heads, seq_pages)
         findings.append(Finding(
             "vmem-budget", sev, loc,
-            f"paged decode blocks (block_size={block_size}, head_dim="
-            f"{head_dim}, group={group}, itemsize {itemsize}) estimate "
+            f"paged decode at {pps} page(s) a grid step (block_size="
+            f"{block_size}, kv_heads={kv_heads}, head_dim={head_dim}, "
+            f"group={group}, itemsize {itemsize}) estimates "
             f"{est / 2**20:.1f} MiB VMEM — {verdict} the "
-            f"{limit / 2**20:.0f} MiB per-core budget; lower "
-            "FLAGS_kv_block_size for this geometry",
+            f"{limit / 2**20:.0f} MiB per-core budget; the kernel already "
+            "streams as few pages a step as its share allows, so a page "
+            "of every KV head is too large: lower FLAGS_kv_block_size for "
+            "this geometry",
             {"head_dim": head_dim, "block_size": block_size,
+             "kv_heads": kv_heads, "pages_per_step": pps,
              "estimate_bytes": est, "limit_bytes": limit}))
     if pool_blocks is not None and slots is not None \
             and seq_pages is not None:

@@ -1741,11 +1741,31 @@ class ServingEngine:
         self._m_prefill_tokens.inc(n)
         return tok, t_end
 
+    def _kv_steps(self, bucket):
+        """Grid steps a layer of the `paged_decode` kernel at this slot
+        bucket; 0 where the router keeps the XLA composition."""
+        from ..ops import pallas_decode as pd
+
+        pool = self.cache.k.shape[1:]                # [N, H_kv, rows, D]
+        int4 = self.kv_mode == "int4"
+        if not pd.use_pallas_decode(
+                jax.ShapeDtypeStruct((bucket, self.spec.num_heads,
+                                      self.spec.head_dim),
+                                     self.params["embed"].dtype),
+                jax.ShapeDtypeStruct(pool, self.cache.k.dtype),
+                jax.ShapeDtypeStruct((bucket, self.pages), jnp.int32), int4):
+            return 0
+        return pd.kv_steps(bucket, self.pages, pool[2], pool[1], pool[3],
+                           self.cache.k.dtype.itemsize)
+
     def _decode(self, active):
         from ..jit.api import default_buckets
 
         bucket = min(default_buckets(len(active)), self.max_slots)
-        at = {"active": len(active), "bucket": int(bucket)}
+        at = {"active": len(active), "bucket": int(bucket),
+              "live_pages": int(
+                  (self._slot_pos[active] // self.block_size + 1).sum()),
+              "kv_steps": self._kv_steps(bucket)}
         c = self.cache
         with _span("serving.decode.build", **at) as build:
             reqs = [self._slot_req[i] for i in active]

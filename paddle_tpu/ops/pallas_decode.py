@@ -7,27 +7,47 @@ kernel the reference ships for serving
 PagedAttention's block tables (Kwon et al., vLLM).
 
 TPU-native design (NOT a kernel translation):
-  - The KV cache lives as fixed-size blocks `[num_blocks, H_kv,
+  - The KV cache lives as fixed-size blocks (pages) `[num_blocks, H_kv,
     block_size, D]` and each sequence owns a BLOCK TABLE `[pages]` of
-    block ids. The kernel grid is `(seq, kv_head, page)`; the page axis is
-    the innermost grid dimension, so the f32 running-max/sum/acc scratch
-    persists across the cache sweep — exactly the flash-decode split-K
-    merge, with the block table consulted by the BlockSpec index_map via
-    scalar prefetch (the DMA engine gathers non-contiguous cache blocks;
-    no gather tensor is ever materialized).
-  - Layout note: the issue-level sketch writes `[num_blocks, block_size,
-    H_kv, D]`; the cache here is `[num_blocks, H_kv, block_size, D]` so a
-    per-(block, head) tile is the contiguous (sublane=tokens, lane=D)
-    MXU tile — with H_kv inside, every block fetch would stride by head.
+    block ids. The kernel grid is `(slot, kv_block)`: one step attends
+    every KV head of a slot to one COMPUTE BLOCK of `pages_per_step` pages
+    (512 tokens at 8 KV heads x 128 in bf16). The kv_block axis is the
+    innermost grid dimension, so the f32 running-max/sum/acc scratch (one
+    a KV head) persists across the cache sweep — exactly the flash-decode
+    split-K merge.
+  - The pools stay in HBM and the kernel copies pages itself
+    (`make_async_copy`, page ids from the scalar-prefetched block table)
+    into a double-buffered VMEM scratch: the next block's pages — or the
+    next slot's first block — are in flight while this block computes. No
+    gather tensor is ever materialized, only pages that hold tokens are
+    copied, and a step wholly past its slot's end does nothing. The cost
+    of the walk is its step and descriptor count, not its bytes (a step a
+    page of one head took 32,768 steps a layer to read 50 MB: PERF.md, PR
+    29), hence many pages a step.
+  - Layout note: with H_kv OUTSIDE the tokens a page's slab of all heads
+    `[H_kv, block_size, D]` is contiguous, so one descriptor a page moves
+    every head, and a per-(page, head) tile is the contiguous (sublane=
+    tokens, lane=D) MXU tile; `[num_blocks, block_size, H_kv, D]` would
+    stride every head's tile by head.
+  - `pages_per_step` follows from the shapes alone (`pages_per_step()`:
+    K and V, double-buffered, in a fixed quarter of the scoped VMEM); a
+    geometry whose one page fills that share streams a page a step and
+    still gets the all-heads copy. analysis D5 reads the same function.
   - GQA packing: all `H_q/H_kv` query heads sharing a KV head ride ONE
     [group, D] tile (padded to the sublane minimum), so the whole group's
-    scores come from one MXU pass per cache block. Decode is pure HBM
-    bandwidth: every cache byte is read exactly once per step.
+    scores come from one MXU pass per compute block. Decode is pure HBM
+    bandwidth: every live cache byte is read exactly once per step.
+  - The two matmuls take the cache's own float dtype (the query's, for a
+    quantized cache) with f32 accumulation, as the XLA oracle and the
+    chunk-prefill path do: in f32 the MXU passes, not the bytes, bound a
+    step. Scores are scaled, masked and exponentiated in f32; the sum is
+    taken before the probabilities are rounded for the second matmul.
   - Optional int8 KV: the cache stores int8 with ONE f32 scale per block
     (text/paged_cache.py maintains them by block requantization on
-    append); the kernel reads per-(seq, page) scales from scalar-prefetch
-    SMEM and folds k's scale into the logits, v's into the pv partial —
-    decode cache reads halve again on top of bf16.
+    append); the kernel reads per-(slot, page) scales from scalar-prefetch
+    SMEM and folds k's scale into the logits, v's into the probabilities,
+    a page of the compute block at a time — decode cache reads halve again
+    on top of bf16. int4 packs two tokens a byte and is unpacked in VMEM.
 
 Same layering as pallas_attention.py / pallas_norm.py: bf16/f32 in/out
 with f32 VMEM accumulation, `interpret` mode off-TPU (how the parity
@@ -65,109 +85,221 @@ _MIN_ELEMS = 1 << 16
 _SUPPORTED_DTYPES = ("float32", "bfloat16", "float16", "int8", "int4")
 
 
+# ------------------------------------------------------------------ sizing
+
+#: VMEM the K and V stream buffers take together, both double-buffered: a
+#: quarter of the 16 MiB a kernel gets by default, the rest left for the
+#: per-head temporaries (dequantized keys, scores) and the accumulators
+_STREAM_VMEM_BYTES = 4 << 20
+
+
+def pages_per_step(pages, block_rows, kv_heads, head_dim, itemsize):
+    """Cache pages one grid step streams: the largest power of two whose K
+    and V slabs (every KV head of a page: `kv_heads * block_rows *
+    head_dim * itemsize` bytes each), double-buffered, fit
+    `_STREAM_VMEM_BYTES`; never more than the table holds, never fewer
+    than one. `block_rows` is the STORED rows of a page (block_size / 2 for
+    int4). ONE definition: the kernel's grid, the engine's `kv_steps` span
+    attribute and analysis D5 all read it."""
+    page = int(kv_heads) * int(block_rows) * int(head_dim) * int(itemsize)
+    fit = max(1, _STREAM_VMEM_BYTES // (4 * page))
+    return max(1, min(1 << (fit.bit_length() - 1), int(pages)))
+
+
+def kv_steps(slots, pages, block_rows, kv_heads, head_dim, itemsize):
+    """Grid steps of one kernel call: `slots` x compute blocks a slot."""
+    pps = pages_per_step(pages, block_rows, kv_heads, head_dim, itemsize)
+    return int(slots) * -(-int(pages) // pps)
+
+
 # ------------------------------------------------------------------ kernel
 
-def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, has_scale,
-                   packed=False):
-    """One (seq, kv_head, page) grid step: the GQA query group attends to
-    one cache block, merged into the running flash state.
+def _cdiv(a, b: int):
+    # pl.cdiv mixes an i32 tracer with an i64 weak int under x64
+    return (a + (b - 1)) // b
+
+
+def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
+                   has_scale, packed):
+    """One (slot, kv_block) grid step: every KV head's GQA query group
+    attends to one compute block of `pps` cache pages, merged into the
+    running flash state.
 
     tab_ref/len_ref (+ ks_ref/vs_ref when has_scale): scalar-prefetch SMEM
-    (block table [S, P], kv lengths [S], per-(seq, page) dequant scales).
-    q is [1, 1, Gp, D]; k/v blocks are [1, 1, block_size, D] picked by the
-    index_map from the block table — or [1, 1, block_size/2, D] int4-packed
-    when `packed` (split-half along tokens: byte t holds token t in the low
-    nibble, token bs/2 + t in the high — unpacked HERE so the packed bytes
-    are the only cache traffic).
+    (block table [S, P], kv lengths [S], per-(slot, page) dequant scales).
+    q/o are [1, H_kv, Gp, D] VMEM blocks; the pools stay in HBM and pages
+    come by DMA into k_buf/v_buf [2, pps, H_kv, rows, D] — one descriptor a
+    page moves every head (the pool's [N, H_kv, rows, D] layout makes that
+    slab contiguous). `rows` is block_size, or block_size / 2 int4-packed
+    (split-half along tokens: byte t holds token t in the low nibble, token
+    bs/2 + t in the high — unpacked HERE so the packed bytes are the only
+    cache traffic). Only pages that hold tokens are copied; a step wholly
+    past its slot's end does nothing. Every slot owns at least its first
+    step (a zero length reads one page and masks all of it), so the step
+    after a slot's last live one is always the next slot's first: that is
+    the block whose copies start before this block's compute and are
+    waited on by the step that consumes them. step_ref counts live steps;
+    its parity names the buffer.
     """
     if has_scale:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s = rest
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s = rest
-    si = pl.program_id(0)
-    pi = pl.program_id(2)
-    n_p = pl.num_programs(2)
+        ks_ref, vs_ref, *rest = rest
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, step_ref,
+     acc, m_s, l_s) = rest
+    si, ji = pl.program_id(0), pl.program_id(1)
+    n_s, n_j = pl.num_programs(0), pl.num_programs(1)
+    hkv, rows, d = k_buf.shape[2:]
+    gp = q_ref.shape[2]
+    t = pps * block_size
+    # matmul operands: the cache's own float dtype, or the query's for a
+    # quantized cache (int8 values are exact in bf16); f32 accumulation
+    cdt = k_buf.dtype if jnp.issubdtype(k_buf.dtype, jnp.floating) \
+        else q_ref.dtype
 
-    def unpack(p):
-        # widened first: v5e's Mosaic legalizes no shift on vector<i8>
-        p = p.astype(jnp.int32)
-        lo = jnp.right_shift(jnp.left_shift(p, 28), 28)
-        hi = jnp.right_shift(p, 4)
-        return jnp.concatenate([lo, hi], axis=0)       # [bs, D]
+    def live_pages(s):
+        return jnp.maximum(_cdiv(len_ref[s], block_size), 1)
 
-    @pl.when(pi == 0)
+    def each_copy(s, j, buf, do):
+        """`do` on the K and the V copy of every live page of block j of
+        slot s into buffer buf (a copy is waited on through a descriptor
+        like the one that started it); returns how many pages."""
+        first = j * pps
+        n = jnp.minimum(live_pages(s) - first, pps)
+
+        def page(i, c):
+            pid = tab_ref[s, first + i]
+            do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, i],
+                                     sems.at[0, buf]))
+            do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, i],
+                                     sems.at[1, buf]))
+            return c
+        jax.lax.fori_loop(0, n, page, None)
+        return n
+
+    def start(s, j, buf):
+        each_copy(s, j, buf, lambda cp: cp.start())
+
+    def tokens(ref, buf, h):
+        """Head h's [t, D] operand from the pages of buffer buf."""
+        x = ref[buf, :, h]                               # [pps, rows, D]
+        if packed:
+            # widened first: v5e's Mosaic legalizes no shift on vector<i8>
+            x = x.astype(jnp.int32)
+            x = jnp.concatenate(
+                [jnp.right_shift(jnp.left_shift(x, 28), 28),
+                 jnp.right_shift(x, 4)], axis=1)         # [pps, bs, D]
+        if x.dtype != cdt:
+            x = x.astype(jnp.float32).astype(cdt)
+        return x.reshape(t, d)
+
+    def scale_row(ref):
+        """[1, t] per-token dequant scales of this block, a page at a
+        time from SMEM."""
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        return jax.lax.fori_loop(
+            0, pps, lambda i, row: jnp.where(cols >= i * block_size,
+                                             ref[si, ji * pps + i], row),
+            jnp.zeros((1, t), jnp.float32))
+
+    @pl.when((si == 0) & (ji == 0))
+    def _first():
+        step_ref[0] = 0
+        start(0, 0, 0)
+
+    @pl.when(ji == 0)
     def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_s[:] = jnp.full_like(m_s, _NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
 
     seq_len = len_ref[si]
-    page_start = pi * block_size
+    n_blk = _cdiv(live_pages(si), pps)
 
-    @pl.when(page_start < seq_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * np.float32(scale)  # [Gp, D]
-        k = k_ref[0, 0]                                          # [bs, D]
-        if packed:
-            k = unpack(k)
-        k = k.astype(jnp.float32)
-        if has_scale:
-            k = k * ks_ref[si, pi]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        # the tail page is partially valid; interior pages are full — one
-        # masked path keeps the kernel small (the page grid is the cost)
-        cols = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    @pl.when(ji < n_blk)
+    def _block():
+        buf = step_ref[0] % 2
+        step_ref[0] = step_ref[0] + 1
+        more = ji + 1 < n_blk
+        nxt_s = jnp.where(more, si, si + 1)
+
+        @pl.when(nxt_s < n_s)
+        def _prefetch():
+            start(nxt_s, jnp.where(more, ji + 1, 0), 1 - buf)
+
+        n = each_copy(si, ji, buf, lambda cp: cp.wait())
+        if jnp.issubdtype(v_buf.dtype, jnp.floating):
+            # pages of the tail block that no copy wrote: whatever the
+            # buffer held (NaN bits included) would meet p = 0 in pv
+            def zero(i, c):
+                v_buf[buf, i] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+                return c
+            jax.lax.fori_loop(n, pps, zero, None)
+
+        # the tail block is partially valid, interior blocks are full: one
+        # masked path keeps the kernel small
+        cols = ji * t + jax.lax.broadcasted_iota(jnp.int32, (gp, t), 1)
         mask = cols < seq_len
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_s[:, :1]
-        l_prev = l_s[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, _ZERO)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0]                                          # [bs, D]
-        if packed:
-            v = unpack(v)
-        v = v.astype(jnp.float32)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
         if has_scale:
-            pv = pv * vs_ref[si, pi]
-        acc[:] = acc[:] * alpha + pv
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+            ks_row, vs_row = scale_row(ks_ref), scale_row(vs_ref)
 
-    @pl.when(pi == n_p - 1)
+        def head(h, c):
+            q = q_ref[0, h].astype(cdt)                          # [Gp, D]
+            s = jax.lax.dot_general(q, tokens(k_buf, buf, h),
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * np.float32(scale)
+            if has_scale:
+                s = s * ks_row
+            s = jnp.where(mask, s, _NEG_INF)
+
+            m_prev = m_s[h][:, :1]
+            l_prev = l_s[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new), _ZERO)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            if has_scale:
+                p = p * vs_row
+            pv = jax.lax.dot_general(p.astype(cdt), tokens(v_buf, buf, h),
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc[h] = acc[h] * alpha + pv
+            m_s[h] = jnp.broadcast_to(m_new, m_s.shape[1:])
+            l_s[h] = jnp.broadcast_to(l_new, l_s.shape[1:])
+            return c
+        jax.lax.fori_loop(0, hkv, head, None)
+
+    @pl.when(ji == n_j - 1)
     def _finish():
-        l = l_s[:, :1]
+        l = l_s[...][:, :, :1]
         safe_l = jnp.where(l == _ZERO, _ONE, l)
-        o_ref[0, 0] = (acc[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
 
 
 def paged_decode_attention_raw(q, k_cache, v_cache, block_tables, seq_lens,
-                               k_scale=None, v_scale=None, kv_int4=False):
+                               k_scale=None, v_scale=None, kv_int4=False,
+                               pages_per_step_=None):
     """The Pallas kernel path. q [S, H_q, D]; caches [N, H_kv, bs, D]
     (int8 when k_scale/v_scale [N] f32 are given; int4-packed
     [N, H_kv, bs/2, D] when kv_int4); block_tables [S, P] int32 (entries
     < 0 tolerated as padding); seq_lens [S] valid kv lengths. Returns
-    [S, H_q, D] in q.dtype."""
+    [S, H_q, D] in q.dtype. `pages_per_step_` overrides the derived
+    compute block (tests and sweeps; nothing in the library passes it)."""
     with _x64_guard():
         return _paged_decode_x32(q, k_cache, v_cache, block_tables,
-                                 seq_lens, k_scale, v_scale, kv_int4)
+                                 seq_lens, k_scale, v_scale, kv_int4,
+                                 pages_per_step_)
 
 
 def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
-                      k_scale=None, v_scale=None, kv_int4=False):
+                      k_scale=None, v_scale=None, kv_int4=False,
+                      pages_per_step_=None):
     s_n, hq, d = q.shape
-    n_blocks, hkv, bs, dc = k_cache.shape
+    n_blocks, hkv, rows, dc = k_cache.shape
+    bs = rows
     if kv_int4:
         if k_scale is None:
             raise ValueError("int4 KV needs per-block scales")
-        bs = bs * 2          # logical tokens per block (two per byte)
+        bs = rows * 2        # logical tokens per block (two per byte)
     if d != dc:
         raise ValueError(f"head_dim mismatch: q {d} vs cache {dc}")
     if hq % hkv:
@@ -178,47 +310,41 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
     gp = _ceil_to(max(g, 16), 16)
     q4 = q.reshape(s_n, hkv, g, d)
     q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    tables = jnp.maximum(block_tables, 0).astype(jnp.int32)
+    pages = block_tables.shape[1]
+    pps = pages_per_step_ or pages_per_step(pages, rows, hkv, d,
+                                            k_cache.dtype.itemsize)
+    n_blk = -(-pages // pps)
+    # whole compute blocks: the pad pages are never live, so never copied
+    tables = jnp.pad(jnp.maximum(block_tables, 0).astype(jnp.int32),
+                     ((0, 0), (0, n_blk * pps - pages)))
     lens = seq_lens.astype(jnp.int32)
-    pages = tables.shape[1]
     scale = 1.0 / float(np.sqrt(d))
     has_scale = k_scale is not None
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_size=bs,
-                               has_scale=has_scale, packed=kv_int4)
+                               pps=pps, has_scale=has_scale, packed=kv_int4)
 
-    # index maps see (grid ids..., *scalar-prefetch refs); the cache block
-    # index comes straight from the prefetched block table — the grid
-    # pipeline DMAs non-contiguous pages, no gather materializes. Pages at
-    # or past the sequence length clamp to the LAST VALID page: the
-    # pipeline elides the DMA when consecutive grid steps resolve to the
-    # same block, so a long-budget request early in decode (table full of
-    # allocated-but-unwritten pages) doesn't stream dead cache blocks —
-    # the in-kernel pl.when already skips their compute.
-    def kv_index(s, h, p, tab, lens_ref, *refs):
-        last = jnp.maximum(lens_ref[s] - 1, 0) // bs
-        return (tab[s, jnp.minimum(p, last)], h, 0, 0)
-
-    q_spec = pl.BlockSpec((1, 1, gp, d),
-                          lambda s, h, p, *refs: (s, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, k_cache.shape[2], d), kv_index)
-    o_spec = pl.BlockSpec((1, 1, gp, d),
-                          lambda s, h, p, *refs: (s, h, 0, 0))
+    qo_spec = pl.BlockSpec((1, hkv, gp, d), lambda s, j, *refs: (s, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [tables, lens]
     if has_scale:
-        # per-(seq, page) dequant scales, gathered host-of-kernel from the
-        # per-block scales (tiny: S*P f32 in SMEM)
+        # per-(slot, page) dequant scales, gathered host-of-kernel from
+        # the per-block scales (tiny: S*P f32 in SMEM)
         args += [k_scale[tables].astype(jnp.float32),
                  v_scale[tables].astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(args),
-        grid=(s_n, hkv, pages),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[o_spec],
+        grid=(s_n, n_blk),
+        in_specs=[qo_spec, pool_spec, pool_spec],
+        out_specs=[qo_spec],
         scratch_shapes=[
-            pltpu.VMEM((gp, d), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
+            pltpu.VMEM((2, pps, hkv, rows, d), k_cache.dtype),
+            pltpu.VMEM((2, pps, hkv, rows, d), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((hkv, gp, d), jnp.float32),
+            pltpu.VMEM((hkv, gp, 128), jnp.float32),
+            pltpu.VMEM((hkv, gp, 128), jnp.float32),
         ],
     )
     out, = pl.pallas_call(

@@ -360,16 +360,43 @@ class TestD5DecodeConfig:
     def test_default_decode_config_fits(self):
         assert analysis.audit_decode_config(128, 16) == []
 
+    @pytest.mark.parametrize("kv_heads,group,itemsize", [
+        (8, 4, 2), (32, 1, 2), (16, 1, 2), (8, 4, 1)],
+        ids=["mistral_gqa", "llama2_mha", "cerebras", "mistral_int8"])
+    def test_served_geometries_fit(self, kv_heads, group, itemsize):
+        # the kernel cuts its compute block to its share: every served
+        # head geometry sits well under the budget at the default page
+        assert analysis.audit_decode_config(
+            128, 16, group=group, itemsize=itemsize, kv_heads=kv_heads,
+            seq_pages=256, pool_blocks=4097, slots=16) == []
+        est = analysis.decode_vmem_bytes(128, 16, group, itemsize, kv_heads,
+                                         256)
+        assert 4 * 2**20 < est < 8 * 2**20
+
     def test_oversized_block_fires(self):
         fs = analysis.audit_decode_config(128, 32768)
         assert fs and fs[0].severity == "warning"
         assert "FLAGS_kv_block_size" in fs[0].message
+        # the kernel was already down to a page a step
+        assert fs[0].data["pages_per_step"] == 1
+
+    def test_estimate_reads_the_kernels_own_sizing(self):
+        from paddle_tpu.ops import pallas_decode
+
+        # the serve cell: 32 pages a step, K and V double-buffered = 4 MiB
+        assert pallas_decode.pages_per_step(256, 16, 8, 128, 2) == 32
+        est = analysis.decode_vmem_bytes(128, 16, 4, 2, 8, 256)
+        assert est > pallas_decode._STREAM_VMEM_BYTES == 4 * 32 * 8 * 16 \
+            * 128 * 2
+        # a short table caps the block, and the estimate with it
+        assert analysis.decode_vmem_bytes(128, 16, 4, 2, 8, 4) < est / 4
 
     def test_estimator_monotonic_in_block_size(self):
         # decode_vmem_bytes(head_dim, block_size, ...) — same order as
-        # audit_decode_config
-        a = analysis.decode_vmem_bytes(128, 16)
-        b = analysis.decode_vmem_bytes(128, 256)
+        # audit_decode_config. Past the stream share (one page a step) a
+        # larger page is a larger working set
+        a = analysis.decode_vmem_bytes(128, 8192)
+        b = analysis.decode_vmem_bytes(128, 16384)
         assert b > a
 
 

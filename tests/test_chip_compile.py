@@ -107,17 +107,25 @@ def test_flash_varlen(for_chip):
         q, k, v, lens, causal=True), shp, shp, shp, ((2,), jnp.int32))
 
 
+# (query heads, KV heads, slots, pages, pool blocks, an int4 page's tokens):
+# LLaMA-2's MHA at 8 slots x 128 pages (int4 on pages of 32, so that the
+# packed tile holds 16 rows), and what mistral-7b.serve-chat runs: GQA 32/8,
+# 16 slots x 256 pages of the default 16 (int4: 8 packed rows)
+DECODE_GEOMETRIES = {"llama2_mha": (HEADS, HEADS, 8, 128, 512, 32),
+                     "serve_cell": (32, 8, 16, 256, 4096, 16)}
+
+
+@pytest.mark.parametrize("geometry", list(DECODE_GEOMETRIES))
 @pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
-def test_paged_decode(for_chip, kv):
+def test_paged_decode(for_chip, kv, geometry):
     from paddle_tpu.ops.pallas_decode import paged_decode_attention_raw
 
-    slots, blocks, bs, pages = 8, 512, 16, 128
-    if kv == "int4":
-        bs = 32                               # packed tile holds bs/2 rows
+    hq, hkv, slots, pages, blocks, bs4 = DECODE_GEOMETRIES[geometry]
+    bs = bs4 if kv == "int4" else 16
     cache_dt = BF16 if kv == "bf16" else jnp.int8
-    cache = ((blocks, HEADS, bs // 2 if kv == "int4" else bs, HEAD_DIM),
+    cache = ((blocks, hkv, bs // 2 if kv == "int4" else bs, HEAD_DIM),
              cache_dt)
-    shapes = [((slots, HEADS, HEAD_DIM), BF16), cache, cache,
+    shapes = [((slots, hq, HEAD_DIM), BF16), cache, cache,
               ((slots, pages), jnp.int32), ((slots,), jnp.int32)]
     if kv == "bf16":
         fn = paged_decode_attention_raw

@@ -126,8 +126,41 @@ def test_decode_spans_are_the_decode_step_observation(served):
     assert sum(ticks) == pytest.approx(eng._m_decode_step.sum, abs=1e-6)
     for b, r in zip(build, run):
         assert b.end <= r.start and b.attrs == r.attrs
-        assert set(b.attrs) == {"active", "bucket"}
+        assert set(b.attrs) == {"active", "bucket", "live_pages",
+                                "kv_steps"}
         assert 1 <= b.attrs["active"] <= b.attrs["bucket"] <= 4
+        # at least a page a live slot, at most every page of each
+        assert b.attrs["active"] <= b.attrs["live_pages"] \
+            <= b.attrs["active"] * eng.pages
+        assert b.attrs["kv_steps"] == 0      # CPU: the XLA composition
+
+
+def test_decode_span_counts_live_pages_and_the_kernels_grid(monkeypatch):
+    """`live_pages` is the sum over live slots of `pos // block_size + 1`;
+    `kv_steps` is the `paged_decode` grid at the tick's slot bucket, from
+    the function that sizes the kernel's compute block."""
+    from paddle_tpu.ops import pallas_decode
+
+    eng = ServingEngine(_tiny_llama(), max_slots=4, kv_block_size=8)
+    for ln in (5, 9, 17):
+        eng.add_request(np.arange(ln) % 128, max_new_tokens=3)
+    eng.step()                                  # admits and prefills all 3
+    obs.clear_spans()
+    eng.step()
+    run, = [e for e in obs.span_events() if e.name == "serving.decode.run"]
+    # a slot's pos is one past its prompt's after the tick; read before it
+    assert run.attrs["live_pages"] == 5 // 8 + 1 + 9 // 8 + 1 + 17 // 8 + 1
+    assert run.attrs["kv_steps"] == 0
+    monkeypatch.setattr(pallas_decode, "use_pallas_decode",
+                        lambda *a, **k: True)
+    # 16 pages of 8 rows x 4 heads x 8 wide fit one compute block: a step
+    # a slot of the 4-slot bucket
+    assert eng._kv_steps(4) == 4 == pallas_decode.kv_steps(
+        4, eng.pages, 8, 4, 8, eng.cache.k.dtype.itemsize)
+    # room for K and V, double-buffered, of 4 pages: 4 blocks of 4 a slot
+    page = 4 * 8 * 8 * eng.cache.k.dtype.itemsize
+    monkeypatch.setattr(pallas_decode, "_STREAM_VMEM_BYTES", 4 * 4 * page)
+    assert eng.pages == 16 and eng._kv_steps(4) == 4 * 4
 
 
 def test_flight_recorder_reads_the_spans_clock(served, tmp_path):

@@ -259,7 +259,8 @@ def audit_serving() -> list:
     findings += analysis.audit_decode_config(
         eng.spec.head_dim, eng.block_size,
         group=max(1, eng.spec.num_heads // eng.spec.num_kv_heads),
-        itemsize=2, pool_blocks=eng.allocator.num_blocks,
+        itemsize=2, kv_heads=eng.spec.num_kv_heads,
+        pool_blocks=eng.allocator.num_blocks,
         slots=eng.max_slots, seq_pages=eng.pages,
         cached_blocks=eng.prefix_cache.cached_blocks,
         loc="paged/decode-config")
