@@ -147,14 +147,23 @@ pstring = "pstring"  # string-tensor dtype tag (no string tensors yet)
 
 
 class LazyGuard:
-    """≙ paddle.LazyGuard (lazy parameter materialization). Parameters here
-    are created eagerly but cheaply (no device sync until first use), so the
-    guard is a transparent context kept for API parity."""
+    """≙ paddle.LazyGuard (lazy parameter materialization). A parameter
+    created inside the guard (`Layer.create_parameter`) has a shape, a
+    dtype and its initializer, and no buffer: it is allocated when its
+    data is first read, with the values an eager build of the same seed
+    gives, or never, if weights are assigned to it first
+    (`p._data = w`, `set_state_dict`). Guards nest."""
 
     def __enter__(self):
+        from .core import lazy_init
+
+        lazy_init.enter()
         return self
 
     def __exit__(self, *exc):
+        from .core import lazy_init
+
+        lazy_init.leave()
         return False
 
 

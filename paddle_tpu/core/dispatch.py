@@ -32,6 +32,7 @@ from . import flags as _flags_mod
 from .flags import flag
 from .lazy import LazyData as _LazyData
 from .lazy import current_lazy as _current_lazy
+from .lazy_init import LazyInit
 
 
 class _HotFlags:
@@ -671,6 +672,8 @@ def _op_call_impl(fn: Callable, *args, name: str | None = None, n_diff: int | No
             if trace is not None:
                 trace.on_read(a)
             d = a._data_buf
+            if type(d) is LazyInit:     # a LazyGuard parameter's first use
+                d = a._data
         else:
             d = a
         datas.append(d)

@@ -15,6 +15,9 @@ from .dispatch import current_trace
 from .tensor import Tensor
 
 _key_tensor: Tensor | None = None
+#: keys handed out since import (`lazy_init.defer` counts an
+#: initializer's draws with it)
+_draws = 0
 
 
 def seed(value: int):
@@ -32,6 +35,8 @@ def _state() -> Tensor:
 
 def next_key():
     """Split the global key; returns a raw jax key for immediate consumption."""
+    global _draws
+    _draws += 1
     kt = _state()
     tr = current_trace()
     if tr is not None:
@@ -40,6 +45,10 @@ def next_key():
     new, sub = jax.random.split(kt._data)
     kt._data = new
     return sub
+
+
+def draw_count() -> int:
+    return _draws
 
 
 def get_rng_state():

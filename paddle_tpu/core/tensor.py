@@ -21,6 +21,7 @@ import numpy as np
 from . import dtype as dtypes
 from .device import Place, current_place
 from .dispatch import current_trace, no_grad
+from .lazy_init import LazyInit
 
 
 class Tensor:
@@ -82,23 +83,27 @@ class Tensor:
         self._dist_attr = None
 
     # ------------------------------------------------------------ properties
+    # shape and dtype are read off what is held, not through `_data`: a
+    # LazyGuard parameter holds a `LazyInit` (shape, dtype, no buffer)
+    # and is not to make its buffer to answer them
     @property
     def shape(self):
-        return list(self._data.shape)
+        return list(self._data_buf.shape)
 
     @property
     def ndim(self):
-        return self._data.ndim
+        return len(self._data_buf.shape)
 
     dim = ndim
 
     @property
     def size(self):
-        return int(np.prod(self._data.shape)) if self._data.shape else 1
+        shape = self._data_buf.shape
+        return int(np.prod(shape)) if shape else 1
 
     @property
     def dtype(self):
-        return np.dtype(self._data.dtype)
+        return np.dtype(self._data_buf.dtype)
 
     @property
     def place(self):
@@ -217,10 +222,13 @@ class Tensor:
             value = value._data
         else:
             value = _to_jax(value, self.dtype, None)
-        if tuple(value.shape) != tuple(self._data.shape):
-            value = jnp.broadcast_to(value, self._data.shape)
-        if value.dtype != self._data.dtype:
-            value = value.astype(self._data.dtype)
+        # shape and dtype of what is held (a LazyGuard parameter holds no
+        # buffer yet, and is not to make one only to be overwritten)
+        held = self._data_buf
+        if tuple(value.shape) != tuple(held.shape):
+            value = jnp.broadcast_to(value, held.shape)
+        if value.dtype != held.dtype:
+            value = value.astype(held.dtype)
         self._assign_raw(value)
         return self
 
@@ -340,6 +348,31 @@ class Parameter(Tensor):
     @trainable.setter
     def trainable(self, v):
         self.stop_gradient = not v
+
+
+class LazyParameter(Parameter):
+    """A parameter made under `paddle.LazyGuard`: it holds a `LazyInit`
+    where its buffer would be. Shape and dtype are answered from that;
+    the first read of the data runs the initializer, an assignment
+    replaces it; either way the object becomes a plain `Parameter` (same
+    layout), so that only a parameter still without data pays for the
+    check."""
+
+    __slots__ = ()
+
+    @property
+    def _data(self):
+        buf = self._data_buf
+        if type(buf) is LazyInit:
+            buf = buf.materialize()
+            self._data = buf
+        return buf
+
+    @_data.setter
+    def _data(self, value):
+        Tensor._data.fset(self, value)
+        if type(value) is not LazyInit:
+            self.__class__ = Parameter
 
 
 def _to_jax(data, dtype=None, place=None):

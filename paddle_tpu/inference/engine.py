@@ -77,6 +77,7 @@ from ..text.paged_cache import (TRASH_BLOCK, BlockAllocator, PagedKVCache,
                                 scatter_chunk, scatter_chunk_int4,
                                 scatter_chunk_int8, scatter_prefill,
                                 scatter_prefill_int4, scatter_prefill_int8)
+from . import layered
 
 #: quantized KV-cache modes and their (append, scatter_prefill,
 #: scatter_chunk) triples — the step programs dispatch on the STATIC
@@ -620,6 +621,88 @@ _ENGINE_IDS = itertools.count()
 _SERVING_EXECUTABLES: dict = {}
 
 
+class _StackedPrograms:
+    """The dense side of `ServingEngine` (llama / gpt: layers stacked by
+    `_stacked_params*`, one pool with a layer axis): for `_run_chunk` and
+    `_decode` each site's step function with its operands, and what its
+    result means. `inference/layered.LayeredPrograms` is its counterpart
+    for a model that declares its layers one by one, method for method."""
+
+    whole_prompt_prefill = True
+
+    def __init__(self, eng):
+        self.eng = eng
+
+    def full_pool(self):
+        """One layer's pool (shape and dtype): [N, H_kv, rows, D]."""
+        k = self.eng.cache.k
+        return jax.ShapeDtypeStruct(k.shape[1:], k.dtype)
+
+    def chunk_buckets(self, n, ctx_need):
+        """(chunk-length bucket, context-pages bucket) of a chunk of `n`
+        tokens whose context ends in page `ctx_need`."""
+        from ..jit.api import default_buckets
+
+        e = self.eng
+        c_bucket = max(8, default_buckets(n))
+        return c_bucket, min(e.pages, max(
+            default_buckets(ctx_need),
+            blocks_for(c_bucket, e.block_size) + 1))
+
+    def chunk(self, slot, req, ids, start, n, is_last, ctx_pages, cow):
+        """(step, number of static operands, operands)."""
+        e, c = self.eng, self.eng.cache
+        sample = req.do_sample and is_last
+        return _chunk_prefill_step, 6, (
+            e.spec, e.block_size, e.kv_mode, sample, is_last, ctx_pages,
+            e.params, jnp.asarray(ids), jnp.int32(start),
+            jnp.int32(start + n), jnp.int32(req.prompt.size - 1 - start),
+            jnp.asarray(e._tables[slot]), jnp.int32(cow[0]),
+            jnp.int32(cow[1]), c.k, c.v, c.k_scale, c.v_scale,
+            e._samp_arrays([req]), e._key)
+
+    def chunk_done(self, out, n, is_last, run):
+        """Take the program's result: swap the pools in, fetch the token
+        (None unless the prompt's last chunk)."""
+        c = self.eng.cache
+        tok_arr, ck, cv, cks, cvs, self.eng._key = out
+        c.swap(ck, cv, cks, cvs)
+        if is_last:
+            return int(jax.device_get(tok_arr)[0])
+        # non-final chunks fetch no token, so without an explicit barrier
+        # the span would end at async dispatch's enqueue time — block on
+        # the written cache so the observed wall (roofline utilization +
+        # the chunk's span) is the program's
+        jax.block_until_ready(c.k)
+        return None
+
+    def decode(self, active, reqs, bucket, tok, pos, tables, any_sample):
+        e, c = self.eng, self.eng.cache
+        return _decode_step, 4, (
+            e.spec, e.block_size, e.kv_mode, any_sample, e.params,
+            jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables), c.k,
+            c.v, c.k_scale, c.v_scale,
+            e._samp_arrays(reqs, bucket - len(active)), e._key)
+
+    def decode_done(self, out, n_active, run):
+        nxt, ck, cv, cks, cvs, self.eng._key = out
+        self.eng.cache.swap(ck, cv, cks, cvs)
+        return np.asarray(jax.device_get(nxt))
+
+    def decode_jaxpr(self, bucket, samp):
+        e, c = self.eng, self.eng.cache
+        zeros = jnp.zeros(bucket, jnp.int32)
+        fn = functools.partial(_decode_step_impl, e.spec, e.block_size,
+                               e.kv_mode, False)
+        return jax.make_jaxpr(fn)(
+            e.params, zeros, zeros,
+            jnp.full((bucket, e.pages), TRASH_BLOCK, jnp.int32),
+            c.k, c.v, c.k_scale, c.v_scale, samp, e._key)
+
+    def update_gauges(self):
+        """No gauge of its own: one kind of layer state."""
+
+
 class Request:
     """One generation request riding the engine."""
 
@@ -747,7 +830,26 @@ class ServingEngine:
         self.weight_quant = str(weight_quant)
         cfg = model.config
         arch = getattr(model, "_gen_arch", "llama")
-        if arch == "gpt":
+        #: a model that declares its layers one by one (`serving_arrays()`:
+        #: its own buffers, a layer each; `config.block_spec()`: each
+        #: layer's cache kind) is served by inference/layered.py's
+        #: programs. `self.layered` is their static key (None for the
+        #: dense architectures' stacked programs), `self.ring` the window
+        #: layers' state; `self.programs` answers for either kind
+        self.layered = self.ring = None
+        per_layer = hasattr(model, "serving_arrays")
+        if per_layer:
+            blk = cfg.block_spec()
+            self.spec = _GenSpec(
+                num_layers=len(blk.layer_types), num_heads=blk.num_heads,
+                num_kv_heads=blk.num_kv_heads, head_dim=blk.head_dim,
+                rope_theta=blk.rope_theta, rms_eps=blk.eps,
+                max_new_tokens=0, do_sample=False, top_k=0, top_p=1.0,
+                temperature=1.0, eos_token_id=-1, tie_embeddings=True,
+                arch=arch, weight_quant="none")
+            # the model's own buffers, by reference: no second copy
+            self.params = model.serving_arrays()
+        elif arch == "gpt":
             nh = cfg.num_attention_heads
             self.spec = _GenSpec(
                 num_layers=cfg.num_hidden_layers, num_heads=nh,
@@ -795,10 +897,26 @@ class ServingEngine:
         # trash block); size it down to exercise admission control
         if num_kv_blocks is None:
             num_kv_blocks = 1 + self.max_slots * self.pages
-        self.cache = PagedKVCache(
-            self.spec.num_layers, int(num_kv_blocks),
-            self.spec.num_kv_heads, self.block_size, self.spec.head_dim,
-            mode if self.quantized else dtype)
+        self.chunk_tokens = int(
+            flag("FLAGS_chunked_prefill_tokens")
+            if chunked_prefill_tokens is None else chunked_prefill_tokens)
+        if per_layer:
+            # two kinds of layer state in one manager: the allocator's
+            # pages are the full layers' alone; a window layer keeps a
+            # static ring of the last window + chunk positions a slot
+            self._refuse_for_layered(arch, mode, spec_decode, prefix_cache)
+            prefix_cache = False
+            self.programs = layered.LayeredPrograms(
+                self, blk, int(num_kv_blocks), dtype)
+            self.layered, self.ring, self.cache = (
+                self.programs.spec, self.programs.ring, self.programs.cache)
+        else:
+            self.cache = PagedKVCache(
+                self.spec.num_layers, int(num_kv_blocks),
+                self.spec.num_kv_heads, self.block_size,
+                self.spec.head_dim, mode if self.quantized else dtype)
+            self.programs = _StackedPrograms(self)
+        kv_dtype = str(self.programs.full_pool().dtype)
         self.allocator = BlockAllocator(int(num_kv_blocks))
         if admission not in ("continuous", "static"):
             raise ValueError(f"unknown admission mode {admission!r}")
@@ -811,9 +929,6 @@ class ServingEngine:
         self.prefix_cache_enabled = bool(
             flag("FLAGS_prefix_cache") if prefix_cache is None
             else prefix_cache)
-        self.chunk_tokens = int(
-            flag("FLAGS_chunked_prefill_tokens")
-            if chunked_prefill_tokens is None else chunked_prefill_tokens)
         self.prefix_cache = PrefixCache(
             self.allocator,
             max_cached_blocks=int(
@@ -828,8 +943,7 @@ class ServingEngine:
         #: spec carries weight_quant, so differently-quantized weights
         #: (different K/V numerics) never alias either.
         self._prefix_namespace = hash(
-            (self.spec, self.block_size, self.kv_mode,
-             str(self.cache.k.dtype)))
+            (self.spec, self.block_size, self.kv_mode, kv_dtype))
         self._slot_chunk: dict[int, dict] = {}   # slot -> chunk progress
         self._slot_extra_refs: list[list[int]] = [[] for _ in
                                                   range(self.max_slots)]
@@ -968,6 +1082,22 @@ class ServingEngine:
             "serving_spec_accepted_per_window", "tokens emitted per "
             "verify window: accepted prefix + the correction/bonus "
             "token (1..K+1)")
+        # ---- expert layers and the two-kind cache (PR 31): like the
+        # speculative rows, they exist on every engine; a dense model
+        # with one kind of layer state never moves them
+        self._m_moe_picks = reg.counter(
+            "serving_moe_local_picks_total", "(token, held expert) picks "
+            "the step programs computed, summed over expert layers")
+        self._m_moe_tokens = reg.counter(
+            "serving_moe_routed_tokens_total", "tokens routed, counted "
+            "once an expert layer: the held experts' share of the picks "
+            "is local_picks / (routed_tokens x experts per token)")
+        self._m_kv_window = reg.gauge(
+            "serving_kv_window_bytes_held", "bytes of window-layer state "
+            "(the rings of occupied slots, all window layers)")
+        self._m_kv_full = reg.gauge(
+            "serving_kv_full_blocks_used", "full-history cache blocks "
+            "allocated to live requests")
         # config: explicit arg wins; the FLAGS_spec_decode string is the
         # flag-surface shorthand ("off" | "ngram" | "draft")
         from .speculative import SpecConfig, make_proposer
@@ -998,9 +1128,8 @@ class ServingEngine:
         params_fp = tuple((tuple(p.shape), str(p.dtype))
                           for p in jax.tree_util.tree_leaves(self.params))
         self._prog_key_base = hash(
-            (self.spec, self.block_size, self.kv_mode, self.pages,
-             self.allocator.num_blocks, str(self.cache.k.dtype),
-             params_fp))
+            (self.spec, self.layered, self.block_size, self.kv_mode,
+             self.pages, self.allocator.num_blocks, kv_dtype, params_fp))
         self._warmed = False
         self._draining = False
         # D15 owner-thread contract (binds on the first driving call,
@@ -1037,6 +1166,37 @@ class ServingEngine:
                     "this engine goes unscraped; use "
                     "obs.serve_metrics(port, engine.registry) to expose "
                     "it elsewhere", key="obs-http-bind")
+
+    def _refuse_for_layered(self, arch, kv_mode, spec_decode, prefix_cache):
+        """What an architecture with two kinds of layer state does not
+        get yet, each refused by name at construction (no fallback).
+        `kv_mode`, `self.weight_quant` and `self.chunk_tokens` are the
+        resolved values (argument, else flag)."""
+        from ..core.flags import flag
+
+        def refuse(option, why):
+            raise ValueError(f"{option} is not supported for {arch}: {why}")
+
+        if self.weight_quant != "none":
+            refuse(f"weight_quant={self.weight_quant!r}",
+                   "its step programs read the model's own buffers and "
+                   "have no dequantising matmul")
+        if kv_mode != "model":
+            refuse(f"kv_cache_dtype={kv_mode!r}",
+                   "the window layers' ring has no per-block scales")
+        spec = (str(flag("FLAGS_spec_decode")) if spec_decode is None
+                else spec_decode)
+        if spec != "off":
+            refuse(f"spec_decode={spec!r}",
+                   "there is no verify program for two-kind layers")
+        if prefix_cache:
+            refuse("prefix_cache=True",
+                   "a cached prefix holds no window-layer state to resume "
+                   "from")
+        if self.chunk_tokens <= 0:
+            refuse(f"chunked_prefill_tokens={self.chunk_tokens}",
+                   "every prompt is prefilled by chunks (the window "
+                   "layers' ring is sized by the chunk)")
 
     # ------------------------------------------------------------- API
     def add_request(self, prompt, max_new_tokens=32, do_sample=False,
@@ -1527,8 +1687,8 @@ class ServingEngine:
             row[:len(blocks)] = blocks
             self._tables[slot] = row
             self._update_pool_gauges()
-            if cached_len == 0 and (self.chunk_tokens <= 0
-                                    or s <= self.chunk_tokens):
+            if self.programs.whole_prompt_prefill and cached_len == 0 and (
+                    self.chunk_tokens <= 0 or s <= self.chunk_tokens):
                 tok, done = self._prefill(slot, req)
                 self._register_full_blocks(slot)
                 yield (req.rid, tok, done)
@@ -1553,6 +1713,7 @@ class ServingEngine:
         self._m_pool_free.set(self.allocator.available)
         self._m_pool_used.set(self.allocator.num_blocks - 1
                               - self.allocator.available)
+        self.programs.update_gauges()
         self._m_cache_blocks.set(self.prefix_cache.cached_blocks)
         self._m_cache_refed.set(self.prefix_cache.referenced_blocks)
         ev = self.prefix_cache.evictions - self._m_prefix_evict.value
@@ -1675,53 +1836,31 @@ class ServingEngine:
         context-pages bucket, emit_token): chunk lengths bucket like
         prompt lengths, context pages like slot counts, so a stream
         compiles O(log S * log pages) chunk programs."""
-        from ..jit.api import default_buckets
-
         s = req.prompt.size
         start = req.prefill_pos
         n = s - start if self.chunk_tokens <= 0 \
             else min(s - start, self.chunk_tokens)
         is_last = start + n >= s
-        c_bucket = max(8, default_buckets(n))
-        ctx_need = blocks_for(start + n, self.block_size)
-        ctx_pages = min(self.pages, max(default_buckets(ctx_need),
-                                        blocks_for(c_bucket,
-                                                   self.block_size) + 1))
+        c_bucket, ctx_pages = self.programs.chunk_buckets(
+            n, blocks_for(start + n, self.block_size))
         cow = state.pop("cow", None)
         cow_src, cow_dst = cow if cow is not None else (TRASH_BLOCK,
                                                         TRASH_BLOCK)
         at = {"rid": req.rid, "tokens": int(n), "start": int(start),
               "last": bool(is_last), "bucket": int(c_bucket)}
-        c = self.cache
         with _span("serving.chunk.build", **at):
             ids = np.zeros((1, c_bucket), np.int32)
             ids[0, :n] = req.prompt[start:start + n]
-            samp = self._samp_arrays([req])
-            args = (self.spec, self.block_size, self.kv_mode,
-                    req.do_sample and is_last, is_last, ctx_pages,
-                    self.params, jnp.asarray(ids), jnp.int32(start),
-                    jnp.int32(start + n), jnp.int32(s - 1 - start),
-                    jnp.asarray(self._tables[slot]), jnp.int32(cow_src),
-                    jnp.int32(cow_dst), c.k, c.v, c.k_scale, c.v_scale,
-                    samp, self._key)
+            step, n_static, args = self.programs.chunk(
+                slot, req, ids, start, n, is_last, ctx_pages,
+                (cow_src, cow_dst))
             prog, entry = self._program(
-                "serving.chunk_prefill", _chunk_prefill_step, 6, c_bucket,
+                "serving.chunk_prefill", step, n_static, c_bucket,
                 req.do_sample and is_last, (ctx_pages, bool(is_last)),
                 args)
         with _span("serving.chunk.run", **at) as run:
-            out = prog(*args[6:])
-            tok_arr, ck, cv, cks, cvs, self._key = out
-            c.swap(ck, cv, cks, cvs)
-            if is_last:
-                tok = int(jax.device_get(tok_arr)[0])
-            else:
-                # non-final chunks fetch no token, so without an explicit
-                # barrier the span would end at async dispatch's enqueue
-                # time — block on the written cache so the observed wall
-                # (roofline utilization + the chunk's span) is the
-                # program's
-                tok = None
-                jax.block_until_ready(c.k)
+            tok = self.programs.chunk_done(prog(*args[n_static:]), n,
+                                           is_last, run)
         t_run, t_end = run.start, run.end
         entry.observe(t_end - t_run)
         fl = req._flight
@@ -1746,17 +1885,18 @@ class ServingEngine:
         bucket; 0 where the router keeps the XLA composition."""
         from ..ops import pallas_decode as pd
 
-        pool = self.cache.k.shape[1:]                # [N, H_kv, rows, D]
+        full = self.programs.full_pool()     # [N, H_kv, rows, D]
+        pool, kv_dt = full.shape, full.dtype
         int4 = self.kv_mode == "int4"
         if not pd.use_pallas_decode(
                 jax.ShapeDtypeStruct((bucket, self.spec.num_heads,
                                       self.spec.head_dim),
                                      self.params["embed"].dtype),
-                jax.ShapeDtypeStruct(pool, self.cache.k.dtype),
+                jax.ShapeDtypeStruct(pool, kv_dt),
                 jax.ShapeDtypeStruct((bucket, self.pages), jnp.int32), int4):
             return 0
         return pd.kv_steps(bucket, self.pages, pool[2], pool[1], pool[3],
-                           self.cache.k.dtype.itemsize)
+                           kv_dt.itemsize)
 
     def _decode(self, active):
         from ..jit.api import default_buckets
@@ -1766,7 +1906,6 @@ class ServingEngine:
               "live_pages": int(
                   (self._slot_pos[active] // self.block_size + 1).sum()),
               "kv_steps": self._kv_steps(bucket)}
-        c = self.cache
         with _span("serving.decode.build", **at) as build:
             reqs = [self._slot_req[i] for i in active]
             pad = bucket - len(active)
@@ -1778,19 +1917,14 @@ class ServingEngine:
             tables = np.concatenate(
                 [self._tables[active],
                  np.full((pad, self.pages), TRASH_BLOCK, np.int32)])
-            samp = self._samp_arrays(reqs, pad)
             any_sample = any(r.do_sample for r in reqs)
-            args = (self.spec, self.block_size, self.kv_mode, any_sample,
-                    self.params, jnp.asarray(tok), jnp.asarray(pos),
-                    jnp.asarray(tables), c.k, c.v, c.k_scale, c.v_scale,
-                    samp, self._key)
-            prog, entry = self._program("serving.decode", _decode_step, 4,
+            step, n_static, args = self.programs.decode(
+                active, reqs, bucket, tok, pos, tables, any_sample)
+            prog, entry = self._program("serving.decode", step, n_static,
                                         bucket, any_sample, (), args)
         with _span("serving.decode.run", **at) as run:
-            out = prog(*args[4:])
-            nxt, ck, cv, cks, cvs, self._key = out
-            c.swap(ck, cv, cks, cvs)
-            nxt = np.asarray(jax.device_get(nxt))
+            nxt = self.programs.decode_done(prog(*args[n_static:]),
+                                            len(active), run)
         with _span("serving.decode.emit", **at):
             t_run, t_end = run.start, run.end
             entry.observe(t_end - t_run)
@@ -2022,18 +2156,11 @@ class ServingEngine:
         serving analogue of CompiledFunction.program_jaxpr(), consumed by
         tools/graft_lint.py's paged smoke audit."""
         bucket = min(bucket, self.max_slots)
-        c = self.cache
         samp = {"do_sample": jnp.zeros(bucket, bool),
                 "temperature": jnp.ones(bucket, jnp.float32),
                 "top_k": jnp.zeros(bucket, jnp.int32),
                 "top_p": jnp.ones(bucket, jnp.float32)}
-        fn = functools.partial(_decode_step_impl, self.spec,
-                               self.block_size, self.kv_mode, False)
-        return jax.make_jaxpr(fn)(
-            self.params, jnp.zeros(bucket, jnp.int32),
-            jnp.zeros(bucket, jnp.int32),
-            jnp.full((bucket, self.pages), TRASH_BLOCK, jnp.int32),
-            c.k, c.v, c.k_scale, c.v_scale, samp, self._key)
+        return self.programs.decode_jaxpr(bucket, samp)
 
     def verify_program_jaxpr(self, bucket=2, k=4):
         """The speculative verify program's jaxpr at a given (slot

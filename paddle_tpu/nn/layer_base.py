@@ -11,8 +11,9 @@ from typing import Callable, Iterator
 import numpy as np
 
 from ..core import dtype as dtypes
+from ..core import lazy_init
 from ..core.dispatch import no_grad
-from ..core.tensor import Parameter, Tensor
+from ..core.tensor import LazyParameter, Parameter, Tensor
 
 
 class HookRemoveHelper:
@@ -106,7 +107,7 @@ class Layer:
 
     def create_parameter(self, shape, attr=None, dtype=None, is_bias=False,
                          default_initializer=None):
-        from .initializer import Constant, XavierNormal
+        from .initializer import Constant, Initializer, XavierNormal
 
         dtype = dtypes.convert_dtype(dtype) if dtype else self._dtype
         init = default_initializer
@@ -114,8 +115,14 @@ class Layer:
             init = attr.initializer
         if init is None:
             init = Constant(0.0) if is_bias else XavierNormal()
-        p = Parameter(np.zeros(shape, dtype), _internal=False)
-        init(p)
+        if lazy_init.active() and isinstance(init, Initializer):
+            # under paddle.LazyGuard: shape, dtype and initializer are
+            # recorded and nothing is allocated until first use
+            p = LazyParameter(lazy_init.defer(shape, dtype, init),
+                              _internal=True)
+        else:
+            p = Parameter(np.zeros(shape, dtype), _internal=False)
+            init(p)
         if attr is not None:
             if getattr(attr, "learning_rate", None) is not None:
                 p.optimize_attr = {"learning_rate": attr.learning_rate}

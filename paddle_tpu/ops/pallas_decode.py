@@ -120,7 +120,7 @@ def _cdiv(a, b: int):
 
 
 def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
-                   has_scale, packed):
+                   has_scale, packed, has_start=False):
     """One (slot, kv_block) grid step: every KV head's GQA query group
     attends to one compute block of `pps` cache pages, merged into the
     running flash state.
@@ -140,7 +140,16 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
     the block whose copies start before this block's compute and are
     waited on by the step that consumes them. step_ref counts live steps;
     its parity names the buffer.
+
+    `has_start` (a windowed layer): a third scalar-prefetch array gives
+    each slot's first live position. Pages wholly before it are not
+    copied, compute blocks wholly before it do nothing, the first live
+    page is masked from below, and a slot's first step is the block that
+    holds that page instead of block 0. Without it the program is the one
+    above, unchanged.
     """
+    if has_start:
+        start_ref, *rest = rest
     if has_scale:
         ks_ref, vs_ref, *rest = rest
     (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, step_ref,
@@ -158,12 +167,24 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
     def live_pages(s):
         return jnp.maximum(_cdiv(len_ref[s], block_size), 1)
 
+    def first_page(s):
+        """The page that holds slot s's first live position (0 without
+        `has_start`); never past the slot's last live page."""
+        if not has_start:
+            return 0
+        return jnp.minimum(start_ref[s] // block_size, live_pages(s) - 1)
+
+    def first_blk(s):
+        return first_page(s) // pps
+
     def each_copy(s, j, buf, do):
         """`do` on the K and the V copy of every live page of block j of
         slot s into buffer buf (a copy is waited on through a descriptor
-        like the one that started it); returns how many pages."""
+        like the one that started it); returns the [lo, n) of the
+        buffer's pages that hold them."""
         first = j * pps
         n = jnp.minimum(live_pages(s) - first, pps)
+        lo = jnp.clip(first_page(s) - first, 0, pps) if has_start else 0
 
         def page(i, c):
             pid = tab_ref[s, first + i]
@@ -172,8 +193,8 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
             do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, i],
                                      sems.at[1, buf]))
             return c
-        jax.lax.fori_loop(0, n, page, None)
-        return n
+        jax.lax.fori_loop(lo, n, page, None)
+        return lo, n
 
     def start(s, j, buf):
         each_copy(s, j, buf, lambda cp: cp.start())
@@ -203,7 +224,7 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
     @pl.when((si == 0) & (ji == 0))
     def _first():
         step_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_blk(0), 0)
 
     @pl.when(ji == 0)
     def _init():
@@ -214,7 +235,11 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
     seq_len = len_ref[si]
     n_blk = _cdiv(live_pages(si), pps)
 
-    @pl.when(ji < n_blk)
+    live = ji < n_blk
+    if has_start:
+        live = live & (ji >= first_blk(si))
+
+    @pl.when(live)
     def _block():
         buf = step_ref[0] % 2
         step_ref[0] = step_ref[0] + 1
@@ -223,21 +248,27 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
 
         @pl.when(nxt_s < n_s)
         def _prefetch():
-            start(nxt_s, jnp.where(more, ji + 1, 0), 1 - buf)
+            nxt_first = first_blk(nxt_s) if has_start else 0
+            start(nxt_s, jnp.where(more, ji + 1, nxt_first), 1 - buf)
 
-        n = each_copy(si, ji, buf, lambda cp: cp.wait())
+        lo, n = each_copy(si, ji, buf, lambda cp: cp.wait())
         if jnp.issubdtype(v_buf.dtype, jnp.floating):
-            # pages of the tail block that no copy wrote: whatever the
-            # buffer held (NaN bits included) would meet p = 0 in pv
+            # pages of the block that no copy wrote (past the tail, or
+            # before the first live page): whatever the buffer held (NaN
+            # bits included) would meet p = 0 in pv
             def zero(i, c):
                 v_buf[buf, i] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
                 return c
             jax.lax.fori_loop(n, pps, zero, None)
+            if has_start:
+                jax.lax.fori_loop(0, lo, zero, None)
 
         # the tail block is partially valid, interior blocks are full: one
         # masked path keeps the kernel small
         cols = ji * t + jax.lax.broadcasted_iota(jnp.int32, (gp, t), 1)
         mask = cols < seq_len
+        if has_start:
+            mask = mask & (cols >= start_ref[si])
         if has_scale:
             ks_row, vs_row = scale_row(ks_ref), scale_row(vs_ref)
 
@@ -277,22 +308,27 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
 
 def paged_decode_attention_raw(q, k_cache, v_cache, block_tables, seq_lens,
                                k_scale=None, v_scale=None, kv_int4=False,
-                               pages_per_step_=None):
+                               pages_per_step_=None, kv_start=None,
+                               name="paged_decode"):
     """The Pallas kernel path. q [S, H_q, D]; caches [N, H_kv, bs, D]
     (int8 when k_scale/v_scale [N] f32 are given; int4-packed
     [N, H_kv, bs/2, D] when kv_int4); block_tables [S, P] int32 (entries
     < 0 tolerated as padding); seq_lens [S] valid kv lengths. Returns
     [S, H_q, D] in q.dtype. `pages_per_step_` overrides the derived
-    compute block (tests and sweeps; nothing in the library passes it)."""
+    compute block (tests and sweeps; nothing in the library passes it).
+    `kv_start` [S]: each slot's first live position (a windowed layer:
+    positions before it are neither read nor attended); `name`: the
+    kernel's name in programs and traces."""
     with _x64_guard():
         return _paged_decode_x32(q, k_cache, v_cache, block_tables,
                                  seq_lens, k_scale, v_scale, kv_int4,
-                                 pages_per_step_)
+                                 pages_per_step_, kv_start, name)
 
 
 def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
                       k_scale=None, v_scale=None, kv_int4=False,
-                      pages_per_step_=None):
+                      pages_per_step_=None, kv_start=None,
+                      name="paged_decode"):
     s_n, hq, d = q.shape
     n_blocks, hkv, rows, dc = k_cache.shape
     bs = rows
@@ -320,13 +356,17 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
     lens = seq_lens.astype(jnp.int32)
     scale = 1.0 / float(np.sqrt(d))
     has_scale = k_scale is not None
+    has_start = kv_start is not None
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_size=bs,
-                               pps=pps, has_scale=has_scale, packed=kv_int4)
+                               pps=pps, has_scale=has_scale, packed=kv_int4,
+                               has_start=has_start)
 
     qo_spec = pl.BlockSpec((1, hkv, gp, d), lambda s, j, *refs: (s, 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [tables, lens]
+    if has_start:
+        args.append(jnp.maximum(kv_start, 0).astype(jnp.int32))
     if has_scale:
         # per-(slot, page) dequant scales, gathered host-of-kernel from
         # the per-block scales (tiny: S*P f32 in SMEM)
@@ -351,7 +391,7 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((s_n, hkv, gp, d), q.dtype)],
-        interpret=_interpret(), name="paged_decode",
+        interpret=_interpret(), name=name,
     )(*args, q4, k_cache, v_cache)
     return out[:, :, :g].reshape(s_n, hq, d)
 
@@ -359,7 +399,8 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
 # ------------------------------------------------------- XLA composition
 
 def paged_decode_attention_xla(q, k_cache, v_cache, block_tables, seq_lens,
-                               k_scale=None, v_scale=None, kv_int4=False):
+                               k_scale=None, v_scale=None, kv_int4=False,
+                               kv_start=None):
     """The gather + masked-softmax composition — the numerics oracle for
     the kernel and the off-TPU / gated-off route. Score/output dtype
     conventions match text/generation.py's dense decode attention so the
@@ -392,6 +433,8 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_tables, seq_lens,
     scores = jnp.einsum("shd,sthd->sht", q, k) / np.sqrt(d).astype(
         np.float32)
     valid = jnp.arange(t)[None, :] < seq_lens[:, None]
+    if kv_start is not None:
+        valid = valid & (jnp.arange(t)[None, :] >= kv_start[:, None])
     scores = jnp.where(valid[:, None, :], scores,
                        jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32),
@@ -448,14 +491,20 @@ def use_pallas_decode(q, k_cache, block_tables, kv_int4=False) -> bool:
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
-                           k_scale=None, v_scale=None, kv_int4=False):
+                           k_scale=None, v_scale=None, kv_int4=False,
+                           kv_start=None, name="paged_decode"):
     """Routed paged decode attention (kernel on TPU above threshold, XLA
     composition everywhere else). Same contract as the _raw kernel;
     `kv_int4=True` declares the caches int4-packed along the token axis
-    (k_scale/v_scale required)."""
+    (k_scale/v_scale required). `kv_start` [S] (a windowed layer): row s
+    attends positions [kv_start[s], seq_lens[s]) of its table alone, and
+    the kernel does not copy the pages before them; its call carries
+    `name`, so that a trace tells windowed calls from full ones."""
     if use_pallas_decode(q, k_cache, block_tables, kv_int4):
         return paged_decode_attention_raw(q, k_cache, v_cache,
                                           block_tables, seq_lens,
-                                          k_scale, v_scale, kv_int4)
+                                          k_scale, v_scale, kv_int4,
+                                          kv_start=kv_start, name=name)
     return paged_decode_attention_xla(q, k_cache, v_cache, block_tables,
-                                      seq_lens, k_scale, v_scale, kv_int4)
+                                      seq_lens, k_scale, v_scale, kv_int4,
+                                      kv_start)

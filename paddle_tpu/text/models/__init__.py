@@ -7,5 +7,10 @@ from .llama import (
 )
 from .bert import BertConfig, BertForSequenceClassification, BertModel
 from .gpt import GPTConfig, GPTForCausalLM
+from .cohere2_moe import (
+    Cohere2MoeConfig,
+    Cohere2MoeForCausalLM,
+    cohere2_moe_tiny_config,
+)
 
 __all__ = [n for n in dir() if not n.startswith("_")]

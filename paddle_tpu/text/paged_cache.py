@@ -374,6 +374,95 @@ class PagedKVCache:
         return 2 * per + scales
 
 
+class WindowRing:
+    """The window layers' state of every slot: a static ring of `pages`
+    cache pages a slot, in a pool of its own, position p at ring page
+    `(p // block_size) mod pages`. A window layer reads the last `window`
+    positions and a chunk of at most `chunk` new ones is written before
+    they are read, so `ceil((window + chunk) / block_size) + 1` pages hold
+    everything visible however long the sequence grows (the + 1: a span
+    of that many positions may straddle one page boundary more).
+
+    Nothing is allocated or freed: a slot's ring comes with the slot, and
+    what a finished request left in it is never visible to the next one
+    (below its first live position, or past its length, in the view the
+    programs are handed). The programs know nothing of rings: each tick
+    the host hands them `view()`, the ring rotated into logical order, and
+    they address it like any block table, by position less
+    `base_page * block_size`.
+
+    Block 0 of the pool is the trash block, as in the full layers' pool;
+    slot s owns blocks `1 + s * pages ...`."""
+
+    def __init__(self, slots: int, window: int, chunk: int,
+                 block_size: int):
+        self.slots, self.window = int(slots), int(window)
+        self.block_size = int(block_size)
+        self.pages = blocks_for(int(window) + int(chunk), block_size) + 1
+        self.num_blocks = 1 + self.slots * self.pages
+        self._order = np.arange(self.pages, dtype=np.int64)
+
+    def view(self, slot: int, last_pos: int):
+        """(table row [pages] int32, base_page): the slot's ring in
+        logical order for a step whose LAST written position is
+        `last_pos`. Entry i is the block of absolute page `base_page +
+        i`; the pages that end at `last_pos`'s are the newest."""
+        base = max(0, int(last_pos) // self.block_size - self.pages + 1)
+        row = 1 + int(slot) * self.pages + (base + self._order) % self.pages
+        return row.astype(np.int32), base
+
+    def tokens_reserved(self) -> int:
+        """Positions a slot's ring holds, a layer."""
+        return self.pages * self.block_size
+
+
+class LayeredKVCache:
+    """Pools for a model whose layers are of two kinds: one `[N, H_kv,
+    block_size, D]` array a layer for k and for v (no stacked layer axis:
+    each layer's pool is its own donated buffer, written in place by its
+    layer's scatter and read by its layer's kernel without a slice of a
+    stacked array being copied out and back). Full layers' pools have
+    `full_blocks` blocks, addressed through the `BlockAllocator`'s tables;
+    window layers' have the `WindowRing`'s.
+
+    THREAD CONTRACT (D15): single-owner like `PagedKVCache`; `swap` is the
+    one sanctioned mutation point."""
+
+    _thread_contract = ("swap",)
+
+    def __init__(self, sliding, full_blocks: int, window_blocks: int,
+                 num_kv_heads: int, block_size: int, head_dim: int, dtype):
+        self.contract = ThreadContract("LayeredKVCache")
+        if int(block_size) % 8:
+            raise ValueError(
+                f"kv block_size {block_size} must be a multiple of 8 "
+                "(sublane alignment of the (block_size, head_dim) tile)")
+        #: per layer: True where the layer keeps a window only
+        self.sliding = tuple(bool(x) for x in sliding)
+
+        def pool(is_sliding):
+            n = int(window_blocks) if is_sliding else int(full_blocks)
+            return jnp.zeros((n, int(num_kv_heads), int(block_size),
+                              int(head_dim)), dtype)
+
+        self.k = tuple(pool(x) for x in self.sliding)
+        self.v = tuple(pool(x) for x in self.sliding)
+
+    def swap(self, k, v):
+        self.contract.check("swap")
+        self.k, self.v = tuple(k), tuple(v)
+
+    def bytes_per_token(self, is_sliding: bool) -> int:
+        """K and V bytes one position takes in all layers of one kind."""
+        n = sum(1 for x in self.sliding if x == bool(is_sliding))
+        _, hkv, _, d = self.k[0].shape
+        return 2 * n * hkv * d * self.k[0].dtype.itemsize
+
+    @property
+    def hbm_bytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.k + self.v)
+
+
 # ---------------------------------------------------- in-program updates
 # All functions below are pure jnp and run inside the compiled step
 # programs; `cache`/`scale` arguments are ONE layer's slice
@@ -594,6 +683,47 @@ def scatter_chunk_int4(cache, scale, ks, start, true_end, table_row,
     new_scale = jnp.maximum(amax / INT4_QMAX, 1e-8)    # [P_t]
     packed = _requant_pack_int4(old, new_scale, 1)
     return (cache.at[dest].set(packed), scale.at[dest].set(new_scale))
+
+
+# ------------------------------------------- row-scatter forms (layered)
+# The same writes as `append_token` / `scatter_chunk`, as a scatter of
+# whole [D] rows into the pool seen as [N * H_kv * block_size, D]. The
+# reshape is free (the (block_size, D) tile is untouched) and the one
+# scattered dimension is the major one, so XLA updates the donated pool
+# in place; scattering dimensions 0 and 2 of the 4-D array makes it
+# copy the whole pool into another layout and back around the scatter
+# (PERF.md, PR 31). The layered programs (inference/layered.py) use
+# these; the stacked-pool programs keep the forms above.
+
+def _pool_rows(cache, blocks, offsets):
+    """Flat row index [T, H_kv] of (blocks[t], h, offsets[t])."""
+    _, hkv, bs, _ = cache.shape
+    return ((blocks[:, None] * hkv + jnp.arange(hkv)[None, :]) * bs
+            + offsets[:, None])
+
+
+def _set_rows(cache, rows, kv):
+    n, hkv, bs, d = cache.shape
+    flat = cache.reshape(n * hkv * bs, d)
+    flat = flat.at[rows.reshape(-1)].set(
+        kv.reshape(-1, d).astype(cache.dtype))
+    return flat.reshape(n, hkv, bs, d)
+
+
+def append_rows(cache, kv, block_ids, offsets):
+    """`append_token` by rows: kv [B, H_kv, D] at (block_ids[b], :,
+    offsets[b]); padded slots route to the trash block."""
+    return _set_rows(cache, _pool_rows(cache, block_ids, offsets), kv)
+
+
+def scatter_chunk_rows(cache, ks, start, true_end, table_row, block_size):
+    """`scatter_chunk` by rows: ks [C, H_kv, D] holds positions
+    [start, start + C); positions >= true_end route to the trash block."""
+    pos = start + jnp.arange(ks.shape[0])
+    page = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
+    blk = jnp.where(pos < true_end, table_row[page], TRASH_BLOCK)
+    off = (pos % block_size).astype(jnp.int32)
+    return _set_rows(cache, _pool_rows(cache, blk, off), ks)
 
 
 def gather_context(cache, scale, table_row, ctx_pages, int4=False):

@@ -138,6 +138,34 @@ def test_paged_decode(for_chip, kv, geometry):
     for_chip(fn, *shapes)
 
 
+# what command-a-plus-05-2026.serve-longdoc runs: GQA 128/8, 16 slots; a
+# full layer's table is 1024 pages of 16, a window layer's ring view 273
+# (window 4096 + chunk 256, + 1) with each slot's first live position
+LAYERED_DECODE = {"full": (1024, 1 + 16 * 1024, False),
+                  "window": (273, 1 + 16 * 273, True)}
+
+
+@pytest.mark.parametrize("kind", list(LAYERED_DECODE))
+def test_paged_decode_two_kinds(for_chip, kind):
+    from paddle_tpu.ops.pallas_decode import paged_decode_attention_raw
+
+    pages, blocks, windowed = LAYERED_DECODE[kind]
+    cache = ((blocks, 8, 16, HEAD_DIM), BF16)
+    shapes = [((16, 128, HEAD_DIM), BF16), cache, cache,
+              ((16, pages), jnp.int32), ((16,), jnp.int32)]
+    if not windowed:
+        fn = paged_decode_attention_raw
+    else:
+        shapes.append(((16,), jnp.int32))
+
+        def fn(q, k, v, tab, lens, start):
+            return paged_decode_attention_raw(
+                q, k, v, tab, lens, kv_start=start,
+                name="paged_window_decode")
+    text = for_chip(fn, *shapes).as_text()
+    assert ("paged_window_decode" in text) == windowed
+
+
 # ------------------------------------------------- fused norm/rope/swiglu
 
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])

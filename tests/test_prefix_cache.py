@@ -434,6 +434,16 @@ class TestObsAndRouting:
         assert snap["serving_prefill_chunks_total"]["samples"][0][
             "value"] >= 1
 
+    def test_cache_gauges_follow_the_cache(self):
+        # the gauges are set from the cache's own counts whenever the
+        # pool's are (a presence check alone passes on gauges left at 0)
+        rs = np.random.RandomState(16)
+        p = rs.randint(0, 128, (20,))
+        eng, _ = _drive_pair(_tiny(), [p, p], [3, 3], True)
+        snap = eng.metrics()
+        cached = snap["serving_prefix_cache_blocks"]["samples"][0]["value"]
+        assert cached == eng.prefix_cache.cached_blocks > 0
+
     def test_generate_prefix_cache_kwarg(self):
         m = _tiny()
         prompt = np.random.RandomState(15).randint(0, 128,

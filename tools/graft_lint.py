@@ -421,7 +421,11 @@ REQUIRED_SERVING_METRICS = (
     # stream legitimately leaves them at zero)
     "serving_spec_windows_total", "serving_spec_proposed_tokens_total",
     "serving_spec_accepted_tokens_total", "serving_spec_accept_rate",
-    "serving_spec_accepted_per_window")
+    "serving_spec_accepted_per_window",
+    # PR 31: expert layers and the two-kind cache (NOT in MUST_COUNT — a
+    # dense model with one kind of layer state never moves them)
+    "serving_moe_local_picks_total", "serving_moe_routed_tokens_total",
+    "serving_kv_window_bytes_held", "serving_kv_full_blocks_used")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
