@@ -71,11 +71,12 @@ from ..text.generation import (_GenSpec, _gpt_layer_prefill,
                                _logits, _mm, _repeat_kv, _rms_norm, _rope,
                                _stacked_params, _stacked_params_gpt)
 from ..text.paged_cache import (TRASH_BLOCK, BlockAllocator, PagedKVCache,
-                                PrefixCache, append_token,
+                                PrefixCache, append_rows,
                                 append_token_int4, append_token_int8,
-                                blocks_for, gather_context, hash_blocks,
-                                scatter_chunk, scatter_chunk_int4,
-                                scatter_chunk_int8, scatter_prefill,
+                                blocks_for, copy_block, flat_pool,
+                                gather_context, hash_blocks,
+                                scatter_chunk_int4, scatter_chunk_int8,
+                                scatter_chunk_rows, scatter_prefill,
                                 scatter_prefill_int4, scatter_prefill_int8)
 from . import layered
 
@@ -87,6 +88,37 @@ _KV_FNS = {
     "int8": (append_token_int8, scatter_prefill_int8, scatter_chunk_int8),
     "int4": (append_token_int4, scatter_prefill_int4, scatter_chunk_int4),
 }
+
+
+def _scan_layers(layer, x, params, tables, kc, vc, ksc, vsc):
+    """The stacked programs' layer scan. The pools `[L, N, H_kv, bs, D]`
+    (and their scales `[L, N]`, None for a float cache) ride it as CARRY,
+    seen as `[L * N, ...]`, and come back in the shape they came in; the
+    scan's xs are the stacked weights and each layer's first block, `l *
+    N`. `layer(x, lw, tables, kf, vf, ksf, vsf) -> (x, kf, vf, ksf, vsf)`
+    is handed the program's block table(s) with layer l's offset added
+    (negative padding entries first clamped to the trash block, so layer
+    l's trash block is `l * N`), and every per-layer function of
+    text/paged_cache.py and the decode kernel work on the whole pool
+    unchanged. No slice of a pool leaves the donated buffer and none is
+    stacked back: as xs/ys, a layer's slice of both pools was copied out
+    and back every layer of every call (ten pool-slice copies a layer,
+    72% of the chip's busy time in mistral-7b.serve-chat: PERF.md, PR
+    32)."""
+    n_layers, n_blocks = kc.shape[:2]
+    pools = (kc, vc, ksc, vsc)          # None (no scales) has no leaves
+    tables = jnp.maximum(tables, TRASH_BLOCK)
+
+    def body(carry, per_layer):
+        lw, base = per_layer
+        return layer(carry[0], lw, tables + base, *carry[1:]), None
+
+    flat = jax.tree_util.tree_map(flat_pool, pools)
+    base = jnp.arange(n_layers, dtype=jnp.int32) * n_blocks
+    (x, *flat), _ = jax.lax.scan(body, (x,) + flat,
+                                 (params["layers"], base))
+    return (x,) + jax.tree_util.tree_map(
+        lambda f, a: f.reshape(a.shape), tuple(flat), pools)
 
 
 # ------------------------------------------------------ batched sampling
@@ -145,8 +177,8 @@ def _paged_attn(hn_q, k_new, v_new, kc, vc, ksc, vsc, tables, pos,
         kc, ksc = app(kc, ksc, k_new, blk, off)
         vc, vsc = app(vc, vsc, v_new, blk, off)
     else:
-        kc = append_token(kc, k_new, blk, off)
-        vc = append_token(vc, v_new, blk, off)
+        kc = append_rows(kc, k_new, blk, off)
+        vc = append_rows(vc, v_new, blk, off)
     out = paged_decode_attention(hn_q, kc, vc, tables, pos + 1, ksc, vsc,
                                  kv_int4=kv_mode == "int4")
     return out, kc, vc, ksc, vsc
@@ -155,7 +187,8 @@ def _paged_attn(hn_q, k_new, v_new, kc, vc, ksc, vsc, tables, pos,
 def _paged_layer_llama(x, lw, kc, vc, ksc, vsc, pos, tables, spec,
                        cos, sin, block_size, kv_mode):
     """One LLaMA block for seq-1 queries at PER-SLOT positions against
-    the paged cache. x [B, H]; kc/vc one layer's pool slice."""
+    the paged cache. x [B, H]; kc/vc one pool addressed by `tables` (the
+    whole flat pool, the tables offset to this layer's blocks)."""
     b, h = x.shape
     hn = _rms_norm(x, lw["input_ln"], spec.rms_eps)
     q = _mm(hn, lw["q"]).reshape(b, spec.num_heads, spec.head_dim)
@@ -198,13 +231,13 @@ def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     """ONE decode step for a compacted slot bucket: every row consumes
     its token, appends K/V through its block table, attends over its own
     length, and samples its next token with its own params. Cache pools
-    ride the layer scan as xs/ys exactly like the single-program engine.
-    `any_sample` is STATIC (part of the program key): an all-greedy bucket
-    — the common serving case — compiles to a bare argmax instead of the
-    sort/softmax/cumsum sampling machinery over [B, V] every tick.
+    ride the layer scan as its carry (`_scan_layers`), a layer's blocks
+    addressed by offset. `any_sample` is STATIC (part of the program
+    key): an all-greedy bucket — the common serving case — compiles to a
+    bare argmax instead of the sort/softmax/cumsum sampling machinery
+    over [B, V] every tick.
     """
     gpt = spec.arch == "gpt"
-    quantized = kv_mode != "model"
     dtype = params["embed"].dtype
     xt = params["embed"][tok].astype(dtype)              # [B, H]
     if gpt:
@@ -212,29 +245,15 @@ def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     else:
         cos, sin = params["rope_cos"], params["rope_sin"]
 
-    def layer(xc, per_layer):
-        if quantized:
-            lw, kcl, vcl, kscl, vscl = per_layer
-        else:
-            lw, kcl, vcl = per_layer
-            kscl = vscl = None
+    def layer(xc, lw, tabs, kf, vf, ksf, vsf):
         if gpt:
-            xo, kcl, vcl, kscl, vscl = _paged_layer_gpt(
-                xc, lw, kcl, vcl, kscl, vscl, pos, tables, spec,
-                block_size, kv_mode)
-        else:
-            xo, kcl, vcl, kscl, vscl = _paged_layer_llama(
-                xc, lw, kcl, vcl, kscl, vscl, pos, tables, spec,
-                cos, sin, block_size, kv_mode)
-        ys = (kcl, vcl, kscl, vscl) if quantized else (kcl, vcl)
-        return xo, ys
+            return _paged_layer_gpt(xc, lw, kf, vf, ksf, vsf, pos, tabs,
+                                    spec, block_size, kv_mode)
+        return _paged_layer_llama(xc, lw, kf, vf, ksf, vsf, pos, tabs,
+                                  spec, cos, sin, block_size, kv_mode)
 
-    xs = (params["layers"], kc, vc) + ((ksc, vsc) if quantized else ())
-    xt, ys = jax.lax.scan(layer, xt, xs)
-    if quantized:
-        kc, vc, ksc, vsc = ys
-    else:
-        kc, vc = ys
+    xt, kc, vc, ksc, vsc = _scan_layers(layer, xt, params, tables, kc, vc,
+                                        ksc, vsc)
     lg = _logits(xt, params, spec)                       # [B, V] f32
     if any_sample:
         key, sub = jax.random.split(key)
@@ -311,11 +330,11 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     quantized = kv_mode != "model"
     c = ids.shape[1]
     dtype = params["embed"].dtype
-    kc = kc.at[:, cow_dst].set(kc[:, cow_src])
-    vc = vc.at[:, cow_dst].set(vc[:, cow_src])
+    kc = copy_block(kc, cow_src, cow_dst)
+    vc = copy_block(vc, cow_src, cow_dst)
     if quantized:
-        ksc = ksc.at[:, cow_dst].set(ksc[:, cow_src])
-        vsc = vsc.at[:, cow_dst].set(vsc[:, cow_src])
+        ksc = copy_block(ksc, cow_src, cow_dst)
+        vsc = copy_block(vsc, cow_src, cow_dst)
     pos = start + jnp.arange(c)
     x = params["embed"][ids[0]].astype(dtype)            # [C, H]
     if gpt:
@@ -331,12 +350,7 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     kv_pos = jnp.arange(ctx_pages * block_size)
     q_mask = kv_pos[None, :] <= pos[:, None]             # [C, T]
 
-    def layer(xc, per_layer):
-        if quantized:
-            lw, kcl, vcl, kscl, vscl = per_layer
-        else:
-            lw, kcl, vcl = per_layer
-            kscl = vscl = None
+    def layer(xc, lw, row, kcl, vcl, kscl, vscl):
         if gpt:
             hn = _layer_norm(xc, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
             qkv = _mm(hn, lw["qkv"]).reshape(c, 3, spec.num_heads,
@@ -353,18 +367,18 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
             k = _rope(k, cos, sin)
         if quantized:
             scat = _KV_FNS[kv_mode][2]
-            kcl, kscl = scat(kcl, kscl, k, start, true_end, table_row,
+            kcl, kscl = scat(kcl, kscl, k, start, true_end, row,
                              block_size)
-            vcl, vscl = scat(vcl, vscl, v, start, true_end, table_row,
+            vcl, vscl = scat(vcl, vscl, v, start, true_end, row,
                              block_size)
         else:
-            kcl = scatter_chunk(kcl, k, start, true_end, table_row,
-                                block_size)
-            vcl = scatter_chunk(vcl, v, start, true_end, table_row,
-                                block_size)
-        kx = gather_context(kcl, kscl, table_row, ctx_pages,
+            kcl = scatter_chunk_rows(kcl, k, start, true_end, row,
+                                     block_size)
+            vcl = scatter_chunk_rows(vcl, v, start, true_end, row,
+                                     block_size)
+        kx = gather_context(kcl, kscl, row, ctx_pages,
                             int4=kv_mode == "int4")
-        vx = gather_context(vcl, vscl, table_row, ctx_pages,
+        vx = gather_context(vcl, vscl, row, ctx_pages,
                             int4=kv_mode == "int4")
         kx = _repeat_kv(kx.astype(q.dtype), rep, 1)      # [T, Hq, D]
         vx = _repeat_kv(vx.astype(q.dtype), rep, 1)
@@ -387,15 +401,10 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
             hn2 = _rms_norm(xo, lw["post_ln"], spec.rms_eps)
             xo = xo + _mm(jax.nn.silu(_mm(hn2, lw["gate"]))
                           * _mm(hn2, lw["up"]), lw["down"])
-        ys = (kcl, vcl, kscl, vscl) if quantized else (kcl, vcl)
-        return xo, ys
+        return xo, kcl, vcl, kscl, vscl
 
-    xs = (params["layers"], kc, vc) + ((ksc, vsc) if quantized else ())
-    x, ys = jax.lax.scan(layer, x, xs)
-    if quantized:
-        kc, vc, ksc, vsc = ys
-    else:
-        kc, vc = ys
+    x, kc, vc, ksc, vsc = _scan_layers(layer, x, params, table_row, kc, vc,
+                                       ksc, vsc)
     if emit_token:
         x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=0)
         lg = _logits(x_last, params, spec)               # [1, V]
@@ -495,12 +504,7 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     q_mask = kv_pos[None, None, :] <= qpos[:, :, None]    # [B, C, T]
     nh, nkv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
 
-    def layer(xc, per_layer):
-        if quantized:
-            lw, kcl, vcl, kscl, vscl = per_layer
-        else:
-            lw, kcl, vcl = per_layer
-            kscl = vscl = None
+    def layer(xc, lw, rows, kcl, vcl, kscl, vscl):
         if gpt:
             hn = _layer_norm(xc, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
             qkv = _mm(hn.reshape(b * c, -1), lw["qkv"]).reshape(
@@ -521,21 +525,21 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
             if quantized:
                 scat = _KV_FNS[kv_mode][2]
                 kcl, kscl = scat(kcl, kscl, k[bi], pos[bi], end[bi],
-                                 tables[bi], block_size)
+                                 rows[bi], block_size)
                 vcl, vscl = scat(vcl, vscl, v[bi], pos[bi], end[bi],
-                                 tables[bi], block_size)
+                                 rows[bi], block_size)
             else:
-                kcl = scatter_chunk(kcl, k[bi], pos[bi], end[bi],
-                                    tables[bi], block_size)
-                vcl = scatter_chunk(vcl, v[bi], pos[bi], end[bi],
-                                    tables[bi], block_size)
+                kcl = scatter_chunk_rows(kcl, k[bi], pos[bi], end[bi],
+                                         rows[bi], block_size)
+                vcl = scatter_chunk_rows(vcl, v[bi], pos[bi], end[bi],
+                                         rows[bi], block_size)
         i4 = kv_mode == "int4"
         kx = jax.vmap(
             lambda tr: gather_context(kcl, kscl, tr, pages,
-                                      int4=i4))(tables)
+                                      int4=i4))(rows)
         vx = jax.vmap(
             lambda tr: gather_context(vcl, vscl, tr, pages,
-                                      int4=i4))(tables)
+                                      int4=i4))(rows)
         kx = _repeat_kv(kx.astype(q.dtype), rep, 2)       # [B, T, Hq, D]
         vx = _repeat_kv(vx.astype(q.dtype), rep, 2)
         scores = jnp.einsum("bchd,bthd->bhct", q, kx) * inv_scale
@@ -561,15 +565,10 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
             xo = xo + _mm(jax.nn.silu(_mm(hn2, lw["gate"]))
                           * _mm(hn2, lw["up"]),
                           lw["down"]).reshape(b, c, -1)
-        ys = (kcl, vcl, kscl, vscl) if quantized else (kcl, vcl)
-        return xo, ys
+        return xo, kcl, vcl, kscl, vscl
 
-    xs = (params["layers"], kc, vc) + ((ksc, vsc) if quantized else ())
-    x, ys = jax.lax.scan(layer, x, xs)
-    if quantized:
-        kc, vc, ksc, vsc = ys
-    else:
-        kc, vc = ys
+    x, kc, vc, ksc, vsc = _scan_layers(layer, x, params, tables, kc, vc,
+                                       ksc, vsc)
     lg = _logits(x.reshape(b * c, -1), params, spec).reshape(
         b, c, -1)                                          # [B, C, V] f32
     acc, tgt, key = _verify_tokens(lg, toks[:, 1:], samp, key,
@@ -623,10 +622,13 @@ _SERVING_EXECUTABLES: dict = {}
 
 class _StackedPrograms:
     """The dense side of `ServingEngine` (llama / gpt: layers stacked by
-    `_stacked_params*`, one pool with a layer axis): for `_run_chunk` and
-    `_decode` each site's step function with its operands, and what its
-    result means. `inference/layered.LayeredPrograms` is its counterpart
-    for a model that declares its layers one by one, method for method."""
+    `_stacked_params*`; one pool array `[L, N, H_kv, bs, D]`, which the
+    step programs see as `[L * N, ...]`, carry through their layer scan
+    and address by `l * N + block id`: `_scan_layers`): for `_run_chunk`
+    and `_decode` each site's step function with its operands, and what
+    its result means. `inference/layered.LayeredPrograms` is its
+    counterpart for a model that declares its layers one by one, method
+    for method."""
 
     whole_prompt_prefill = True
 

@@ -15,10 +15,11 @@ Pieces:
     stale contents are never attended to because the length mask bounds
     every read and appends overwrite before reads reach them).
   * `PagedKVCache` — the device arrays: `[L, num_blocks, H_kv,
-    block_size, D]` per k/v (layer axis outermost so the per-step
-    program's `lax.scan` over stacked layer weights threads the matching
-    cache slice), plus per-(layer, block) f32 scales when the storage
-    dtype is int8.
+    block_size, D]` per k/v (layer axis outermost, so that the step
+    programs see the pool as `[L * num_blocks, ...]` for free and their
+    `lax.scan` over stacked layer weights addresses layer l's blocks at
+    `l * num_blocks + id`), plus per-(layer, block) f32 scales when the
+    storage dtype is int8.
   * pure jnp functions used INSIDE the compiled step programs: decode
     append (scatter one token per slot through the block table) and
     prefill scatter (page-granular), each with an int8 variant that
@@ -465,14 +466,51 @@ class LayeredKVCache:
 
 # ---------------------------------------------------- in-program updates
 # All functions below are pure jnp and run inside the compiled step
-# programs; `cache`/`scale` arguments are ONE layer's slice
-# ([num_blocks, H_kv, block_size, D] / [num_blocks]).
+# programs. Unless a docstring says "stacked", `cache`/`scale` arguments
+# are ONE pool `[num_blocks, H_kv, block_size, D]` / `[num_blocks]`
+# addressed by block id. That is a layer's own pool (inference/layered.py)
+# or, in the stacked programs (inference/engine.py), the WHOLE stacked
+# pool seen as `[L * num_blocks, ...]` (`flat_pool`) with layer l's ids
+# and table entries offset by `l * num_blocks`: the pool rides the layer
+# scan as a carry and is never sliced, so every write below lands in the
+# donated buffer in place. Layer l's trash block is then `l * num_blocks`;
+# a write these functions mask themselves goes to flat block 0, layer 0's
+# trash block. Either is never read.
 
-def append_token(cache, kv, block_ids, offsets):
-    """Scatter one token per slot: kv [B, H_kv, D] written at
-    (block_ids[b], :, offsets[b]). Padded slots route block_ids to the
-    trash block; duplicate trash destinations are harmless."""
-    return cache.at[block_ids, :, offsets].set(kv.astype(cache.dtype))
+def flat_pool(a):
+    """A stacked pool `[L, N, ...]` (or its `[L, N]` scales) seen as
+    `[L * N, ...]`: a free reshape, the `(block_size, D)` tile is
+    untouched."""
+    return a.reshape((-1,) + a.shape[2:])
+
+
+def stacked_block_ids(cache, ids):
+    """Flat ids `[L * P]` (layer-major) of blocks `ids` [P] in every layer
+    of the stacked `cache` `[L, N, ...]`."""
+    l, n = cache.shape[:2]
+    return (jnp.arange(l, dtype=jnp.int32)[:, None] * n
+            + ids.astype(jnp.int32)[None, :]).reshape(-1)
+
+
+def _set_blocks(cache, ids, tiles):
+    """Stacked `cache` [L, N, ...] with blocks `ids` [P] of every layer
+    set to `tiles` [L, P, ...]: ONE scatter along the major dimension of
+    the flat view, so the donated pool is updated in place
+    (`cache.at[:, ids].set(tiles)` scatters dimension 1 and copies the
+    pool around it)."""
+    flat = flat_pool(cache).at[stacked_block_ids(cache, ids)].set(
+        flat_pool(tiles).astype(cache.dtype))
+    return flat.reshape(cache.shape)
+
+
+def copy_block(cache, src, dst):
+    """Copy-on-write on a stacked `cache` [L, N, ...] (or its scales):
+    block `src` of every layer duplicated into block `dst` (both
+    TRASH_BLOCK = a no-op that rewrites each layer's trash block)."""
+    flat = flat_pool(cache)
+    tiles = flat[stacked_block_ids(cache, jnp.reshape(src, (1,)))]
+    return flat.at[stacked_block_ids(cache, jnp.reshape(dst, (1,)))].set(
+        tiles).reshape(cache.shape)
 
 
 def append_token_int8(cache, scale, kv, block_ids, offsets):
@@ -513,15 +551,17 @@ def _prefill_pages(ks, true_len, table_row, block_size):
 
 def scatter_prefill(cache, ks, true_len, table_row, block_size):
     """Write a whole prompt's K (or V) into its pages in one scatter.
-    ks [L, S, H_kv, D]; positions >= true_len land in the trash block."""
+    Stacked cache [L, N, H_kv, bs, D], ks [L, S, H_kv, D]; positions >=
+    true_len land in each layer's trash block."""
     tiles, dest, _ = _prefill_pages(ks, true_len, table_row, block_size)
-    return cache.at[:, dest].set(tiles.astype(cache.dtype))
+    return _set_blocks(cache, dest, tiles)
 
 
 def scatter_prefill_int8(cache, scale, ks, true_len, table_row,
                          block_size):
-    """Int8 prefill scatter: one scale per (layer, page) over the page's
-    valid tokens, whole-page requantized write. Returns (cache, scale)."""
+    """Int8 prefill scatter (stacked cache and scale): one scale per
+    (layer, page) over the page's valid tokens, whole-page requantized
+    write. Returns (cache, scale)."""
     tiles, dest, tok_valid = _prefill_pages(ks, true_len, table_row,
                                             block_size)
     tf = tiles.astype(jnp.float32)                 # [L, P_b, Hkv, bs, D]
@@ -530,38 +570,17 @@ def scatter_prefill_int8(cache, scale, ks, true_len, table_row,
     new_scale = jnp.maximum(amax / 127.0, 1e-8)
     q8 = jnp.clip(jnp.round(tf / new_scale[:, :, None, None, None]),
                   -127, 127).astype(jnp.int8)
-    return (cache.at[:, dest].set(q8),
-            scale.at[:, dest].set(new_scale))
+    return (_set_blocks(cache, dest, q8),
+            _set_blocks(scale, dest, new_scale))
 
 
 # ------------------------------------------------ chunked-prefill updates
-# One LAYER's cache slice, like the decode appends above — these run
-# inside the chunk-prefill program's layer scan. Unlike scatter_prefill
+# One pool addressed by block id, like the decode appends above — these
+# run inside the chunk-prefill program's layer scan. Unlike scatter_prefill
 # the chunk's first position is NOT page-aligned (a prefix-cache hit can
 # start a suffix mid-block after copy-on-write), so the scatter is
-# token-granular: position p lands at (table_row[p // bs], p % bs).
-
-def scatter_chunk(cache, ks, start, true_end, table_row, block_size):
-    """Write one chunk's K (or V) through the block table. ks [C, H_kv, D]
-    holds positions [start, start + C); positions >= true_end route to
-    the trash block. cache is one layer's [num_blocks, H_kv, bs, D].
-
-    Speculative verify windows (round 16) reuse this scatter with
-    chunk = K+1 candidate tokens. Rollback of rejected candidates is
-    NOT an erase: the host simply does not advance the slot's kv_len
-    past the accepted prefix, so the stale-data contract above makes
-    the rejected K/V unreachable (length masks bound every read), and
-    the next window idempotently overwrites the same positions."""
-    c = ks.shape[0]
-    pos = start + jnp.arange(c)
-    ok = pos < true_end
-    page = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
-    blk = jnp.where(ok, table_row[page], TRASH_BLOCK)
-    off = (pos % block_size).astype(jnp.int32)
-    # dims 0 and 2 take advanced indices with a slice between, so the
-    # update value keeps ks's own [C, H_kv, D] layout
-    return cache.at[blk, :, off].set(ks.astype(cache.dtype))
-
+# token-granular: position p lands at (table_row[p // bs], p % bs). The
+# float pools' form is `scatter_chunk_rows`, further down.
 
 def scatter_chunk_int8(cache, scale, ks, start, true_end, table_row,
                        block_size):
@@ -640,9 +659,9 @@ def append_token_int4(cache, scale, kv, block_ids, offsets):
 
 def scatter_prefill_int4(cache, scale, ks, true_len, table_row,
                          block_size):
-    """Int4 prefill scatter: one scale per (layer, page) over the page's
-    valid tokens, whole-page requantized + packed write. Returns
-    (cache, scale)."""
+    """Int4 prefill scatter (stacked cache and scale): one scale per
+    (layer, page) over the page's valid tokens, whole-page requantized +
+    packed write. Returns (cache, scale)."""
     tiles, dest, tok_valid = _prefill_pages(ks, true_len, table_row,
                                             block_size)
     tf = tiles.astype(jnp.float32)                 # [L, P_b, Hkv, bs, D]
@@ -650,8 +669,8 @@ def scatter_prefill_int4(cache, scale, ks, true_len, table_row,
                    axis=(2, 3, 4))                 # [L, P_b]
     new_scale = jnp.maximum(amax / INT4_QMAX, 1e-8)
     packed = _requant_pack_int4(tf, new_scale, 2)
-    return (cache.at[:, dest].set(packed),
-            scale.at[:, dest].set(new_scale))
+    return (_set_blocks(cache, dest, packed),
+            _set_blocks(scale, dest, new_scale))
 
 
 def scatter_chunk_int4(cache, scale, ks, start, true_end, table_row,
@@ -685,15 +704,15 @@ def scatter_chunk_int4(cache, scale, ks, start, true_end, table_row,
     return (cache.at[dest].set(packed), scale.at[dest].set(new_scale))
 
 
-# ------------------------------------------- row-scatter forms (layered)
-# The same writes as `append_token` / `scatter_chunk`, as a scatter of
-# whole [D] rows into the pool seen as [N * H_kv * block_size, D]. The
-# reshape is free (the (block_size, D) tile is untouched) and the one
-# scattered dimension is the major one, so XLA updates the donated pool
-# in place; scattering dimensions 0 and 2 of the 4-D array makes it
-# copy the whole pool into another layout and back around the scatter
-# (PERF.md, PR 31). The layered programs (inference/layered.py) use
-# these; the stacked-pool programs keep the forms above.
+# ------------------------------------------------- float pools: by rows
+# A float pool's token-granular writes, as a scatter of whole [D] rows
+# into the pool seen as [N * H_kv * block_size, D]. The reshape is free
+# (the (block_size, D) tile is untouched) and the one scattered dimension
+# is the major one, so XLA updates the donated pool in place; the form
+# these replaced, `cache.at[blk, :, off].set(kv)`, scatters dimensions 0
+# and 2 of the 4-D array and made the chip's compiler copy the whole pool
+# into another layout and back around it (PERF.md, PR 31 and PR 32). Every
+# step program writes a float pool through these.
 
 def _pool_rows(cache, blocks, offsets):
     """Flat row index [T, H_kv] of (blocks[t], h, offsets[t])."""
@@ -711,14 +730,23 @@ def _set_rows(cache, rows, kv):
 
 
 def append_rows(cache, kv, block_ids, offsets):
-    """`append_token` by rows: kv [B, H_kv, D] at (block_ids[b], :,
-    offsets[b]); padded slots route to the trash block."""
+    """Scatter one token per slot: kv [B, H_kv, D] written at
+    (block_ids[b], :, offsets[b]). Padded slots route block_ids to a
+    trash block; duplicate trash destinations are harmless."""
     return _set_rows(cache, _pool_rows(cache, block_ids, offsets), kv)
 
 
 def scatter_chunk_rows(cache, ks, start, true_end, table_row, block_size):
-    """`scatter_chunk` by rows: ks [C, H_kv, D] holds positions
-    [start, start + C); positions >= true_end route to the trash block."""
+    """Write one chunk's K (or V) through the block table. ks [C, H_kv, D]
+    holds positions [start, start + C); positions >= true_end route to
+    the trash block.
+
+    Speculative verify windows (round 16) reuse this scatter with
+    chunk = K+1 candidate tokens. Rollback of rejected candidates is
+    NOT an erase: the host simply does not advance the slot's kv_len
+    past the accepted prefix, so the stale-data contract above makes
+    the rejected K/V unreachable (length masks bound every read), and
+    the next window idempotently overwrites the same positions."""
     pos = start + jnp.arange(ks.shape[0])
     page = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
     blk = jnp.where(pos < true_end, table_row[page], TRASH_BLOCK)

@@ -166,6 +166,120 @@ def test_paged_decode_two_kinds(for_chip, kind):
     assert ("paged_window_decode" in text) == windowed
 
 
+# ------------------------------------- the dense serving step programs
+# `inference/engine.py`'s decode program and one chunk program as
+# mistral-7b.serve-chat runs them (32/8 heads x 128, FFN 14336, 16 slots x
+# 4096 positions in pages of 16: 4097 blocks a layer), 2 of the 8 layers,
+# abstract operands. What is held: neither program copies a pool. Their
+# temporaries stay under ONE layer's slice of one pool (134 MB in bf16;
+# with the pools as the layer scan's xs/ys and a 4-D scatter the decode
+# program held 1.6 GB of them at 2 layers, 2.95 GB at 8), and the compiled
+# text has no copy and no dynamic-update-slice the size of a pool or of a
+# layer's slice of it.
+
+SERVE_CELL = dict(hidden=4096, ffn=14336, heads=32, kv_heads=8, vocab=32000,
+                  layers=2, slots=16, max_len=4096, block=16, chunk=256,
+                  ctx_pages=128)
+
+
+def _dense_programs(kv, sharding):
+    """(name, lowered) of the decode and the chunk program at SERVE_CELL."""
+    from paddle_tpu.inference import engine as E
+    from paddle_tpu.text.generation import _GenSpec
+
+    g = SERVE_CELL
+    h, f, nh, nkv, v, n_l = (g[k] for k in ("hidden", "ffn", "heads",
+                                            "kv_heads", "vocab", "layers"))
+    bs, slots = g["block"], g["slots"]
+    pages = g["max_len"] // bs
+    blocks = slots * pages + 1
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    spec = _GenSpec(num_layers=n_l, num_heads=nh, num_kv_heads=nkv,
+                    head_dim=HEAD_DIM, rope_theta=1e4, rms_eps=1e-5,
+                    max_new_tokens=0, do_sample=False, top_k=0, top_p=1.0,
+                    temperature=1.0, eos_token_id=-1, tie_embeddings=False)
+    params = {
+        "embed": sds((v, h)), "final_ln": sds((h,)), "lm_head": sds((h, v)),
+        "rope_cos": sds((g["max_len"], HEAD_DIM)),
+        "rope_sin": sds((g["max_len"], HEAD_DIM)),
+        "layers": {"q": sds((n_l, h, nh * HEAD_DIM)),
+                   "k": sds((n_l, h, nkv * HEAD_DIM)),
+                   "v": sds((n_l, h, nkv * HEAD_DIM)),
+                   "o": sds((n_l, nh * HEAD_DIM, h)),
+                   "gate": sds((n_l, h, f)), "up": sds((n_l, h, f)),
+                   "down": sds((n_l, f, h)), "input_ln": sds((n_l, h)),
+                   "post_ln": sds((n_l, h))}}
+    pool = sds((n_l, blocks, nkv, bs, HEAD_DIM),
+               BF16 if kv == "bf16" else jnp.int8)
+    scale = None if kv == "bf16" else sds((n_l, blocks), jnp.float32)
+    mode = "model" if kv == "bf16" else kv
+    i32 = jnp.int32
+
+    def samp(b):
+        return {"do_sample": sds((b,), jnp.bool_),
+                "temperature": sds((b,), jnp.float32),
+                "top_k": sds((b,), i32), "top_p": sds((b,), jnp.float32)}
+
+    key = sds((2,), jnp.uint32)
+    yield "decode", pool, E._decode_step.lower(
+        spec, bs, mode, False, params, sds((slots,), i32),
+        sds((slots,), i32), sds((slots, pages), i32), pool, pool, scale,
+        scale, samp(slots), key)
+    yield "chunk", pool, E._chunk_prefill_step.lower(
+        spec, bs, mode, False, False, g["ctx_pages"], params,
+        sds((1, g["chunk"]), i32), sds((), i32), sds((), i32),
+        sds((), i32), sds((pages,), i32), sds((), i32), sds((), i32), pool,
+        pool, scale, scale, samp(1), key)
+
+
+def _pool_sized_copies(text, pool):
+    """Lines of a compiled program whose instruction (or fusion, by its
+    name) is a copy or a dynamic-update-slice and whose result has as many
+    elements of the pool's type as one layer's slice of it, or more."""
+    import math
+    import re
+
+    slice_elems = math.prod(pool.shape[1:])
+    dt = {"bfloat16": "bf16", "int8": "s8"}[jnp.dtype(pool.dtype).name]
+    op = re.compile(r"^\s*(?:ROOT )?%(\S+) = " + dt
+                    + r"\[([0-9,]+)\]\S* ([a-z\-]+)\(")
+    found = []
+    for line in text.splitlines():
+        m = op.match(line)
+        if not m:
+            continue
+        name, dims, opcode = m.groups()
+        moves = opcode in ("copy", "dynamic-update-slice") or (
+            opcode == "fusion" and ("copy" in name or "update" in name))
+        if moves and math.prod(map(int, dims.split(","))) >= slice_elems:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_dense_step_programs_copy_no_pool(for_chip, one_chip, monkeypatch,
+                                          kv):
+    from paddle_tpu.ops import pallas_decode
+
+    # the router asks `jax.default_backend()`, which is the CPU here
+    monkeypatch.setattr(pallas_decode, "use_pallas_decode",
+                        lambda *a, **k: True)
+    for name, pool, lowered in _dense_programs(kv, one_chip):
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        if name == "decode":
+            assert "paged_decode" in text
+        one_slice = pool.size // pool.shape[0] * pool.dtype.itemsize
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < one_slice, (
+            f"{name}/{kv}: {temp / 1e6:.0f} MB of temporaries, a layer's "
+            f"pool slice is {one_slice / 1e6:.0f} MB")
+        assert not _pool_sized_copies(text, pool), name
+
+
 # ------------------------------------------------- fused norm/rope/swiglu
 
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "fwd_bwd"])

@@ -16,10 +16,10 @@ from paddle_tpu.text.paged_cache import (BlockAllocator, PagedKVCache,
                                          blocks_for)
 
 
-def _tiny(vocab=128, kv_heads=None, max_pos=64):
+def _tiny(vocab=128, kv_heads=None, max_pos=64, layers=2):
     paddle.seed(0)
     cfg = LlamaConfig(vocab_size=vocab, hidden_size=32, intermediate_size=64,
-                      num_hidden_layers=2, num_attention_heads=4,
+                      num_hidden_layers=layers, num_attention_heads=4,
                       num_key_value_heads=kv_heads,
                       max_position_embeddings=max_pos)
     m = LlamaForCausalLM(cfg)
@@ -27,11 +27,11 @@ def _tiny(vocab=128, kv_heads=None, max_pos=64):
     return m
 
 
-def _tiny_gpt():
+def _tiny_gpt(layers=2):
     from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
     paddle.seed(0)
-    cfg = GPTConfig(vocab_size=96, hidden_size=32, num_hidden_layers=2,
+    cfg = GPTConfig(vocab_size=96, hidden_size=32, num_hidden_layers=layers,
                     num_attention_heads=4, max_position_embeddings=64)
     m = GPTForCausalLM(cfg)
     m.eval()
@@ -437,6 +437,171 @@ class TestServingPredictor:
         got = pred.generate([prompt], max_new_tokens=4)[0]
         want = generate_paged(m, prompt[None], 4)[0]
         np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------------------ pools by offset (PR 32)
+# The stacked programs see the pools `[L, N, H_kv, bs, D]` as
+# `[L * N, ...]`, carry them through the layer scan and address layer l's
+# blocks at `l * N + id`: no slice of a pool leaves the donated buffer.
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_pools_ride_the_layer_scan_as_carry(program):
+    m = _tiny(kv_heads=2)
+    eng = ServingEngine(m, max_slots=2, kv_block_size=8, prefix_cache=False)
+    closed = eng.decode_program_jaxpr() if program == "decode" \
+        else eng.verify_program_jaxpr()
+    layers, blocks = eng.cache.k.shape[:2]
+    flat = (layers * blocks,) + eng.cache.k.shape[2:]
+    scans = [e for e in _eqns(closed.jaxpr) if e.primitive.name == "scan"
+             and e.params["length"] == layers]
+    assert len(scans) == 1
+    scan = scans[0]
+    nc, ncar = scan.params["num_consts"], scan.params["num_carry"]
+    shapes = [tuple(v.aval.shape) for v in scan.invars]
+    assert shapes[nc:nc + ncar].count(flat) == 2          # k and v
+    pool_like = [sh for sh in shapes[:nc] + shapes[nc + ncar:]
+                 + [tuple(v.aval.shape) for v in scan.outvars[ncar:]]
+                 if sh[-3:] == flat[-3:]]
+    assert pool_like == [], "a pool rides the scan as a constant, xs or ys"
+    scatters = [e for e in _eqns(scan.params["jaxpr"].jaxpr)
+                if e.primitive.name.startswith("scatter")]
+    assert scatters, "the layer writes no K/V"
+    for e in scatters:
+        dims = e.params["dimension_numbers"].scatter_dims_to_operand_dims
+        assert len(dims) == 1, f"a scatter over dimensions {dims}"
+
+
+def _dense_kv(eng, tokens):
+    """Every layer's K and V of `tokens` by the static engine's dense
+    causal forward: [L, S, H_kv, D] each."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.text import generation as g
+
+    params, spec = eng.params, eng.spec
+    ids = jnp.asarray(np.asarray(tokens, np.int32))[None]
+    if spec.arch == "gpt":
+        x = params["embed"][ids] + params["wpe"][None, :ids.shape[1]]
+        _, (ks, vs) = jax.lax.scan(
+            lambda xc, lw: g._gpt_layer_prefill(xc, lw, spec), x,
+            params["layers"])
+    else:
+        _, (ks, vs) = jax.lax.scan(
+            lambda xc, lw: g._layer_forward_prefill(
+                xc, lw, spec, params["rope_cos"], params["rope_sin"]),
+            params["embed"][ids], params["layers"])
+    return np.asarray(ks[:, 0], np.float32), np.asarray(vs[:, 0],
+                                                        np.float32)
+
+
+#: how far a cache row may lie from the dense forward's, as a share of the
+#: largest magnitude in the prompt's K (or V): float pools differ by the
+#: paged attention's rounding, int8 by half a step of 1/127, int4 by half
+#: a step of 1/7 and what re-quantising a block on every append adds
+_ROW_TOL = {"model": 1e-4, "int8": 0.02, "int4": 0.25}
+
+
+@pytest.mark.parametrize("mode", ["model", "int8", "int4"])
+@pytest.mark.parametrize("arch", ["llama", "gpt"])
+def test_each_layer_writes_and_reads_its_own_pages(arch, mode):
+    """A prompt by chunks, a whole short prompt, a copy-on-write prefix
+    hit and some decode ticks, three layers with K/V of their own: layer
+    l's pages hold layer l's rows, every block nobody was given is as it
+    was made, each layer's block 0 took that layer's masked writes, and
+    the float cache's tokens are the static engine's."""
+    from paddle_tpu.text.paged_cache import gather_context
+
+    m = _tiny_gpt(layers=3) if arch == "gpt" \
+        else _tiny(vocab=96, kv_heads=2, layers=3)
+    bs, new = 8, 12
+    eng = ServingEngine(m, max_slots=4, kv_block_size=bs, num_kv_blocks=48,
+                        prefix_cache=True, chunked_prefill_tokens=16,
+                        kv_cache_dtype=None if mode == "model" else mode)
+    rs = np.random.RandomState(11)
+    shared, by_chunks, short = (rs.randint(0, 96, (n,))
+                                for n in (16, 40, 9))
+    given = set()
+
+    def step():
+        out = eng.step()
+        for blocks in eng._slot_blocks:
+            given.update(blocks)
+        return out
+
+    # the shared prompt once to its end: its two blocks enter the cache
+    first = eng.add_request(shared, max_new_tokens=new)
+    while eng.has_work():
+        step()
+    # then three requests at once, and three slots of a bucket of four
+    prompts = {eng.add_request(p, max_new_tokens=new): p
+               for p in (by_chunks, short, shared)}
+    while eng.num_waiting or min(len(r.tokens) for r in eng._slot_req
+                                 if r is not None) < 3:
+        step()
+    st = eng.stats()
+    assert st["prefill_chunks"] >= 4 and st["prefix_blocks_hit"] == 2
+    assert eng.num_active == 3
+
+    c = eng.cache
+    int4 = mode == "int4"
+    for slot, req in enumerate(eng._slot_req):
+        if req is None:
+            continue
+        n = int(eng._slot_pos[slot])             # positions in the cache
+        seq = np.concatenate([req.prompt, req.tokens])[:n]
+        for pool, scale, want in zip(
+                (c.k, c.v), (c.k_scale, c.v_scale), _dense_kv(eng, seq)):
+            tol = _ROW_TOL[mode] * np.abs(want).max()
+            for layer in range(3):
+                got = np.asarray(gather_context(
+                    pool[layer], None if scale is None else scale[layer],
+                    eng._tables[slot], eng.pages, int4=int4),
+                    np.float32)[:n]
+                err = [np.abs(got - want[other]).max()
+                       for other in range(3)]
+                assert err[layer] <= tol, (slot, layer, err, tol)
+                assert min(err[:layer] + err[layer + 1:]) > 2 * tol, \
+                    "the layers' rows are too alike to tell apart"
+
+    never = sorted(set(range(1, c.num_blocks)) - given)
+    assert len(never) > 10
+    for pool in (c.k, c.v):
+        pool = np.asarray(pool, np.float32)
+        assert not pool[:, never].any(), "a block nobody was given"
+        # the padded fourth row of the decode bucket wrote every layer's
+        # own trash block, `l * N` of the flat pool
+        assert all(pool[layer, 0].any() for layer in range(3))
+    if c.k_scale is not None:
+        for scale in (c.k_scale, c.v_scale):
+            assert np.all(np.asarray(scale)[:, never] == np.float32(1e-8))
+
+    while eng.has_work():
+        step()
+    prompts[first] = shared
+    same = []
+    for rid, prompt in prompts.items():
+        want = np.asarray(m.generate(
+            paddle.to_tensor(prompt[None].astype("int64")),
+            max_new_tokens=new)._data)[0, prompt.size:]
+        got = eng.completed[rid]
+        if mode == "model" or prompt is short:
+            np.testing.assert_array_equal(got, want)
+        same.extend(got == want)
+    # a quantized cache rounds what a long prompt's last chunk and every
+    # later tick read: most tokens are the float cache's, not all
+    assert np.mean(same) >= 0.5, (mode, same)
 
 
 def test_registered_in_quick_tier():
