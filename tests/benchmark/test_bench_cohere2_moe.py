@@ -210,13 +210,20 @@ def test_the_cell_runs_through_the_harness(traced):
     assert out["attempted"] == round(3.0 * 0.93 * 3.0)
     got = out["metrics"]
     # every accepted serve metric the cell was appended to, and the two
-    # span metrics this PR adds; no decode kernel runs on the CPU, so the
-    # two rooflines stay silent
-    for name in ("queue_wait_p50_ms", "slot_occupancy", "decode_tick_ms",
-                 "step_mfu.serve", "device_idle.serve", "decode_run_ms",
-                 "chunk_prefill_ms_per_ktok", "engine_host_share",
-                 "moe_expert_imbalance", "kv_bytes_per_live_token"):
+    # span metrics PR 31 added; no decode kernel runs on the CPU, so the
+    # two rooflines stay silent. Since PR 34 this cell holds `tpot_p95_ms`
+    # to no bound (its p95 gap stands on an edge between two kinds of
+    # tick): the metrics that moved it are here under `.ttft` names, and
+    # the gap itself per layer as `decode_gap_p95_ms`
+    for name in ("queue_wait_p50_ms", "slot_occupancy", "decode_tick_ms.ttft",
+                 "step_mfu.serve.ttft", "device_idle.serve.ttft",
+                 "decode_run_ms.ttft", "chunk_prefill_ms_per_ktok",
+                 "engine_host_share.ttft", "step_host_ms_p95.serve.ttft",
+                 "decode_gap_p95_ms", "moe_expert_imbalance",
+                 "kv_bytes_per_live_token"):
         assert got[name]["value"] > 0, name
+    assert not {"decode_tick_ms", "step_mfu.serve", "decode_run_ms",
+                "tpot_p95_ms"} & set(got)
     assert "paged_decode_full_roofline" not in got
     assert "paged_decode_window_roofline" not in got
     assert "paged_decode_roofline" not in got
@@ -292,39 +299,11 @@ def test_the_sparse_check_counts_the_tokens_that_are_off():
     assert over(comps) == ["served_logit_gap_sigma"]
 
 
-def test_the_accepted_cells_keep_what_pr28_said_of_their_span_metrics():
-    """What `test_bench_program_spans.py::test_manifest_entries_are_the_
-    issues` asserts and this PR's manifest entries leave true (the test
-    itself unpacks the workloads as exactly three cells and is marked in
-    `tests/conftest.py`): every serve span metric still lists the Mistral
-    serve cell first, every train one the two train cells alone, with the
-    source, direction, key set and `moves` PR 28 gave them."""
-    from test_bench_program_spans import SERVE, TRAIN
-
-    by = {m["name"]: m for m in MAN["per_layer"]}
-    serve, t4k, gpt = [w["name"] for w in MAN["workloads"]][:3]
-    assert (serve, t4k, gpt) == ("mistral-7b.serve-chat",
-                                 "mistral-7b.train-4k",
-                                 "cerebras-gpt-1.3b.train-2k")
-    for name in SERVE[:-1]:
-        assert by[name]["workloads"] == [serve, CELL]
-    for name in TRAIN[:-1]:
-        assert by[name]["workloads"] == [t4k, gpt]
-    assert by["setup_compile_s"]["workloads"] == [serve, t4k, gpt, CELL]
-    for name in set(SERVE + TRAIN):
-        m = by[name]
-        assert m["source"] == "program_span" and m["better"] == "lower"
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-    assert {by[n]["moves"] for n in ("setup_warmup_s", "setup_compile_s")} \
-        == {"setup_s"}
-
-
 def test_untraced_run_reports_the_end_to_end_metrics():
     out = harness.run_cell(_tiny_ctx(seed=11))
     assert out["correct"] is True
-    assert {"serve_tokens_per_s", "ttft_p70_ms", "tpot_p95_ms",
-            "setup_s"} <= set(out["metrics"])
+    assert {"serve_tokens_per_s", "ttft_p70_ms",
+            "setup_s"} == set(out["metrics"])
 
 
 def test_new_metric_files_name_readers_that_exist():

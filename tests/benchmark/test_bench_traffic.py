@@ -1,5 +1,7 @@
 """The traffic generator: deterministic in --seed, the clips honoured,
 every seed the same multiset of sizes and gaps."""
+import re
+
 import numpy as np
 import pytest
 
@@ -39,10 +41,14 @@ def test_clips_and_window(seed):
         assert r["prompt"].min() >= 0 and r["prompt"].max() < 32000
 
 
+def _schedule(reqs):
+    return [(r["due_s"], len(r["prompt"]), r["max_new_tokens"])
+            for r in reqs]
+
+
 def test_seeds_share_the_schedule_and_differ_in_tokens():
     a, b = _reqs(3), _reqs(4)
-    assert [(r["due_s"], len(r["prompt"]), r["max_new_tokens"]) for r in a] \
-        == [(r["due_s"], len(r["prompt"]), r["max_new_tokens"]) for r in b]
+    assert _schedule(a) == _schedule(b)
     assert not any(np.array_equal(x["prompt"], y["prompt"])
                    for x, y in zip(a, b))
     other = traffic_gen.serve_requests(dict(MIX, schedule_seed=1), 3, 40.0,
@@ -53,6 +59,29 @@ def test_seeds_share_the_schedule_and_differ_in_tokens():
                                                  for r in a]
     assert traffic_gen.warmup_lengths(MIX, 40.0) \
         == sorted({len(r["prompt"]) for r in a})
+
+
+@pytest.mark.parametrize("name", ["chat-open-loop", "longdoc-open-loop"])
+def test_serve_mix_offers_a_share_of_a_knee_that_was_swept(name):
+    """What a serve cell's rate rests on: `rate_from` names the two swept
+    rates the knee lies between, the mix offers 0.6-0.8 of the lower, and
+    a 51 s window of it is the same requests for every `--seed`."""
+    mix = harness.load_json("traffic", name + ".json")
+    arr = mix["arrivals"]
+    low, high = (float(x) for x in
+                 re.findall(r"of (\d+\.?\d*)", mix["rate_from"])[:2])
+    assert low < high
+    assert 0.6 * low <= arr["rate_per_s"] <= 0.8 * low + 1e-9
+    a, b = (traffic_gen.serve_requests(mix, seed, 51.0, 32000)
+            for seed in (5, BIG_SEED))
+    assert len(a) == round(arr["rate_per_s"] * arr["due_within"] * 51)
+    assert f"{len(a)} requests" in mix["rate_from"]
+    for r in a:
+        assert 1 <= r["max_new_tokens"]
+        assert len(r["prompt"]) + r["max_new_tokens"] \
+            <= mix["max_total_tokens"] <= mix["engine"]["max_model_len"]
+    assert _schedule(a) == _schedule(b)
+    assert 0 < a[0]["due_s"] and a[-1]["due_s"] < arr["due_within"] * 51
 
 
 @pytest.mark.parametrize("spec", [
