@@ -8,15 +8,25 @@ and requests that join freed slots mid-flight instead of waiting for a
 whole static batch to drain.
 
 TPU-native design:
-  - Per step the scheduler runs at most TWO compiled-program families,
-    both static-shaped: a PREFILL program per joining request (keyed by
-    the prompt-length bucket; rides the Pallas flash kernel on TPU and
-    scatters the prompt's K/V into its pages), and ONE DECODE program
+  - The scheduler runs FOUR compiled-program families, all static-shaped:
+    a whole-prompt PREFILL program per joining request whose prompt fits
+    one (keyed by the prompt-length bucket; rides the Pallas flash kernel
+    on TPU and scatters the prompt's K/V into its pages), a CHUNK program
+    (one chunk of one prompt, keyed by chunk and context-pages buckets;
+    chunked prefill and prefix-cache suffixes), ONE DECODE program
     advancing every active slot one token (keyed by the active-slot-count
     bucket — 1/2/4/8/... — so a half-empty engine doesn't pay the full
-    slot array). That is the per-slot prefill-or-decode dispatch: the
-    host decides which program touches each slot, the programs never
-    branch dynamically.
+    slot array), and with speculative decoding a VERIFY program scoring
+    K+1 candidates a slot. The host decides which program touches each
+    slot; the programs never branch dynamically.
+  - The dense architectures' layer is ONE functional block,
+    `text/models/dense_block.block`, and what a program adds is where the
+    keys and values live: its `attend` closure writes the step's K/V
+    through the block table and attends over the pool (`_paged_attn` for
+    decode; scatter, gather and `dense_block.attend_many` for chunk and
+    verify; the sequence in hand for the whole-prompt prefill). A model
+    that declares its layers one by one has `inference/layered.py`'s
+    programs around `parallel_block.block`, in the same idiom.
   - Slot state entering the decode program is COMPACTED: tokens /
     positions / block-table rows / sampling params of the active slots
     are gathered into bucket-sized arrays (cheap — the KV pool itself is
@@ -55,7 +65,6 @@ from __future__ import annotations
 
 import functools
 import itertools
-import math
 import time
 from collections import OrderedDict, deque
 
@@ -66,10 +75,9 @@ import numpy as np
 from ..obs.metrics import DEFAULT_EXACT_CAP
 from ..obs.trace import span as _span
 from ..ops._pallas_common import ceil_to as _ceil_to
-from ..text.generation import (_GenSpec, _gpt_layer_prefill,
-                               _layer_forward_prefill, _layer_norm,
-                               _logits, _mm, _repeat_kv, _rms_norm, _rope,
-                               _stacked_params, _stacked_params_gpt)
+from ..text.generation import _stacked_params, _stacked_params_gpt
+from ..text.models import dense_block as db
+from ..text.models.dense_block import _GenSpec, _logits
 from ..text.paged_cache import (TRASH_BLOCK, BlockAllocator, PagedKVCache,
                                 PrefixCache, append_rows,
                                 append_token_int4, append_token_int8,
@@ -184,43 +192,18 @@ def _paged_attn(hn_q, k_new, v_new, kc, vc, ksc, vsc, tables, pos,
     return out, kc, vc, ksc, vsc
 
 
-def _paged_layer_llama(x, lw, kc, vc, ksc, vsc, pos, tables, spec,
-                       cos, sin, block_size, kv_mode):
-    """One LLaMA block for seq-1 queries at PER-SLOT positions against
-    the paged cache. x [B, H]; kc/vc one pool addressed by `tables` (the
-    whole flat pool, the tables offset to this layer's blocks)."""
-    b, h = x.shape
-    hn = _rms_norm(x, lw["input_ln"], spec.rms_eps)
-    q = _mm(hn, lw["q"]).reshape(b, spec.num_heads, spec.head_dim)
-    k = _mm(hn, lw["k"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-    v = _mm(hn, lw["v"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-    c = cos[pos][:, None]                       # [B, 1, D]
-    sn = sin[pos][:, None]
-    q = _rope(q, c, sn)
-    k = _rope(k, c, sn)
-    out, kc, vc, ksc, vsc = _paged_attn(q, k, v, kc, vc, ksc, vsc,
-                                        tables, pos, block_size, kv_mode)
-    x = x + _mm(out.reshape(b, spec.num_heads * spec.head_dim), lw["o"])
-    hn = _rms_norm(x, lw["post_ln"], spec.rms_eps)
-    mlp = _mm(jax.nn.silu(_mm(hn, lw["gate"])) * _mm(hn, lw["up"]),
-              lw["down"])
-    return x + mlp, kc, vc, ksc, vsc
-
-
-def _paged_layer_gpt(x, lw, kc, vc, ksc, vsc, pos, tables, spec,
-                     block_size, kv_mode):
-    """Pre-LN GPT block, paged decode variant."""
-    b, h = x.shape
-    hn = _layer_norm(x, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-    qkv = _mm(hn, lw["qkv"]).reshape(b, 3, spec.num_heads, spec.head_dim)
-    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-    out, kc, vc, ksc, vsc = _paged_attn(q, k, v, kc, vc, ksc, vsc,
-                                        tables, pos, block_size, kv_mode)
-    x = x + _mm(out.reshape(b, spec.num_heads * spec.head_dim), lw["o"])
-    hn = _layer_norm(x, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-    x = x + _mm(jax.nn.gelu(_mm(hn, lw["fc_in"]), approximate=False),
-                lw["fc_out"])
-    return x, kc, vc, ksc, vsc
+def _scatter_rows(k, v, kc, vc, ksc, vsc, start, end, row, block_size,
+                  kv_mode):
+    """Write positions [start, end) of one slot's new K/V rows through
+    its block table `row` (token-granular), in the cache's mode."""
+    if kv_mode != "model":
+        scat = _KV_FNS[kv_mode][2]
+        kc, ksc = scat(kc, ksc, k, start, end, row, block_size)
+        vc, vsc = scat(vc, vsc, v, start, end, row, block_size)
+    else:
+        kc = scatter_chunk_rows(kc, k, start, end, row, block_size)
+        vc = scatter_chunk_rows(vc, v, start, end, row, block_size)
+    return kc, vc, ksc, vsc
 
 
 # ------------------------------------------------------- step programs
@@ -237,20 +220,17 @@ def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     bare argmax instead of the sort/softmax/cumsum sampling machinery
     over [B, V] every tick.
     """
-    gpt = spec.arch == "gpt"
-    dtype = params["embed"].dtype
-    xt = params["embed"][tok].astype(dtype)              # [B, H]
-    if gpt:
-        xt = xt + params["wpe"][pos]
-    else:
-        cos, sin = params["rope_cos"], params["rope_sin"]
+    xt, rope = db.embed(params, tok, pos, spec)          # [B, H]
 
-    def layer(xc, lw, tabs, kf, vf, ksf, vsf):
-        if gpt:
-            return _paged_layer_gpt(xc, lw, kf, vf, ksf, vsf, pos, tabs,
-                                    spec, block_size, kv_mode)
-        return _paged_layer_llama(xc, lw, kf, vf, ksf, vsf, pos, tabs,
-                                  spec, cos, sin, block_size, kv_mode)
+    def layer(xc, lw, tabs, *pools):
+        pools = list(pools)
+
+        def attend(q, k, v):
+            out, *pools[:] = _paged_attn(q, k, v, *pools, tabs, pos,
+                                         block_size, kv_mode)
+            return out
+
+        return (db.block(xc, lw, spec, attend, rope), *pools)
 
     xt, kc, vc, ksc, vsc = _scan_layers(layer, xt, params, tables, kc, vc,
                                         ksc, vsc)
@@ -271,24 +251,9 @@ def _prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     """Prefill one joining request: full-prompt forward (Pallas flash on
     TPU), page-scatter the prompt K/V through the slot's block table, and
     sample the first token from the last REAL prompt position."""
-    gpt = spec.arch == "gpt"
-    quantized = kv_mode != "model"
-    b, s = ids.shape
-    if gpt:
-        x = params["embed"][ids] + params["wpe"][None, :s]
-
-        def pre(xc, lw):
-            return _gpt_layer_prefill(xc, lw, spec)
-    else:
-        cos, sin = params["rope_cos"], params["rope_sin"]
-        x = params["embed"][ids]
-
-        def pre(xc, lw):
-            return _layer_forward_prefill(xc, lw, spec, cos, sin)
-
-    x, (ks, vs) = jax.lax.scan(pre, x, params["layers"])
+    x, ks, vs = db.forward_sequence(params, ids, spec)
     ks, vs = ks[:, 0], vs[:, 0]                          # [L, S, Hkv, D]
-    if quantized:
+    if kv_mode != "model":
         scat = _KV_FNS[kv_mode][1]
         kc, ksc = scat(kc, ksc, ks, true_len, table_row, block_size)
         vc, vsc = scat(vc, vsc, vs, true_len, table_row, block_size)
@@ -326,82 +291,32 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     = no-op). Context length is static via `ctx_pages` (bucketed): pages
     past the written watermark gather garbage the causal mask never
     reaches."""
-    gpt = spec.arch == "gpt"
-    quantized = kv_mode != "model"
     c = ids.shape[1]
-    dtype = params["embed"].dtype
     kc = copy_block(kc, cow_src, cow_dst)
     vc = copy_block(vc, cow_src, cow_dst)
-    if quantized:
+    if kv_mode != "model":
         ksc = copy_block(ksc, cow_src, cow_dst)
         vsc = copy_block(vsc, cow_src, cow_dst)
     pos = start + jnp.arange(c)
-    x = params["embed"][ids[0]].astype(dtype)            # [C, H]
-    if gpt:
-        pos_safe = jnp.clip(pos, 0, params["wpe"].shape[0] - 1)
-        x = x + params["wpe"][pos_safe]
-        cos = sin = None
-    else:
-        pos_safe = jnp.clip(pos, 0, params["rope_cos"].shape[0] - 1)
-        cos = params["rope_cos"][pos_safe][:, None]      # [C, 1, D]
-        sin = params["rope_sin"][pos_safe][:, None]
-    rep = spec.num_heads // spec.num_kv_heads
-    inv_scale = 1.0 / math.sqrt(spec.head_dim)
+    x, rope = db.embed(                                  # [C, H]
+        params, ids[0],
+        jnp.clip(pos, 0, db.num_positions(params, spec) - 1), spec)
     kv_pos = jnp.arange(ctx_pages * block_size)
     q_mask = kv_pos[None, :] <= pos[:, None]             # [C, T]
+    i4 = kv_mode == "int4"
 
-    def layer(xc, lw, row, kcl, vcl, kscl, vscl):
-        if gpt:
-            hn = _layer_norm(xc, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-            qkv = _mm(hn, lw["qkv"]).reshape(c, 3, spec.num_heads,
-                                             spec.head_dim)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-        else:
-            hn = _rms_norm(xc, lw["input_ln"], spec.rms_eps)
-            q = _mm(hn, lw["q"]).reshape(c, spec.num_heads, spec.head_dim)
-            k = _mm(hn, lw["k"]).reshape(c, spec.num_kv_heads,
-                                         spec.head_dim)
-            v = _mm(hn, lw["v"]).reshape(c, spec.num_kv_heads,
-                                         spec.head_dim)
-            q = _rope(q, cos, sin)
-            k = _rope(k, cos, sin)
-        if quantized:
-            scat = _KV_FNS[kv_mode][2]
-            kcl, kscl = scat(kcl, kscl, k, start, true_end, row,
-                             block_size)
-            vcl, vscl = scat(vcl, vscl, v, start, true_end, row,
-                             block_size)
-        else:
-            kcl = scatter_chunk_rows(kcl, k, start, true_end, row,
-                                     block_size)
-            vcl = scatter_chunk_rows(vcl, v, start, true_end, row,
-                                     block_size)
-        kx = gather_context(kcl, kscl, row, ctx_pages,
-                            int4=kv_mode == "int4")
-        vx = gather_context(vcl, vscl, row, ctx_pages,
-                            int4=kv_mode == "int4")
-        kx = _repeat_kv(kx.astype(q.dtype), rep, 1)      # [T, Hq, D]
-        vx = _repeat_kv(vx.astype(q.dtype), rep, 1)
-        # scores stay rank-4 [1, Hq, C, T]: this is a prefill composition,
-        # not the rank-3 seq-1 decode shape D4's decode anchor matches
-        scores = (jnp.einsum("chd,thd->hct", q, kx) * inv_scale)[None]
-        scores = jnp.where(q_mask[None, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("hct,thd->chd", probs[0], vx)
-        attn = out.reshape(c, spec.num_heads * spec.head_dim)
-        if gpt:
-            xo = xc + _mm(attn, lw["o"])
-            hn2 = _layer_norm(xo, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-            xo = xo + _mm(jax.nn.gelu(_mm(hn2, lw["fc_in"]),
-                                      approximate=False), lw["fc_out"])
-        else:
-            xo = xc + _mm(attn, lw["o"])
-            hn2 = _rms_norm(xo, lw["post_ln"], spec.rms_eps)
-            xo = xo + _mm(jax.nn.silu(_mm(hn2, lw["gate"]))
-                          * _mm(hn2, lw["up"]), lw["down"])
-        return xo, kcl, vcl, kscl, vscl
+    def layer(xc, lw, row, *pools):
+        pools = list(pools)
+
+        def attend(q, k, v):
+            pools[:] = _scatter_rows(k, v, *pools, start, true_end, row,
+                                     block_size, kv_mode)
+            kx = gather_context(pools[0], pools[2], row, ctx_pages, int4=i4)
+            vx = gather_context(pools[1], pools[3], row, ctx_pages, int4=i4)
+            return db.attend_many(q, kx.astype(q.dtype),      # [T, Hkv, D]
+                                  vx.astype(q.dtype), q_mask)
+
+        return (db.block(xc, lw, spec, attend, rope), *pools)
 
     x, kc, vc, ksc, vsc = _scan_layers(layer, x, params, table_row, kc, vc,
                                        ksc, vsc)
@@ -482,90 +397,36 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     same positions are idempotent re-derivations. The accept/emit split
     lives in _verify_tokens; this returns (acc [B, K], tgt [B, C],
     caches..., key)."""
-    gpt = spec.arch == "gpt"
-    quantized = kv_mode != "model"
     b, c = toks.shape
-    dtype = params["embed"].dtype
     qpos = pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
-    x = params["embed"][toks].astype(dtype)               # [B, C, H]
-    if gpt:
-        x = x + params["wpe"][jnp.clip(qpos, 0,
-                                       params["wpe"].shape[0] - 1)]
-        cos = sin = None
-    else:
-        ps = jnp.clip(qpos, 0, params["rope_cos"].shape[0] - 1)
-        cos = params["rope_cos"][ps][:, :, None]          # [B, C, 1, D]
-        sin = params["rope_sin"][ps][:, :, None]
-    rep = spec.num_heads // spec.num_kv_heads
-    inv_scale = 1.0 / math.sqrt(spec.head_dim)
+    x, rope = db.embed(                                   # [B, C, H]
+        params, toks,
+        jnp.clip(qpos, 0, db.num_positions(params, spec) - 1), spec)
     pages = tables.shape[1]
     end = jnp.minimum(pos + c, limit)
     kv_pos = jnp.arange(pages * block_size)
     q_mask = kv_pos[None, None, :] <= qpos[:, :, None]    # [B, C, T]
-    nh, nkv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    i4 = kv_mode == "int4"
 
-    def layer(xc, lw, rows, kcl, vcl, kscl, vscl):
-        if gpt:
-            hn = _layer_norm(xc, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-            qkv = _mm(hn.reshape(b * c, -1), lw["qkv"]).reshape(
-                b, c, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:
-            hn = _rms_norm(xc, lw["input_ln"],
-                           spec.rms_eps).reshape(b * c, -1)
-            q = _mm(hn, lw["q"]).reshape(b, c, nh, hd)
-            k = _mm(hn, lw["k"]).reshape(b, c, nkv, hd)
-            v = _mm(hn, lw["v"]).reshape(b, c, nkv, hd)
-            q = _rope(q, cos, sin)
-            k = _rope(k, cos, sin)
-        # per-row window scatter: the slot bucket is small, so the
-        # unrolled loop reuses the chunk programs' token-granular
-        # scatter (+ its int8 self-healing requantization) unchanged
-        for bi in range(b):
-            if quantized:
-                scat = _KV_FNS[kv_mode][2]
-                kcl, kscl = scat(kcl, kscl, k[bi], pos[bi], end[bi],
-                                 rows[bi], block_size)
-                vcl, vscl = scat(vcl, vscl, v[bi], pos[bi], end[bi],
-                                 rows[bi], block_size)
-            else:
-                kcl = scatter_chunk_rows(kcl, k[bi], pos[bi], end[bi],
-                                         rows[bi], block_size)
-                vcl = scatter_chunk_rows(vcl, v[bi], pos[bi], end[bi],
-                                         rows[bi], block_size)
-        i4 = kv_mode == "int4"
-        kx = jax.vmap(
-            lambda tr: gather_context(kcl, kscl, tr, pages,
-                                      int4=i4))(rows)
-        vx = jax.vmap(
-            lambda tr: gather_context(vcl, vscl, tr, pages,
-                                      int4=i4))(rows)
-        kx = _repeat_kv(kx.astype(q.dtype), rep, 2)       # [B, T, Hq, D]
-        vx = _repeat_kv(vx.astype(q.dtype), rep, 2)
-        scores = jnp.einsum("bchd,bthd->bhct", q, kx) * inv_scale
-        scores = jnp.where(q_mask[:, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhct,bthd->bchd", probs, vx)
-        attn = out.reshape(b, c, nh * hd)
-        if gpt:
-            xo = xc + _mm(attn.reshape(b * c, -1), lw["o"]).reshape(
-                b, c, -1)
-            hn2 = _layer_norm(xo, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-            xo = xo + _mm(
-                jax.nn.gelu(_mm(hn2.reshape(b * c, -1), lw["fc_in"]),
-                            approximate=False),
-                lw["fc_out"]).reshape(b, c, -1)
-        else:
-            xo = xc + _mm(attn.reshape(b * c, -1),
-                          lw["o"]).reshape(b, c, -1)
-            hn2 = _rms_norm(xo, lw["post_ln"],
-                            spec.rms_eps).reshape(b * c, -1)
-            xo = xo + _mm(jax.nn.silu(_mm(hn2, lw["gate"]))
-                          * _mm(hn2, lw["up"]),
-                          lw["down"]).reshape(b, c, -1)
-        return xo, kcl, vcl, kscl, vscl
+    def layer(xc, lw, rows, *pools):
+        pools = list(pools)
+
+        def attend(q, k, v):
+            # per-row window scatter: the slot bucket is small, so the
+            # unrolled loop reuses the chunk programs' token-granular
+            # scatter (+ its int8 self-healing requantization) unchanged
+            for bi in range(b):
+                pools[:] = _scatter_rows(k[bi], v[bi], *pools, pos[bi],
+                                         end[bi], rows[bi], block_size,
+                                         kv_mode)
+            kx = jax.vmap(lambda tr: gather_context(
+                pools[0], pools[2], tr, pages, int4=i4))(rows)
+            vx = jax.vmap(lambda tr: gather_context(
+                pools[1], pools[3], tr, pages, int4=i4))(rows)
+            return db.attend_many(q, kx.astype(q.dtype),   # [B, T, Hkv, D]
+                                  vx.astype(q.dtype), q_mask)
+
+        return (db.block(xc, lw, spec, attend, rope), *pools)
 
     x, kc, vc, ksc, vsc = _scan_layers(layer, x, params, tables, kc, vc,
                                        ksc, vsc)

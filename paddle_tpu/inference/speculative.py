@@ -19,7 +19,10 @@ K+1 tokens per sweep with the output distribution provably unchanged:
     rejections from the residual — exactly p at every position because
     the draft proposes deterministically (a point-mass q).
 
-This module owns everything above the verify program: the SpecConfig
+This module owns everything above the verify program (every program
+here runs `text/models/dense_block.block` with an `attend` over a DENSE
+cache: per-row write and mask positions for the draft, `[B, C]`
+candidate positions for the static verify): the SpecConfig
 selection surface, the two proposers behind one interface (the
 model-free n-gram/prompt-lookup proposer and the small-draft-model
 proposer with its own slot-free cached state), and the static
@@ -40,17 +43,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..text.generation import (_GenSpec, _gpt_layer_prefill,
-                               _layer_forward_prefill, _layer_norm,
-                               _logits, _mm, _repeat_kv, _rms_norm, _rope,
-                               _stacked_params, _stacked_params_gpt)
+from ..text.generation import _stacked_params, _stacked_params_gpt
+from ..text.models import dense_block as db
+from ..text.models.dense_block import _GenSpec, _logits
 
 
 # ------------------------------------------------------------ config
@@ -239,74 +240,12 @@ def _spec_and_params(model):
     return spec, _stacked_params(model)
 
 
-def _dense_decode_layer(x, lw, kc, vc, wpos, mpos, spec, cos, sin):
-    """One decoder block for seq-1 queries at PER-ROW positions against a
-    dense [B, T, Hkv, D] cache — the draft proposer's slot-free variant
-    of text.generation's decode layers (which take one scalar position
-    for the whole batch). `wpos` is the per-row WRITE index — inactive
-    rows park their writes on the trash position T-1 so the batch shape
-    never depends on which slots are speculating — and `mpos` bounds the
-    length mask (`arange <= mpos`), which for live rows never reaches
-    the trash position."""
-    b, h = x.shape
-    gpt = spec.arch == "gpt"
-    if gpt:
-        hn = _layer_norm(x, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-        qkv = (hn @ lw["qkv"]).reshape(b, 3, spec.num_heads, spec.head_dim)
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-    else:
-        hn = _rms_norm(x, lw["input_ln"], spec.rms_eps)
-        q = _mm(hn, lw["q"]).reshape(b, spec.num_heads, spec.head_dim)
-        k = _mm(hn, lw["k"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-        v = _mm(hn, lw["v"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-        q = _rope(q, cos[:, None], sin[:, None])
-        k = _rope(k, cos[:, None], sin[:, None])
-    rows = jnp.arange(b)
-    kc = kc.at[rows, wpos].set(k.astype(kc.dtype))
-    vc = vc.at[rows, wpos].set(v.astype(vc.dtype))
-    rep = spec.num_heads // spec.num_kv_heads
-    kr = _repeat_kv(kc, rep, 2)                       # [B, T, Hq, D]
-    vr = _repeat_kv(vc, rep, 2)
-    scores = jnp.einsum("bhd,bthd->bht", q, kr) / math.sqrt(spec.head_dim)
-    valid = jnp.arange(kc.shape[1])[None, :] <= mpos[:, None]
-    scores = jnp.where(valid[:, None, :], scores,
-                       jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32),
-                           axis=-1).astype(q.dtype)
-    out = jnp.einsum("bht,bthd->bhd", probs, vr)
-    attn = out.reshape(b, spec.num_heads * spec.head_dim)
-    if gpt:
-        x = x + attn @ lw["o"]
-        hn2 = _layer_norm(x, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-        return x + jax.nn.gelu(hn2 @ lw["fc_in"],
-                               approximate=False) @ lw["fc_out"], kc, vc
-    x = x + _mm(attn, lw["o"])
-    hn2 = _rms_norm(x, lw["post_ln"], spec.rms_eps)
-    mlp = _mm(jax.nn.silu(_mm(hn2, lw["gate"])) * _mm(hn2, lw["up"]),
-              lw["down"])
-    return x + mlp, kc, vc
-
-
 def _draft_prefill_impl(dspec, params, ids, slot, kc, vc):
     """Prefill one request's prompt into the DRAFT cache's `slot` row.
     ids [1, S_bucket] right-padded; pad positions write garbage K/V past
     the true length that the ingest scan overwrites before any mask
     exposes them (same invariant as the target engine's prefill)."""
-    gpt = dspec.arch == "gpt"
-    s = ids.shape[1]
-    if gpt:
-        x = params["embed"][ids] + params["wpe"][None, :s]
-
-        def pre(xc, lw):
-            return _gpt_layer_prefill(xc, lw, dspec)
-    else:
-        cos, sin = params["rope_cos"], params["rope_sin"]
-        x = params["embed"][ids]
-
-        def pre(xc, lw):
-            return _layer_forward_prefill(xc, lw, dspec, cos, sin)
-
-    _, (ks, vs) = jax.lax.scan(pre, x, params["layers"])
+    _, ks, vs = db.forward_sequence(params, ids, dspec)
     ks, vs = ks[:, 0], vs[:, 0]                   # [L, S, Hkv, D]
     z = jnp.int32(0)
     kc = jax.lax.dynamic_update_slice(
@@ -326,13 +265,11 @@ def _draft_propose_impl(dspec, steps, params, pend, plen, pos, kc, vc):
     regardless of which slots speculate, so the zero-post-warmup-compile
     audit holds. Returns (greedy [B, steps], kc, vc); the proposal for
     row b is greedy[b, plen-1 : plen-1+k]."""
-    gpt = dspec.arch == "gpt"
     b, w = pend.shape
     t_trash = kc.shape[2] - 1
     active = plen > 0
-    dtype = params["embed"].dtype
-    if not gpt:
-        cos_t, sin_t = params["rope_cos"], params["rope_sin"]
+    rows = jnp.arange(b)
+    last_pos = db.num_positions(params, dspec) - 1
 
     def time_step(carry, t):
         last, kcc, vcc = carry
@@ -340,22 +277,25 @@ def _draft_propose_impl(dspec, steps, params, pend, plen, pos, kc, vc):
             pend, jnp.minimum(t, w - 1), axis=1, keepdims=False)
         tok = jnp.where(t < plen, pend_t, last)
         p = pos + t
+        # the per-row WRITE index: inactive rows park their writes on the
+        # trash position T-1, so the batch shape never depends on which
+        # slots are speculating; `mp` bounds the length mask, which for
+        # live rows never reaches the trash position
         wp = jnp.where(active, jnp.minimum(p, t_trash), t_trash)
         mp = jnp.minimum(p, t_trash)
-        x = params["embed"][tok].astype(dtype)
-        if gpt:
-            x = x + params["wpe"][jnp.clip(p, 0,
-                                           params["wpe"].shape[0] - 1)]
-            cos = sin = None
-        else:
-            ps = jnp.clip(p, 0, cos_t.shape[0] - 1)
-            cos, sin = cos_t[ps], sin_t[ps]       # [B, D]
+        x, rope = db.embed(params, tok, jnp.clip(p, 0, last_pos), dspec)
 
         def layer(xc, per_layer):
-            lw, kcl, vcl = per_layer
-            xo, kcl, vcl = _dense_decode_layer(xc, lw, kcl, vcl, wp, mp,
-                                               dspec, cos, sin)
-            return xo, (kcl, vcl)
+            lw, *kv = per_layer
+
+            def attend(q, k, v):
+                # the dense cache [B, T, Hkv, D] at PER-ROW positions
+                kv[0] = kv[0].at[rows, wp].set(k.astype(kv[0].dtype))
+                kv[1] = kv[1].at[rows, wp].set(v.astype(kv[1].dtype))
+                valid = jnp.arange(t_trash + 1)[None, :] <= mp[:, None]
+                return db.attend_one(q, *kv, valid)
+
+            return db.block(xc, lw, dspec, attend, rope), tuple(kv)
 
         x, (kcc, vcc) = jax.lax.scan(layer, x, (params["layers"], kcc,
                                                 vcc))
@@ -519,22 +459,8 @@ def _static_spec_prefill_impl(dspec, t_total, params, ids, true_len):
     """Prefill for the static speculative loop: full-prompt forward,
     K/V placed into a [L, B, t_total, Hkv, D] cache, and the first
     token taken greedily from the last REAL prompt position."""
-    gpt = dspec.arch == "gpt"
-    b, s = ids.shape
-    if gpt:
-        x = params["embed"][ids] + params["wpe"][None, :s]
-
-        def pre(xc, lw):
-            return _gpt_layer_prefill(xc, lw, dspec)
-    else:
-        cos, sin = params["rope_cos"], params["rope_sin"]
-        x = params["embed"][ids]
-
-        def pre(xc, lw):
-            return _layer_forward_prefill(xc, lw, dspec, cos, sin)
-
-    x, (ks, vs) = jax.lax.scan(pre, x, params["layers"])
-    pad = t_total - s
+    x, ks, vs = db.forward_sequence(params, ids, dspec)
+    pad = t_total - ids.shape[1]
     kc = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     vc = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
@@ -553,69 +479,26 @@ def _dense_verify_impl(dspec, params, toks, pos, kc, vc):
     not advancing pos past what it accepted — the next window's writes
     re-derive the same positions before any mask exposes them. Returns
     (greedy argmax [B, C] int32, kc, vc)."""
-    gpt = dspec.arch == "gpt"
     b, c = toks.shape
     t = kc.shape[2]
-    dtype = params["embed"].dtype
     qpos = pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
     wp = jnp.clip(qpos, 0, t - 1)
-    x = params["embed"][toks].astype(dtype)               # [B, C, H]
-    if gpt:
-        x = x + params["wpe"][jnp.clip(qpos, 0,
-                                       params["wpe"].shape[0] - 1)]
-        cos = sin = None
-    else:
-        ps = jnp.clip(qpos, 0, params["rope_cos"].shape[0] - 1)
-        cos = params["rope_cos"][ps][:, :, None]          # [B, C, 1, D]
-        sin = params["rope_sin"][ps][:, :, None]
-    rep = dspec.num_heads // dspec.num_kv_heads
-    inv_scale = 1.0 / math.sqrt(dspec.head_dim)
+    x, rope = db.embed(                                   # [B, C, H]
+        params, toks,
+        jnp.clip(qpos, 0, db.num_positions(params, dspec) - 1), dspec)
     q_mask = jnp.arange(t)[None, None, :] <= qpos[:, :, None]  # [B,C,T]
     rows = jnp.arange(b)[:, None]
-    nh, nkv, hd = dspec.num_heads, dspec.num_kv_heads, dspec.head_dim
 
     def layer(xc, per_layer):
-        lw, kcl, vcl = per_layer
-        if gpt:
-            hn = _layer_norm(xc, lw["ln1_w"], lw["ln1_b"], dspec.rms_eps)
-            qkv = (hn.reshape(b * c, -1) @ lw["qkv"]).reshape(
-                b, c, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:
-            hn = _rms_norm(xc, lw["input_ln"],
-                           dspec.rms_eps).reshape(b * c, -1)
-            q = _mm(hn, lw["q"]).reshape(b, c, nh, hd)
-            k = _mm(hn, lw["k"]).reshape(b, c, nkv, hd)
-            v = _mm(hn, lw["v"]).reshape(b, c, nkv, hd)
-            q = _rope(q, cos, sin)
-            k = _rope(k, cos, sin)
-        kcl = kcl.at[rows, wp].set(k.astype(kcl.dtype))
-        vcl = vcl.at[rows, wp].set(v.astype(vcl.dtype))
-        kr = _repeat_kv(kcl, rep, 2)
-        vr = _repeat_kv(vcl, rep, 2)
-        scores = jnp.einsum("bchd,bthd->bhct", q, kr) * inv_scale
-        scores = jnp.where(q_mask[:, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhct,bthd->bchd", probs, vr)
-        attn = out.reshape(b, c, nh * hd)
-        if gpt:
-            xo = xc + (attn.reshape(b * c, -1) @ lw["o"]).reshape(
-                b, c, -1)
-            hn2 = _layer_norm(xo, lw["ln2_w"], lw["ln2_b"], dspec.rms_eps)
-            xo = xo + (jax.nn.gelu(hn2.reshape(b * c, -1) @ lw["fc_in"],
-                                   approximate=False)
-                       @ lw["fc_out"]).reshape(b, c, -1)
-        else:
-            xo = xc + _mm(attn.reshape(b * c, -1),
-                          lw["o"]).reshape(b, c, -1)
-            hn2 = _rms_norm(xo, lw["post_ln"],
-                            dspec.rms_eps).reshape(b * c, -1)
-            xo = xo + _mm(jax.nn.silu(_mm(hn2, lw["gate"]))
-                          * _mm(hn2, lw["up"]),
-                          lw["down"]).reshape(b, c, -1)
-        return xo, (kcl, vcl)
+        lw, *kv = per_layer
+
+        def attend(q, k, v):
+            # the dense cache [B, T, Hkv, D] at [B, C] candidate positions
+            kv[0] = kv[0].at[rows, wp].set(k.astype(kv[0].dtype))
+            kv[1] = kv[1].at[rows, wp].set(v.astype(kv[1].dtype))
+            return db.attend_many(q, *kv, q_mask)
+
+        return db.block(xc, lw, dspec, attend, rope), tuple(kv)
 
     x, (kc, vc) = jax.lax.scan(layer, x, (params["layers"], kc, vc))
     lg = _logits(x.reshape(b * c, -1), params, dspec)
@@ -658,8 +541,7 @@ def generate_static_spec(model, ids, max_new_tokens, eos_token_id=None,
     b, s = ids.shape
     mnt = int(max_new_tokens)
     eos = -1 if eos_token_id is None else int(eos_token_id)
-    max_pos = int(params["wpe"].shape[0] if dspec.arch == "gpt"
-                  else params["rope_cos"].shape[0])
+    max_pos = int(db.num_positions(params, dspec))
     if s + mnt > max_pos:
         raise ValueError(
             f"prompt ({s}) + max_new_tokens ({mnt}) exceeds "

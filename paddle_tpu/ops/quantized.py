@@ -20,7 +20,7 @@ packs int4 along its block_size (token) axis.
 Three consumers share ONE quantization rule and ONE dequant-matmul:
   - `weight_quantize(algo="weight_only_int4")` / `weight_only_linear`
     (incubate/nn/functional) — the public op surface;
-  - the static generation engine's `_mm` (text/generation.py) — stacked
+  - the dense block's `_mm` (text/models/dense_block.py) — stacked
     per-layer weights ride lax.scan as (packed, scale) pytree leaves;
   - the paged ServingEngine's per-slot decode matmuls + lm_head
     (inference/engine.py).
@@ -229,8 +229,8 @@ def use_quant_matmul(m, k, n, dtype, grouped=False) -> bool:
 
 def quant_matmul(x, w, scale):
     """Routed dequant-matmul over a quantized weight pair — the single
-    shared routine behind generation's `_mm`, `weight_only_linear` and the
-    serving engine's per-slot matmuls.
+    shared routine behind the dense block's `_mm`, `weight_only_linear`
+    and the serving engine's per-slot matmuls.
 
     x [..., K]; (w, scale) is either int8 (w [K, N], the historical pair)
     or packed int4 (w [ceil(K/2), N]) — disambiguated by shape. scale [N]

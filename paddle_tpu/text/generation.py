@@ -17,59 +17,25 @@ TPU-native design (NOT a kernel translation):
     region is tracked by a scalar position (the masked_multihead_attention
     role: seq-1 query attending to the cache under a length mask).
   - Prefill rides the Pallas flash kernel (ops/pallas_attention.py) on TPU.
+  - The layer's mathematics is `models/dense_block.block`, which the paged
+    engine's and the speculative programs call too; this module adds the
+    two `attend`s it knows: the sequence in hand
+    (`dense_block.forward_sequence`, no cache) and the dense cache at one
+    scalar position (the decode scan's). Weight extraction and stacking
+    (`_stacked_params*`, `_STACK_CACHE`) live here for both engines.
   - Prompt lengths bucket via jit.default_buckets so a serving stream
     compiles O(log S) programs, keyed by (bucket, B, sampling config).
 """
 from __future__ import annotations
 
 import functools
-import math
-from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-@dataclass(frozen=True)
-class _GenSpec:
-    """Static configuration that keys the compiled generate program."""
-    num_layers: int
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    rope_theta: float
-    rms_eps: float
-    max_new_tokens: int
-    do_sample: bool
-    top_k: int
-    top_p: float
-    temperature: float
-    eos_token_id: int
-    tie_embeddings: bool
-    arch: str = "llama"  # "llama" (RMSNorm+RoPE+SwiGLU) | "gpt" (LN+wpe+GELU)
-    # "none" | "int8" | "int4": weight-only per-output-channel quantization
-    # on the layer matmuls + lm_head (≙ weight_only_linear's serving role) —
-    # decode is HBM-bandwidth-bound, so shrinking weight bytes is the win;
-    # activations stay bf16. int8 stores [K, N] int8 (XLA fuses the
-    # int8->bf16 convert into the matmul tiles); int4 stores TRUE packed
-    # [ceil(K/2), N] nibbles (ops/quantized.py) so the packed bytes are the
-    # only HBM weight traffic — the Pallas fused dequant-matmul unpacks in
-    # VMEM on TPU, the XLA take-bits composition everywhere else
-    weight_quant: str = "none"
-
-
-def _rms_norm(x, w, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(x.dtype) \
-        * w
-
-
-def _rope(x, cos, sin):
-    # x [..., D]; cos/sin broadcastable [..., D]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return x * cos + rotated * sin
+from .models import dense_block as db
+from .models.dense_block import _GenSpec, _logits
 
 
 def _rope_tables_np(max_len, head_dim, theta, dtype):
@@ -79,23 +45,6 @@ def _rope_tables_np(max_len, head_dim, theta, dtype):
     freqs = np.outer(t, inv)
     emb = np.concatenate([freqs, freqs], axis=-1)  # [T, D]
     return (np.cos(emb).astype(dtype), np.sin(emb).astype(dtype))
-
-
-def _repeat_kv(x, rep, axis):
-    return x if rep == 1 else jnp.repeat(x, rep, axis=axis)
-
-
-def _mm(x, w):
-    """x @ w where w is either a dense array or a weight-only pair
-    (int8 [K,N] or packed int4 [ceil(K/2),N], scale f32 [N]) — the pair
-    shape disambiguates, see ops/quantized.quant_matmul (the single shared
-    dequant-matmul behind generation, weight_only_linear and the paged
-    engine)."""
-    if isinstance(w, tuple):
-        from ..ops.quantized import quant_matmul
-
-        return quant_matmul(x, w[0], w[1])
-    return x @ w
 
 
 def _quantize_w(w):
@@ -138,152 +87,6 @@ def _sample_token(logits, key, spec: _GenSpec):
     return jax.random.categorical(key, lg, axis=-1).astype(jnp.int32)
 
 
-def _layer_forward_prefill(x, lw, spec: _GenSpec, cos, sin):
-    """One decoder block over the full prompt. x [B, S, H]."""
-    from ..ops.pallas_attention import flash_attention_raw
-
-    b, s, h = x.shape
-    hn = _rms_norm(x, lw["input_ln"], spec.rms_eps)
-    flat = hn.reshape(b * s, h)
-    q = _mm(flat, lw["q"]).reshape(b, s, spec.num_heads, spec.head_dim)
-    k = _mm(flat, lw["k"]).reshape(b, s, spec.num_kv_heads, spec.head_dim)
-    v = _mm(flat, lw["v"]).reshape(b, s, spec.num_kv_heads, spec.head_dim)
-    c = cos[None, :s, None, :]
-    sn = sin[None, :s, None, :]
-    q = _rope(q, c, sn)
-    k = _rope(k, c, sn)
-    rep = spec.num_heads // spec.num_kv_heads
-    if jax.default_backend() == "tpu" and s >= 128:
-        out = flash_attention_raw(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(_repeat_kv(k, rep, 2), 1, 2),
-            jnp.swapaxes(_repeat_kv(v, rep, 2), 1, 2), causal=True)
-        out = jnp.swapaxes(out, 1, 2)
-    else:
-        kr = _repeat_kv(k, rep, 2)
-        vr = _repeat_kv(v, rep, 2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr) \
-            / math.sqrt(spec.head_dim)
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask[None, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-    attn = _mm(out.reshape(b * s, spec.num_heads * spec.head_dim), lw["o"])
-    x = x + attn.reshape(b, s, h)
-    hn = _rms_norm(x, lw["post_ln"], spec.rms_eps).reshape(b * s, h)
-    mlp = _mm(jax.nn.silu(_mm(hn, lw["gate"])) * _mm(hn, lw["up"]),
-              lw["down"])
-    return x + mlp.reshape(b, s, h), (k, v)
-
-
-def _layer_forward_decode(x, lw, kc, vc, pos, spec: _GenSpec, cos, sin):
-    """One decoder block for a seq-1 query against the cache.
-    x [B, H]; kc/vc [B, T, H_kv, D]; pos scalar (current write index)."""
-    b, h = x.shape
-    hn = _rms_norm(x, lw["input_ln"], spec.rms_eps)
-    q = _mm(hn, lw["q"]).reshape(b, spec.num_heads, spec.head_dim)
-    k = _mm(hn, lw["k"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-    v = _mm(hn, lw["v"]).reshape(b, spec.num_kv_heads, spec.head_dim)
-    c = jax.lax.dynamic_slice(cos, (pos, jnp.int32(0)), (1, spec.head_dim))
-    sn = jax.lax.dynamic_slice(sin, (pos, jnp.int32(0)), (1, spec.head_dim))
-    q = _rope(q, c[None], sn[None])
-    k = _rope(k, c[None], sn[None])
-    z = jnp.int32(0)
-    kc = jax.lax.dynamic_update_slice(kc, k[:, None], (z, pos, z, z))
-    vc = jax.lax.dynamic_update_slice(vc, v[:, None], (z, pos, z, z))
-    rep = spec.num_heads // spec.num_kv_heads
-    kr = _repeat_kv(kc, rep, 2)                       # [B, T, Hq, D]
-    vr = _repeat_kv(vc, rep, 2)
-    scores = jnp.einsum("bhd,bthd->bht", q, kr) / math.sqrt(spec.head_dim)
-    valid = jnp.arange(kc.shape[1]) <= pos            # length mask
-    scores = jnp.where(valid[None, None, :], scores,
-                       jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bht,bthd->bhd", probs, vr)
-    attn = _mm(out.reshape(b, spec.num_heads * spec.head_dim), lw["o"])
-    x = x + attn
-    hn = _rms_norm(x, lw["post_ln"], spec.rms_eps)
-    mlp = _mm(jax.nn.silu(_mm(hn, lw["gate"])) * _mm(hn, lw["up"]),
-              lw["down"])
-    return x + mlp, kc, vc
-
-
-def _layer_norm(x, w, b, eps):
-    xf = x.astype(jnp.float32)
-    m = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - m), axis=-1, keepdims=True)
-    return ((xf - m) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w + b
-
-
-def _gpt_layer_prefill(x, lw, spec: _GenSpec):
-    """Pre-LN GPT block over the full prompt. x [B, S, H]."""
-    from ..ops.pallas_attention import flash_attention_raw
-
-    b, s, h = x.shape
-    hn = _layer_norm(x, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-    qkv = _mm(hn.reshape(b * s, h), lw["qkv"]).reshape(
-        b, s, 3, spec.num_heads, spec.head_dim)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    if jax.default_backend() == "tpu" and s >= 128:
-        out = flash_attention_raw(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), causal=True)
-        out = jnp.swapaxes(out, 1, 2)
-    else:
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) \
-            / math.sqrt(spec.head_dim)
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask[None, None], scores,
-                           jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32),
-                               axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    x = x + _mm(out.reshape(b * s, h), lw["o"]).reshape(b, s, h)
-    hn = _layer_norm(x, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-    mlp = _mm(jax.nn.gelu(_mm(hn.reshape(b * s, h), lw["fc_in"]),
-                          approximate=False), lw["fc_out"])
-    return x + mlp.reshape(b, s, h), (k, v)
-
-
-def _gpt_layer_decode(x, lw, kc, vc, pos, spec: _GenSpec):
-    """Pre-LN GPT block for a seq-1 query. x [B, H]."""
-    b, h = x.shape
-    hn = _layer_norm(x, lw["ln1_w"], lw["ln1_b"], spec.rms_eps)
-    qkv = _mm(hn, lw["qkv"]).reshape(b, 3, spec.num_heads, spec.head_dim)
-    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-    z = jnp.int32(0)
-    kc = jax.lax.dynamic_update_slice(kc, k[:, None], (z, pos, z, z))
-    vc = jax.lax.dynamic_update_slice(vc, v[:, None], (z, pos, z, z))
-    scores = jnp.einsum("bhd,bthd->bht", q, kc) / math.sqrt(spec.head_dim)
-    valid = jnp.arange(kc.shape[1]) <= pos
-    scores = jnp.where(valid[None, None, :], scores,
-                       jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bht,bthd->bhd", probs, vc)
-    x = x + _mm(out.reshape(b, h), lw["o"])
-    hn = _layer_norm(x, lw["ln2_w"], lw["ln2_b"], spec.rms_eps)
-    return x + _mm(jax.nn.gelu(_mm(hn, lw["fc_in"]),
-                               approximate=False), lw["fc_out"]), kc, vc
-
-
-def _logits(x, params, spec: _GenSpec):
-    """x [B, H] -> [B, V]."""
-    if spec.arch == "gpt":
-        x = _layer_norm(x, params["final_ln"], params["final_ln_b"],
-                        spec.rms_eps)
-    else:
-        x = _rms_norm(x, params["final_ln"], spec.rms_eps)
-    if spec.tie_embeddings:
-        return x.astype(jnp.float32) @ params["embed"].T.astype(jnp.float32)
-    head = params["lm_head"]
-    if isinstance(head, tuple):
-        # f32 activations keep the historical logits numerics: for int8
-        # this is exactly (x_f32 @ w8_f32) * ws_f32; int4 unpacks first
-        return _mm(x.astype(jnp.float32), head)
-    return x.astype(jnp.float32) @ head.astype(jnp.float32)
-
-
 #: host-side mirror of the generation program keys — a NEW key here
 #: records a compile event for the obs watchdog. Kept separate from the
 #: executable cache below so tests can clear the event mirror without
@@ -309,23 +112,9 @@ def _generate_program(params, ids, spec: _GenSpec, rng_key, true_len):
     `arange <= pos` mask never reaches an unwritten slot, so the garbage is
     progressively overwritten and never attended to.
     Returns tokens [B, max_new_tokens] int32."""
-    b, s = ids.shape
+    s = ids.shape[1]
     total = s + spec.max_new_tokens
-    dtype = params["embed"].dtype
-    gpt = spec.arch == "gpt"
-    if gpt:
-        x = params["embed"][ids] + params["wpe"][None, :s]
-
-        def pre(xc, lw):
-            return _gpt_layer_prefill(xc, lw, spec)
-    else:
-        cos, sin = params["rope_cos"], params["rope_sin"]
-        x = params["embed"][ids]                      # [B, S, H]
-
-        def pre(xc, lw):
-            return _layer_forward_prefill(xc, lw, spec, cos, sin)
-
-    x, (ks, vs) = jax.lax.scan(pre, x, params["layers"])
+    x, ks, vs = db.forward_sequence(params, ids, spec)
     # static-shaped cache for the whole generation
     pad = total - s
     kcache = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
@@ -340,19 +129,22 @@ def _generate_program(params, ids, spec: _GenSpec, rng_key, true_len):
 
     def step(carry, _):
         tok, kc, vc, pos, key, finished = carry
-        xt = params["embed"][tok].astype(dtype)       # [B, H]
-        if gpt:
-            xt = xt + params["wpe"][pos]
+        xt, rope = db.embed(params, tok, pos, spec)       # [B, H]
 
         def layer(xc, per_layer):
-            lw, kcl, vcl = per_layer
-            if gpt:
-                xo, kcl, vcl = _gpt_layer_decode(xc, lw, kcl, vcl, pos,
-                                                 spec)
-            else:
-                xo, kcl, vcl = _layer_forward_decode(xc, lw, kcl, vcl, pos,
-                                                     spec, cos, sin)
-            return xo, (kcl, vcl)
+            lw, *kv = per_layer
+
+            def attend(q, k, v):
+                # the dense cache [B, T, H_kv, D] at ONE scalar position:
+                # write there, attend under the length mask
+                z = jnp.int32(0)
+                kv[0] = jax.lax.dynamic_update_slice(kv[0], k[:, None],
+                                                     (z, pos, z, z))
+                kv[1] = jax.lax.dynamic_update_slice(kv[1], v[:, None],
+                                                     (z, pos, z, z))
+                return db.attend_one(q, *kv, jnp.arange(total) <= pos)
+
+            return db.block(xc, lw, spec, attend, rope), tuple(kv)
 
         xt, (kc, vc) = jax.lax.scan(layer, xt, (params["layers"], kc, vc))
         lg = _logits(xt, params, spec)

@@ -78,25 +78,8 @@ QUICK_MODULES = {
 }
 
 
-#: PR 31: the one accepted benchmark test that this PR's manifest entries
-#: make false and that only a `benchmark` PR may edit. It unpacks
-#: BENCHMARK.json's workloads as exactly PR 28's three cells and holds the
-#: serve metrics' lists to one cell; the issue adds
-#: command-a-plus-05-2026.serve-longdoc to both. What it asserts and stays
-#: true is asserted in tests/benchmark/test_bench_cohere2_moe.py
-#: (`test_the_accepted_cells_keep_what_pr28_said_of_their_span_metrics`).
-#: The next `benchmark` PR repairs the test and deletes this marker
-#: (PERF.md section 7, first item).
-PR31_OUTDATED = ("test_bench_program_spans.py",
-                 "test_manifest_entries_are_the_issues")
-
-
 def pytest_collection_modifyitems(config, items):
     for item in items:
         mod = os.path.basename(str(item.fspath))
         if mod in QUICK_MODULES:
             item.add_marker(pytest.mark.quick)
-        if (mod, item.name) == PR31_OUTDATED:
-            item.add_marker(pytest.mark.xfail(
-                reason="PR 31 added a fourth cell, as its issue asks",
-                strict=True))

@@ -485,23 +485,12 @@ def test_pools_ride_the_layer_scan_as_carry(program):
 def _dense_kv(eng, tokens):
     """Every layer's K and V of `tokens` by the static engine's dense
     causal forward: [L, S, H_kv, D] each."""
-    import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.text import generation as g
+    from paddle_tpu.text.models import dense_block
 
-    params, spec = eng.params, eng.spec
     ids = jnp.asarray(np.asarray(tokens, np.int32))[None]
-    if spec.arch == "gpt":
-        x = params["embed"][ids] + params["wpe"][None, :ids.shape[1]]
-        _, (ks, vs) = jax.lax.scan(
-            lambda xc, lw: g._gpt_layer_prefill(xc, lw, spec), x,
-            params["layers"])
-    else:
-        _, (ks, vs) = jax.lax.scan(
-            lambda xc, lw: g._layer_forward_prefill(
-                xc, lw, spec, params["rope_cos"], params["rope_sin"]),
-            params["embed"][ids], params["layers"])
+    _, ks, vs = dense_block.forward_sequence(eng.params, ids, eng.spec)
     return np.asarray(ks[:, 0], np.float32), np.asarray(vs[:, 0],
                                                         np.float32)
 
