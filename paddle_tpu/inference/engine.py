@@ -32,9 +32,16 @@ TPU-native design:
     are gathered into bucket-sized arrays (cheap — the KV pool itself is
     shared and addressed through the tables, it never moves). Padded rows
     point at the reserved trash block and their outputs are dropped.
+  - What the host decides a call (tokens, positions, table rows, a
+    prompt's ids, the scalars) reaches a program as ONE int32 operand,
+    filled in place and unpacked inside the program by static slices: a
+    transfer costs the host a quarter of a millisecond whatever its size
+    (PERF.md, PR 36), so a call makes one, not one a value. A `.build`
+    span's `h2d` says how many its call made.
   - Per-request sampling params thread as BATCHED arrays (temperature /
     top-k / top-p / greedy mask per slot), so mixed sampling configs share
-    one program.
+    one program. An all-greedy call's program reads none of them and is
+    handed one cached device copy a bucket size (`ServingEngine._samp`).
   - Cache buffers are DONATED to the step programs on TPU: the pool is
     updated in place, never copied (a [L, N, Hkv, bs, D] pool is the
     dominant HBM tenant at serving time).
@@ -209,17 +216,20 @@ def _scatter_rows(k, v, kc, vc, ksc, vsc, start, end, row, block_size,
 # ------------------------------------------------------- step programs
 
 def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
-                      any_sample: bool, params, tok, pos, tables, kc, vc,
-                      ksc, vsc, samp, key):
+                      any_sample: bool, params, ints, kc, vc, ksc, vsc,
+                      samp, key):
     """ONE decode step for a compacted slot bucket: every row consumes
     its token, appends K/V through its block table, attends over its own
-    length, and samples its next token with its own params. Cache pools
-    ride the layer scan as its carry (`_scan_layers`), a layer's blocks
-    addressed by offset. `any_sample` is STATIC (part of the program
-    key): an all-greedy bucket — the common serving case — compiles to a
-    bare argmax instead of the sort/softmax/cumsum sampling machinery
-    over [B, V] every tick.
+    length, and samples its next token with its own params. `ints` [B, 2
+    + pages], a row a slot (`_StackedPrograms.decode` packs it): its
+    token, its position, its block table row. Cache pools ride the layer
+    scan as its carry (`_scan_layers`), a layer's blocks addressed by
+    offset. `any_sample` is STATIC (part of the program key): an
+    all-greedy bucket — the common serving case — compiles to a bare
+    argmax instead of the sort/softmax/cumsum sampling machinery over
+    [B, V] every tick, and reads nothing of `samp`.
     """
+    tok, pos, tables = ints[:, 0], ints[:, 1], ints[:, 2:]
     xt, rope = db.embed(params, tok, pos, spec)          # [B, H]
 
     def layer(xc, lw, tabs, *pools):
@@ -246,11 +256,15 @@ def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
 
 
 def _prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
-                  any_sample: bool, params, ids, true_len, table_row, kc,
-                  vc, ksc, vsc, samp, key):
+                  any_sample: bool, pages: int, params, ints, kc, vc, ksc,
+                  vsc, samp, key):
     """Prefill one joining request: full-prompt forward (Pallas flash on
     TPU), page-scatter the prompt K/V through the slot's block table, and
-    sample the first token from the last REAL prompt position."""
+    sample the first token from the last REAL prompt position. `ints` [1
+    + pages + S] (`ServingEngine._prefill` packs it): true_len, the
+    slot's block table row, the prompt's ids padded to the bucket S."""
+    true_len, table_row = ints[0], ints[1:1 + pages]
+    ids = ints[None, 1 + pages:]                         # [1, S]
     x, ks, vs = db.forward_sequence(params, ids, spec)
     ks, vs = ks[:, 0], vs[:, 0]                          # [L, S, Hkv, D]
     if kv_mode != "model":
@@ -275,10 +289,13 @@ def _prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
 
 def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
                         any_sample: bool, emit_token: bool, ctx_pages: int,
-                        params, ids, start, true_end, last_idx, table_row,
-                        cow_src, cow_dst, kc, vc, ksc, vsc, samp, key):
-    """Prefill ONE chunk of one prompt: compute Q/K/V for positions
-    [start, true_end), scatter the chunk's K/V through the block table
+                        pages: int, params, ints, kc, vc, ksc, vsc, samp,
+                        key):
+    """Prefill ONE chunk of one prompt. `ints` [5 + pages + C]
+    (`_StackedPrograms.chunk` packs it): start, true_end, last_idx,
+    cow_src, cow_dst, the slot's block table row, the chunk's ids padded
+    to the bucket C. Compute Q/K/V for positions [start, true_end),
+    scatter the chunk's K/V through the block table
     (token-granular — a prefix-cache suffix may start mid-block), and
     attend each chunk position over the WHOLE context so far (cached
     prefix pages + earlier chunks + this chunk) gathered from the paged
@@ -291,7 +308,10 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     = no-op). Context length is static via `ctx_pages` (bucketed): pages
     past the written watermark gather garbage the causal mask never
     reaches."""
-    c = ids.shape[1]
+    start, true_end, last_idx, cow_src, cow_dst = (ints[i] for i in range(5))
+    table_row = ints[5:5 + pages]
+    ids = ints[5 + pages:]                               # [C]
+    c = ids.shape[0]
     kc = copy_block(kc, cow_src, cow_dst)
     vc = copy_block(vc, cow_src, cow_dst)
     if kv_mode != "model":
@@ -299,7 +319,7 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
         vsc = copy_block(vsc, cow_src, cow_dst)
     pos = start + jnp.arange(c)
     x, rope = db.embed(                                  # [C, H]
-        params, ids[0],
+        params, ids,
         jnp.clip(pos, 0, db.num_positions(params, spec) - 1), spec)
     kv_pos = jnp.arange(ctx_pages * block_size)
     q_mask = kv_pos[None, :] <= pos[:, None]             # [C, T]
@@ -377,11 +397,13 @@ def _verify_tokens(lg, proposed, samp, key, any_sample):
 
 
 def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
-                      any_sample: bool, params, toks, pos, tables, limit,
-                      kc, vc, ksc, vsc, samp, key):
+                      any_sample: bool, pages: int, params, ints, kc, vc,
+                      ksc, vsc, samp, key):
     """Score C = K+1 candidate positions per slot in ONE paged-attention
     pass — the verify half of speculative decoding, costing the same
-    weight sweep as a single decode tick. toks[:, 0] is each slot's last
+    weight sweep as a single decode tick. `ints` [B, 2 + pages + C], a
+    row a slot (`ServingEngine._spec_decode` packs it): pos, limit, the
+    block table row, toks. toks[:, 0] is each slot's last
     emitted (not yet consumed) token, toks[:, 1:] its K proposals; row b
     writes candidate K/V at positions pos[b] + [0, C) through its block
     table (positions >= limit[b], the slot's allocated-token watermark,
@@ -397,12 +419,13 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     same positions are idempotent re-derivations. The accept/emit split
     lives in _verify_tokens; this returns (acc [B, K], tgt [B, C],
     caches..., key)."""
+    pos, limit = ints[:, 0], ints[:, 1]
+    tables, toks = ints[:, 2:2 + pages], ints[:, 2 + pages:]
     b, c = toks.shape
     qpos = pos[:, None] + jnp.arange(c)[None, :]          # [B, C]
     x, rope = db.embed(                                   # [B, C, H]
         params, toks,
         jnp.clip(qpos, 0, db.num_positions(params, spec) - 1), spec)
-    pages = tables.shape[1]
     end = jnp.minimum(pos + c, limit)
     kv_pos = jnp.arange(pages * block_size)
     q_mask = kv_pos[None, None, :] <= qpos[:, :, None]    # [B, C, T]
@@ -437,18 +460,21 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
     return acc, tgt, kc, vc, ksc, vsc, key
 
 
+# what the host decides a call (tokens, positions, tables, ids) reaches a
+# program as ONE int32 operand, unpacked by static slices: one transfer a
+# call, not one a value. Only the pools are donated.
 _decode_step = functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(8, 9, 10, 11))(_decode_step_impl)
+    donate_argnums=(6, 7, 8, 9))(_decode_step_impl)
 _prefill_step = functools.partial(
-    jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(8, 9, 10, 11))(_prefill_impl)
+    jax.jit, static_argnums=(0, 1, 2, 3, 4),
+    donate_argnums=(7, 8, 9, 10))(_prefill_impl)
 _chunk_prefill_step = functools.partial(
-    jax.jit, static_argnums=(0, 1, 2, 3, 4, 5),
-    donate_argnums=(14, 15, 16, 17))(_chunk_prefill_impl)
+    jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
+    donate_argnums=(9, 10, 11, 12))(_chunk_prefill_impl)
 _spec_verify_step = functools.partial(
-    jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(9, 10, 11, 12))(_spec_verify_impl)
+    jax.jit, static_argnums=(0, 1, 2, 3, 4),
+    donate_argnums=(7, 8, 9, 10))(_spec_verify_impl)
 
 
 # ------------------------------------------------------------ scheduler
@@ -512,17 +538,18 @@ class _StackedPrograms:
             default_buckets(ctx_need),
             blocks_for(c_bucket, e.block_size) + 1))
 
-    def chunk(self, slot, req, ids, start, n, is_last, ctx_pages, cow):
+    def chunk(self, slot, req, start, n, c_bucket, is_last, ctx_pages, cow):
         """(step, number of static operands, operands)."""
         e, c = self.eng, self.eng.cache
         sample = req.do_sample and is_last
-        return _chunk_prefill_step, 6, (
+        ints = np.zeros(5 + e.pages + c_bucket, np.int32)
+        ints[:5] = (start, start + n, req.prompt.size - 1 - start, *cow)
+        ints[5:5 + e.pages] = e._tables[slot]
+        ints[5 + e.pages:5 + e.pages + n] = req.prompt[start:start + n]
+        return _chunk_prefill_step, 7, (
             e.spec, e.block_size, e.kv_mode, sample, is_last, ctx_pages,
-            e.params, jnp.asarray(ids), jnp.int32(start),
-            jnp.int32(start + n), jnp.int32(req.prompt.size - 1 - start),
-            jnp.asarray(e._tables[slot]), jnp.int32(cow[0]),
-            jnp.int32(cow[1]), c.k, c.v, c.k_scale, c.v_scale,
-            e._samp_arrays([req]), e._key)
+            e.pages, e.params, e._put(ints), c.k, c.v, c.k_scale,
+            c.v_scale, e._samp([req], 0, sample), e._key)
 
     def chunk_done(self, out, n, is_last, run):
         """Take the program's result: swap the pools in, fetch the token
@@ -539,13 +566,19 @@ class _StackedPrograms:
         jax.block_until_ready(c.k)
         return None
 
-    def decode(self, active, reqs, bucket, tok, pos, tables, any_sample):
+    def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.eng.cache
+        n = len(active)
+        # padded rows: token 0 at position 0 through the trash block
+        ints = np.zeros((bucket, 2 + e.pages), np.int32)
+        ints[n:, 2:] = TRASH_BLOCK
+        ints[:n, 0] = [r.tokens[-1] for r in reqs]
+        ints[:n, 1] = e._slot_pos[active]
+        ints[:n, 2:] = e._tables[active]
         return _decode_step, 4, (
             e.spec, e.block_size, e.kv_mode, any_sample, e.params,
-            jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(tables), c.k,
-            c.v, c.k_scale, c.v_scale,
-            e._samp_arrays(reqs, bucket - len(active)), e._key)
+            e._put(ints), c.k, c.v, c.k_scale, c.v_scale,
+            e._samp(reqs, bucket - n, any_sample), e._key)
 
     def decode_done(self, out, n_active, run):
         nxt, ck, cv, cks, cvs, self.eng._key = out
@@ -554,12 +587,10 @@ class _StackedPrograms:
 
     def decode_jaxpr(self, bucket, samp):
         e, c = self.eng, self.eng.cache
-        zeros = jnp.zeros(bucket, jnp.int32)
         fn = functools.partial(_decode_step_impl, e.spec, e.block_size,
                                e.kv_mode, False)
         return jax.make_jaxpr(fn)(
-            e.params, zeros, zeros,
-            jnp.full((bucket, e.pages), TRASH_BLOCK, jnp.int32),
+            e.params, jnp.zeros((bucket, 2 + e.pages), jnp.int32),
             c.k, c.v, c.k_scale, c.v_scale, samp, e._key)
 
     def update_gauges(self):
@@ -825,6 +856,13 @@ class ServingEngine:
         self._waiting: deque[Request] = deque()
         self._key = jax.random.PRNGKey(int(seed))
         self._next_id = 0
+        # what a call's build need not do again: host arrays handed to
+        # the device so far (`_put`; a `.build` span's `h2d` is its
+        # share), a greedy call's sampling operands by bucket size
+        # (`_samp`), the decode kernel's grid by slot bucket
+        self._h2d = 0
+        self._greedy_samp: dict[int, dict] = {}
+        self._kv_steps_of: dict[int, int] = {}
         # scheduler bookkeeping the step logic itself reads
         self.steps = 0
         self.active_slot_steps = 0
@@ -1345,9 +1383,12 @@ class ServingEngine:
         real recompile, exactly the old jit-cache semantics."""
         key = (site, self._prog_key_base, bool(any_sample), int(bucket),
                tuple(extra))
-        keystr = (f"bucket{bucket}/sample{int(any_sample)}/"
-                  f"kv{self.kv_mode}/w{self.weight_quant}"
-                  + "".join(f"/{x}" for x in extra))
+
+        def keystr():       # a miss's or a first sighting's, never a hit's
+            return (f"bucket{bucket}/sample{int(any_sample)}/"
+                    f"kv{self.kv_mode}/w{self.weight_quant}"
+                    + "".join(f"/{x}" for x in extra))
+
         cached = _SERVING_EXECUTABLES.get(key)
         compile_wall = None
         if cached is None:
@@ -1358,7 +1399,7 @@ class ServingEngine:
                 compiled = jitted.lower(*args).compile()
             compile_wall = sp.end - sp.start
             entry = _costs.record_program(
-                site, self._prog_group(site), keystr,
+                site, self._prog_group(site), keystr(),
                 compiled=compiled, wall_s=compile_wall, bucket=int(bucket))
             cached = (compiled, entry)
             _SERVING_EXECUTABLES[key] = cached
@@ -1375,7 +1416,7 @@ class ServingEngine:
 
             entry = cached[1]
             record_compile(
-                site, self._prog_group(site), keystr, bucket=int(bucket),
+                site, self._prog_group(site), keystr(), bucket=int(bucket),
                 wall_s=compile_wall or 0.0, donated=True,
                 warm=self._warmed,
                 cost=({"flops": entry.flops,
@@ -1616,18 +1657,21 @@ class ServingEngine:
         bucket = max(bucket, _ceil_to(s, self.block_size))
         at = {"rid": req.rid, "tokens": int(s), "bucket": int(bucket)}
         c = self.cache
-        with _span("serving.prefill.build", **at):
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :s] = req.prompt
-            samp = self._samp_arrays([req])
+        with _span("serving.prefill.build", **at) as build:
+            h2d = self._h2d
+            ints = np.zeros(1 + self.pages + bucket, np.int32)
+            ints[0] = s
+            ints[1:1 + self.pages] = self._tables[slot]
+            ints[1 + self.pages:1 + self.pages + s] = req.prompt
             args = (self.spec, self.block_size, self.kv_mode,
-                    req.do_sample, self.params, jnp.asarray(ids),
-                    jnp.int32(s), jnp.asarray(self._tables[slot]), c.k,
-                    c.v, c.k_scale, c.v_scale, samp, self._key)
+                    req.do_sample, self.pages, self.params,
+                    self._put(ints), c.k, c.v, c.k_scale, c.v_scale,
+                    self._samp([req], 0, req.do_sample), self._key)
             prog, entry = self._program("serving.prefill", _prefill_step,
-                                        4, bucket, req.do_sample, (), args)
+                                        5, bucket, req.do_sample, (), args)
+            build.attrs["h2d"] = self._h2d - h2d
         with _span("serving.prefill.run", **at) as run:
-            out = prog(*args[4:])
+            out = prog(*args[5:])
             tok_arr, ck, cv, cks, cvs, self._key = out
             c.swap(ck, cv, cks, cvs)
             tok = int(jax.device_get(tok_arr)[0])
@@ -1711,16 +1755,16 @@ class ServingEngine:
                                                         TRASH_BLOCK)
         at = {"rid": req.rid, "tokens": int(n), "start": int(start),
               "last": bool(is_last), "bucket": int(c_bucket)}
-        with _span("serving.chunk.build", **at):
-            ids = np.zeros((1, c_bucket), np.int32)
-            ids[0, :n] = req.prompt[start:start + n]
+        with _span("serving.chunk.build", **at) as build:
+            h2d = self._h2d
             step, n_static, args = self.programs.chunk(
-                slot, req, ids, start, n, is_last, ctx_pages,
+                slot, req, start, n, c_bucket, is_last, ctx_pages,
                 (cow_src, cow_dst))
             prog, entry = self._program(
                 "serving.chunk_prefill", step, n_static, c_bucket,
                 req.do_sample and is_last, (ctx_pages, bool(is_last)),
                 args)
+            build.attrs["h2d"] = self._h2d - h2d
         with _span("serving.chunk.run", **at) as run:
             tok = self.programs.chunk_done(prog(*args[n_static:]), n,
                                            is_last, run)
@@ -1765,26 +1809,21 @@ class ServingEngine:
         from ..jit.api import default_buckets
 
         bucket = min(default_buckets(len(active)), self.max_slots)
+        if bucket not in self._kv_steps_of:
+            self._kv_steps_of[bucket] = self._kv_steps(bucket)
         at = {"active": len(active), "bucket": int(bucket),
               "live_pages": int(
                   (self._slot_pos[active] // self.block_size + 1).sum()),
-              "kv_steps": self._kv_steps(bucket)}
+              "kv_steps": self._kv_steps_of[bucket]}
         with _span("serving.decode.build", **at) as build:
+            h2d = self._h2d
             reqs = [self._slot_req[i] for i in active]
-            pad = bucket - len(active)
-            tok = np.array([r.tokens[-1] for r in reqs] + [0] * pad,
-                           np.int32)
-            pos = np.concatenate(
-                [self._slot_pos[active],
-                 np.zeros(pad, np.int64)]).astype(np.int32)
-            tables = np.concatenate(
-                [self._tables[active],
-                 np.full((pad, self.pages), TRASH_BLOCK, np.int32)])
             any_sample = any(r.do_sample for r in reqs)
             step, n_static, args = self.programs.decode(
-                active, reqs, bucket, tok, pos, tables, any_sample)
+                active, reqs, bucket, any_sample)
             prog, entry = self._program("serving.decode", step, n_static,
                                         bucket, any_sample, (), args)
+            build.attrs["h2d"] = self._h2d - h2d
         with _span("serving.decode.run", **at) as run:
             nxt = self.programs.decode_done(prog(*args[n_static:]),
                                             len(active), run)
@@ -1857,9 +1896,14 @@ class ServingEngine:
         width = k + 1
         bucket = min(default_buckets(len(slots)), self.max_slots)
         reqs = [self._slot_req[i] for i in slots]
-        pad = bucket - len(slots)
-        toks = np.zeros((bucket, width), np.int32)
-        limit = np.zeros(bucket, np.int32)
+        n_live = len(slots)
+        # a row a slot: pos, limit, its block table row, its candidates
+        # (padded rows: zeros through the trash block, limit 0)
+        ints = np.zeros((bucket, 2 + self.pages + width), np.int32)
+        ints[n_live:, 2:2 + self.pages] = TRASH_BLOCK
+        ints[:n_live, 0] = self._slot_pos[slots]
+        ints[:n_live, 2:2 + self.pages] = self._tables[slots]
+        toks = ints[:, 2 + self.pages:]
         for j, (slot, req, prop) in enumerate(zip(slots, reqs,
                                                   proposals)):
             toks[j, 0] = req.tokens[-1]
@@ -1867,27 +1911,21 @@ class ServingEngine:
             toks[j, 1:1 + n] = prop[:n]
             if n < k:    # short proposal: pad by repeating (auto-reject)
                 toks[j, 1 + n:] = toks[j, n]
-            limit[j] = len(self._slot_blocks[slot]) * self.block_size
-        pos = np.concatenate([self._slot_pos[slots],
-                              np.zeros(pad, np.int64)]).astype(np.int32)
-        tables = np.concatenate(
-            [self._tables[slots],
-             np.full((pad, self.pages), TRASH_BLOCK, np.int32)])
-        samp = self._samp_arrays(reqs, pad)
+            ints[j, 1] = len(self._slot_blocks[slot]) * self.block_size
         any_sample = any(r.do_sample for r in reqs)
         c = self.cache
         args = (self.spec, self.block_size, self.kv_mode, any_sample,
-                self.params, jnp.asarray(toks), jnp.asarray(pos),
-                jnp.asarray(tables), jnp.asarray(limit), c.k, c.v,
-                c.k_scale, c.v_scale, samp, self._key)
+                self.pages, self.params, self._put(ints), c.k, c.v,
+                c.k_scale, c.v_scale,
+                self._samp(reqs, bucket - n_live, any_sample), self._key)
         prog, entry = self._program("serving.spec_verify",
-                                    _spec_verify_step, 4, bucket,
+                                    _spec_verify_step, 5, bucket,
                                     any_sample, (k,), args)
         # a span only so that the flight recorder's `verify_window` and
         # the cost ledger share its clock reads; no metric reads it
         with _span("serving.verify.run", active=len(slots),
                    k=int(k)) as run:
-            out = prog(*args[4:])
+            out = prog(*args[5:])
             acc, tgt, ck, cv, cks, cvs, self._key = out
             c.swap(ck, cv, cks, cvs)
             acc = np.asarray(jax.device_get(acc))
@@ -1946,21 +1984,41 @@ class ServingEngine:
                               program=entry.program)
         return emitted
 
+    def _put(self, host):
+        """Hand one host array to the device. Every operand a step
+        program is given from the host goes through here, so that a
+        `.build` span can say how many its call made (`h2d`)."""
+        self._h2d += 1
+        return jnp.asarray(host)
+
     def _samp_arrays(self, reqs, pad=0):
         """Per-slot sampling params as batched device arrays (padded rows
         greedy — their tokens are discarded)."""
         return {
-            "do_sample": jnp.asarray(
-                [r.do_sample for r in reqs] + [False] * pad),
-            "temperature": jnp.asarray(
+            "do_sample": self._put(
+                np.array([r.do_sample for r in reqs] + [False] * pad,
+                         bool)),
+            "temperature": self._put(
                 np.array([r.temperature for r in reqs] + [1.0] * pad,
                          np.float32)),
-            "top_k": jnp.asarray(
+            "top_k": self._put(
                 np.array([r.top_k for r in reqs] + [0] * pad, np.int32)),
-            "top_p": jnp.asarray(
+            "top_p": self._put(
                 np.array([r.top_p for r in reqs] + [1.0] * pad,
                          np.float32)),
         }
+
+    def _samp(self, reqs, pad, any_sample):
+        """A call's sampling operands. A greedy program reads none of them
+        (`any_sample` is static), so a greedy call is handed one device
+        copy a bucket size, made once, and transfers nothing. No program
+        donates them."""
+        if any_sample:
+            return self._samp_arrays(reqs, pad)
+        n = len(reqs) + pad
+        if n not in self._greedy_samp:
+            self._greedy_samp[n] = self._samp_arrays([], n)
+        return self._greedy_samp[n]
 
     def _check_done(self, req, tok) -> bool:
         if req.eos_token_id >= 0 and tok == req.eos_token_id:
@@ -2036,12 +2094,11 @@ class ServingEngine:
                 "top_k": jnp.zeros(bucket, jnp.int32),
                 "top_p": jnp.ones(bucket, jnp.float32)}
         fn = functools.partial(_spec_verify_impl, self.spec,
-                               self.block_size, self.kv_mode, False)
+                               self.block_size, self.kv_mode, False,
+                               self.pages)
         return jax.make_jaxpr(fn)(
-            self.params, jnp.zeros((bucket, int(k) + 1), jnp.int32),
-            jnp.zeros(bucket, jnp.int32),
-            jnp.full((bucket, self.pages), TRASH_BLOCK, jnp.int32),
-            jnp.zeros(bucket, jnp.int32),
+            self.params,
+            jnp.zeros((bucket, 2 + self.pages + int(k) + 1), jnp.int32),
             c.k, c.v, c.k_scale, c.v_scale, samp, self._key)
 
 

@@ -195,21 +195,10 @@ class LayeredPrograms:
             [k == pb.SLIDING for k in block.layer_types], full_blocks,
             self.ring.num_blocks, block.num_kv_heads, eng.block_size,
             block.head_dim, dtype)
-        self._greedy = {}
 
     def full_pool(self):
         """One full-history layer's pool (shape and dtype)."""
         return self.cache.k[self.cache.sliding.index(False)]
-
-    def _samp(self, reqs, pad, any_sample):
-        """The sampling arrays; a greedy step reads none of them, so it
-        is handed one device copy a bucket and nothing is transferred."""
-        if any_sample:
-            return self.eng._samp_arrays(reqs, pad)
-        n = len(reqs) + pad
-        if n not in self._greedy:
-            self._greedy[n] = self.eng._samp_arrays([], n)
-        return self._greedy[n]
 
     def chunk_buckets(self, n, ctx_need):
         """One chunk shape, and full-layer contexts in powers of two
@@ -220,18 +209,20 @@ class LayeredPrograms:
         return e.chunk_tokens, min(e.pages, max(
             floor, 1 << (ctx_need - 1).bit_length()))
 
-    def chunk(self, slot, req, ids, start, n, is_last, ctx_pages, cow):
+    def chunk(self, slot, req, start, n, c_bucket, is_last, ctx_pages, cow):
         """(step, number of static operands, operands)."""
         e, c = self.eng, self.cache
         sample = req.do_sample and is_last
+        ids = np.zeros((1, c_bucket), np.int32)
+        ids[0, :n] = req.prompt[start:start + n]
         wrow, wbase = self.ring.view(slot, start + n - 1)
         ints = np.concatenate([
             np.array([start, start + n, req.prompt.size - 1 - start, wbase],
                      np.int32), e._tables[slot], wrow])
         return chunk_step, 4, (
             self.spec, sample, is_last, ctx_pages, e.params,
-            jnp.asarray(ids), jnp.asarray(ints), c.k, c.v,
-            self._samp([req], 0, sample), e._key)
+            e._put(ids), e._put(ints), c.k, c.v,
+            e._samp([req], 0, sample), e._key)
 
     def chunk_done(self, out, n, is_last, run):
         """Take the program's result: swap the pools in, fetch the token
@@ -244,19 +235,20 @@ class LayeredPrograms:
         run.attrs.update(self._moe_attrs(n, picks, loads))
         return int(tok[0]) if is_last else None
 
-    def decode(self, active, reqs, bucket, tok, pos, tables, any_sample):
+    def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.cache
         n = len(active)
         ints = np.zeros((bucket, 4 + e.pages + self.ring.pages), np.int32)
         ints[:, 4:] = TRASH_BLOCK
-        ints[:, 0], ints[:, 1], ints[:n, 2] = tok, pos, 1
-        ints[:, 4:4 + e.pages] = tables
+        ints[:n, 0] = [r.tokens[-1] for r in reqs]
+        ints[:n, 1], ints[:n, 2] = e._slot_pos[active], 1
+        ints[:n, 4:4 + e.pages] = e._tables[active]
         for j, slot in enumerate(active):
             ints[j, 4 + e.pages:], ints[j, 3] = self.ring.view(
                 slot, e._slot_pos[slot])
         return decode_step, 2, (
-            self.spec, any_sample, e.params, jnp.asarray(ints), c.k, c.v,
-            self._samp(reqs, bucket - n, any_sample), e._key)
+            self.spec, any_sample, e.params, e._put(ints), c.k, c.v,
+            e._samp(reqs, bucket - n, any_sample), e._key)
 
     def decode_done(self, out, n_active, run):
         nxt, picks, loads, ck, cv, self.eng._key = out

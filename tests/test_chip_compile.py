@@ -224,15 +224,15 @@ def _dense_programs(kv, sharding):
                 "top_k": sds((b,), i32), "top_p": sds((b,), jnp.float32)}
 
     key = sds((2,), jnp.uint32)
+    # one packed int32 operand a call: [token, position, table row] a
+    # slot; [5 scalars, table row, the chunk's ids]
     yield "decode", pool, E._decode_step.lower(
-        spec, bs, mode, False, params, sds((slots,), i32),
-        sds((slots,), i32), sds((slots, pages), i32), pool, pool, scale,
-        scale, samp(slots), key)
+        spec, bs, mode, False, params, sds((slots, 2 + pages), i32), pool,
+        pool, scale, scale, samp(slots), key)
     yield "chunk", pool, E._chunk_prefill_step.lower(
-        spec, bs, mode, False, False, g["ctx_pages"], params,
-        sds((1, g["chunk"]), i32), sds((), i32), sds((), i32),
-        sds((), i32), sds((pages,), i32), sds((), i32), sds((), i32), pool,
-        pool, scale, scale, samp(1), key)
+        spec, bs, mode, False, False, g["ctx_pages"], pages, params,
+        sds((5 + pages + g["chunk"],), i32), pool, pool, scale, scale,
+        samp(1), key)
 
 
 def _pool_sized_copies(text, pool):

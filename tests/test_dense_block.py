@@ -72,29 +72,29 @@ def _site_programs(arch):
                 "top_p": _sds((b,))}
 
     paged = (spec, BS, "model", False)
+    # the paged programs take what the host decides a call as ONE int32
+    # operand: scalars, the block table row(s), the ids
     return {
         "whole_prompt_prefill": (
-            functools.partial(engine._prefill_impl, *paged),
-            (params, _sds((1, S), i32), _sds((), i32), _sds((PAGES,), i32),
-             pool, pool, None, None, samp(1), key), (1, S, H)),
+            functools.partial(engine._prefill_impl, *paged, PAGES),
+            (params, _sds((1 + PAGES + S,), i32), pool, pool, None, None,
+             samp(1), key), (1, S, H)),
         "static_decode": (
             lambda p, ids, k, n: generation._generate_program.__wrapped__(
                 p, ids, _spec(arch, nkv, max_new_tokens=4), k, n),
             (params, _sds((B, S), i32), key, _sds((), i32)), (B, H)),
         "paged_decode": (
             functools.partial(engine._decode_step_impl, *paged),
-            (params, _sds((B,), i32), _sds((B,), i32),
-             _sds((B, PAGES), i32), pool, pool, None, None, samp(B), key),
-            (B, H)),
+            (params, _sds((B, 2 + PAGES), i32), pool, pool, None, None,
+             samp(B), key), (B, H)),
         "paged_chunk": (
-            functools.partial(engine._chunk_prefill_impl, *paged, True, 2),
-            (params, _sds((1, S), i32), _sds((), i32), _sds((), i32),
-             _sds((), i32), _sds((PAGES,), i32), _sds((), i32),
-             _sds((), i32), pool, pool, None, None, samp(1), key), (S, H)),
+            functools.partial(engine._chunk_prefill_impl, *paged, True, 2,
+                              PAGES),
+            (params, _sds((5 + PAGES + S,), i32), pool, pool, None, None,
+             samp(1), key), (S, H)),
         "paged_verify": (
-            functools.partial(engine._spec_verify_impl, *paged),
-            (params, _sds((B, C), i32), _sds((B,), i32),
-             _sds((B, PAGES), i32), _sds((B,), i32), pool, pool, None, None,
+            functools.partial(engine._spec_verify_impl, *paged, PAGES),
+            (params, _sds((B, 2 + PAGES + C), i32), pool, pool, None, None,
              samp(B), key), (B, C, H)),
         "draft_decode": (
             functools.partial(speculative._draft_propose_impl, spec, 4),

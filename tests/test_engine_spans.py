@@ -125,9 +125,11 @@ def test_decode_spans_are_the_decode_step_observation(served):
                                           abs=1e-6)
     assert sum(ticks) == pytest.approx(eng._m_decode_step.sum, abs=1e-6)
     for b, r in zip(build, run):
-        assert b.end <= r.start and b.attrs == r.attrs
-        assert set(b.attrs) == {"active", "bucket", "live_pages",
+        assert b.end <= r.start
+        assert set(r.attrs) == {"active", "bucket", "live_pages",
                                 "kv_steps"}
+        # the build says besides how many host arrays it handed over
+        assert b.attrs == {**r.attrs, "h2d": b.attrs["h2d"]}
         assert 1 <= b.attrs["active"] <= b.attrs["bucket"] <= 4
         # at least a page a live slot, at most every page of each
         assert b.attrs["active"] <= b.attrs["live_pages"] \
@@ -221,6 +223,195 @@ def test_verify_window_shares_the_spans_clock():
     assert sorted((e.start, e.end) for e in ver) \
         == sorted((t0, t1) for _, t0, t1, _ in wins)
     assert all(set(e.attrs) == {"active", "k"} for e in ver)
+
+
+# ------------------------------------------- one packed operand a call
+
+def _tiny_gpt(max_pos=128):
+    from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
+
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig(
+        vocab_size=128, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, max_position_embeddings=max_pos))
+    m.eval()
+    return m
+
+
+ARCH_KV = [(a, kv) for a in ("llama", "gpt")
+           for kv in ("model", "int8", "int4")]
+BUILDS = ("serving.prefill.build", "serving.chunk.build",
+          "serving.decode.build")
+
+
+def _engine(arch, kv, **kw):
+    model = _tiny_llama() if arch == "llama" else _tiny_gpt()
+    return ServingEngine(model, max_slots=4, kv_block_size=8,
+                         chunked_prefill_tokens=16, kv_cache_dtype=kv,
+                         seed=7, **kw)
+
+
+@pytest.mark.parametrize("arch,kv", ARCH_KV)
+def test_a_greedy_call_hands_the_device_one_packed_operand(arch, kv):
+    """`h2d` on every `.build` span is the number of host arrays its call
+    handed to the device: on greedy traffic ONE (tokens, positions, block
+    tables, ids and the scalars packed into one int32 array; the sampling
+    operands a cached device dict), plus the four arrays of that dict the
+    first time a bucket size is seen."""
+    eng = _engine(arch, kv)
+    rs = np.random.RandomState(3)
+    obs.clear_spans()
+    for ln, nt in ((5, 4), (40, 6), (12, 3), (33, 5), (7, 2), (21, 4)):
+        eng.add_request(rs.randint(0, 128, (ln,)), max_new_tokens=nt)
+    eng.run()
+    builds = sorted((e for e in obs.span_events() if e.name in BUILDS),
+                    key=lambda e: e.start)
+    assert {e.name for e in builds} == set(BUILDS)
+    sizes = set()
+    for e in builds:
+        # the sampling operands' size: the slot bucket, or one request
+        size = e.attrs["bucket"] if e.name == BUILDS[2] else 1
+        assert e.attrs["h2d"] == (1 if size in sizes else 5), e
+        sizes.add(size)
+    assert len(sizes) >= 3 and eng._h2d == len(builds) + 4 * len(sizes)
+    # steady state: the same traffic again transfers one array a call
+    obs.clear_spans()
+    for ln, nt in ((5, 4), (40, 6), (12, 3)):
+        eng.add_request(rs.randint(0, 128, (ln,)), max_new_tokens=nt)
+    eng.run()
+    again = [e for e in obs.span_events() if e.name in BUILDS]
+    assert again and all(e.attrs["h2d"] == 1 for e in again)
+
+
+@pytest.mark.parametrize("arch,kv", ARCH_KV)
+def test_greedy_calls_share_one_sampling_dict_a_size(arch, kv):
+    """Consecutive greedy calls of one bucket size are handed the
+    IDENTICAL device dict (nothing is built or transferred for them), no
+    program donates it, and a sampling call gets arrays of its own."""
+    eng = _engine(arch, kv)
+    seen = []
+    program = eng._program
+
+    def recording(site, jitted, n_static, bucket, any_sample, extra, args):
+        seen.append((site, int(bucket), bool(any_sample), args[-2]))
+        return program(site, jitted, n_static, bucket, any_sample, extra,
+                       args)
+
+    eng._program = recording
+    for ln in (6, 30, 9):
+        eng.add_request(np.arange(ln) % 128, max_new_tokens=6)
+    eng.run()
+    decodes = [s for s in seen if s[0] == "serving.decode"]
+    assert len(decodes) >= 5
+    by_size = {}
+    for site, bucket, any_sample, samp in seen:
+        assert not any_sample
+        size = bucket if site == "serving.decode" else 1
+        assert by_size.setdefault(size, samp) is samp
+        assert samp is eng._greedy_samp[size]
+    assert len(by_size) == len(eng._greedy_samp) >= 2
+    for size, samp in by_size.items():          # alive after every call
+        assert set(samp) == {"do_sample", "temperature", "top_k", "top_p"}
+        assert not any(a.is_deleted() for a in samp.values())
+        assert samp["do_sample"].shape == (size,)
+        assert not np.asarray(samp["do_sample"]).any()
+    # a sampled request: its calls are handed fresh arrays, the greedy
+    # dicts stay what they were
+    seen.clear()
+    eng.add_request(np.arange(7), max_new_tokens=3, do_sample=True,
+                    temperature=0.7, top_k=5)
+    eng.run()
+    sampled = [s for s in seen if s[2]]
+    assert sampled and all(s[3] is not by_size.get(1) for s in sampled)
+    assert len({id(s[3]) for s in sampled}) == len(sampled)
+    assert all(eng._greedy_samp[k] is v for k, v in by_size.items())
+
+
+#: what the parent commit (unpacked operands, `_samp_arrays` on every
+#: call) served for `_mixed_traffic`, by (arch, kv_mode, spec_decode): generated by
+#: running `_mixed_traffic` on that tree, CPU backend
+PARENT_TOKENS = {
+    ("llama", "model", None): [
+        [105, 12, 77, 3, 62, 116, 41, 108],
+        [42, 50, 43, 110, 126, 89, 115, 72],
+        [108, 90, 58, 1, 74, 18, 11, 97],
+        [57, 48, 117, 35, 96, 84, 1, 126],
+    ],
+    ("llama", "int8", None): [
+        [105, 12, 77, 3, 62, 116, 41, 108],
+        [42, 50, 43, 110, 126, 89, 115, 72],
+        [108, 90, 58, 1, 74, 18, 11, 97],
+        [57, 48, 117, 35, 96, 84, 1, 126],
+    ],
+    ("llama", "int4", None): [
+        [105, 12, 77, 126, 119, 9, 82, 34],
+        [42, 50, 82, 23, 3, 93, 84, 89],
+        [108, 90, 58, 1, 74, 18, 11, 97],
+        [57, 48, 19, 92, 96, 84, 1, 70],
+    ],
+    ("gpt", "model", None): [
+        [4, 57, 30, 67, 24, 80, 57, 71],
+        [77, 38, 97, 103, 121, 77, 109, 57],
+        [108, 38, 1, 70, 60, 18, 15, 97],
+        [13, 73, 1, 76, 1, 65, 0, 24],
+    ],
+    ("gpt", "int8", None): [
+        [4, 57, 30, 67, 24, 80, 57, 71],
+        [77, 38, 97, 103, 121, 77, 109, 57],
+        [108, 38, 1, 70, 60, 18, 15, 97],
+        [13, 73, 1, 76, 1, 65, 0, 24],
+    ],
+    ("gpt", "int4", None): [
+        [4, 57, 30, 67, 24, 80, 57, 71],
+        [77, 38, 97, 103, 0, 115, 26, 89],
+        [108, 38, 1, 70, 60, 18, 15, 97],
+        [13, 73, 1, 76, 1, 65, 0, 24],
+    ],
+    ("llama", "model", "ngram"): [
+        [105, 12, 77, 3, 62, 116, 41, 108],
+        [42, 50, 43, 110, 126, 89, 113, 123],
+        [108, 32, 94, 74, 94, 103, 31, 38],
+        [57, 48, 117, 35, 96, 84, 1, 126],
+    ],
+}
+
+
+def _mixed_traffic(arch, kv, **kw):
+    """Greedy and sampled requests side by side in one engine: a whole
+    short prompt and a chunked one of each kind, so that the prefill, the
+    last chunk and the decode bucket all see a mixed `do_sample`."""
+    eng = _engine(arch, kv, **kw)
+    rs = np.random.RandomState(11)
+    rids = [
+        eng.add_request(rs.randint(0, 128, (5,)), max_new_tokens=8),
+        eng.add_request(rs.randint(0, 128, (12,)), max_new_tokens=8,
+                        do_sample=True, temperature=0.8, top_k=5),
+        eng.add_request(rs.randint(0, 128, (40,)), max_new_tokens=8,
+                        do_sample=True, temperature=1.3, top_p=0.9),
+        eng.add_request(rs.randint(0, 128, (27,)), max_new_tokens=8),
+    ]
+    done = eng.run()
+    return [done[r].tolist() for r in rids]
+
+
+@pytest.mark.parametrize("arch,kv,spec", [(*a, None) for a in ARCH_KV]
+                         + [("llama", "model", "ngram")])
+def test_a_mixed_bucket_serves_the_parents_tokens(arch, kv, spec):
+    """The sampling path is what it was: for a fixed engine key a bucket
+    that mixes greedy and sampled rows serves the tokens the parent
+    commit served (through the verify window too), and its greedy rows
+    are those of an all-greedy run."""
+    got = _mixed_traffic(arch, kv, **({"spec_decode": spec} if spec else {}))
+    assert got == PARENT_TOKENS[arch, kv, spec]
+    eng = _engine(arch, kv)
+    rs = np.random.RandomState(11)
+    prompts = [rs.randint(0, 128, (n,)) for n in (5, 12, 40, 27)]
+    rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
+    done = eng.run()
+    assert got[0] == done[rids[0]].tolist()
+    assert got[3] == done[rids[3]].tolist()
+    assert got[1] != done[rids[1]].tolist() \
+        or got[2] != done[rids[2]].tolist()      # sampling did sample
 
 
 # ------------------------------------------------------------ arrival_s
