@@ -5,7 +5,7 @@ The layer of "sigmoid top-k" mixture-of-experts decoders with shared
 experts, as one chip of an expert-parallel deployment computes it:
 
     s      = sigmoid(h Wr)  in R^E            the router, in float32, over ALL E experts
-    T      = the k largest of s ;  g_e = s_e / sum_{e' in T} s_e'
+    T      = the k largest of s ;  g_e = c * s_e / sum_{e' in T} s_e'     c: `routed_scale`, 1 unless the model says
     routed = sum_{e in T, e held here} g_e * Wd_e( silu(Wg_e h) * (Wu_e h) )
     shared = (1/S) * sum_j Wd'_j( silu(Wg'_j h) * (Wu'_j h) )
 
@@ -33,18 +33,21 @@ import jax.numpy as jnp
 _F32 = jnp.float32
 
 
-def route_sigmoid_topk(h, w_router, top_k: int):
+def route_sigmoid_topk(h, w_router, top_k: int, routed_scale: float = 1.0):
     """(idx [T, k] int32, gates [T, k] float32): the k highest sigmoid
-    scores of each token over all experts, normalised over the k. The
-    router runs in float32 at full matmul precision: a pick that flips on
-    rounding sends a token to another chip."""
+    scores of each token over all experts, normalised over the k and
+    multiplied by `routed_scale`. The router runs in float32 at full
+    matmul precision: a pick that flips on rounding sends a token to
+    another chip."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             h.astype(_F32), w_router.astype(_F32),
             precision=jax.lax.Precision.HIGHEST))
         top, idx = jax.lax.top_k(scores, top_k)
-        return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1,
-                                                    keepdims=True)
+        gates = top / jnp.sum(top, axis=-1, keepdims=True)
+        if routed_scale != 1.0:
+            gates = gates * jnp.float32(routed_scale)
+        return idx.astype(jnp.int32), gates
 
 
 def local_gates(idx, gates, first_expert: int, num_local: int):
@@ -91,13 +94,15 @@ def shared_experts_mean(h, w_gate, w_up, w_down, num_shared: int):
 
 
 def moe_forward(h, lw, *, top_k, first_expert, num_local, num_shared,
-                valid=None, h_router=None):
+                valid=None, h_router=None, routed_scale=1.0):
     """(routed + shared [T, H] float32, picks, max_load) for one layer's
     weights `lw` (keys `router`, `experts_gate|up|down`,
     `shared_gate|up|down`). `h_router`: the same activations before they
-    were rounded to the matmuls' dtype, for the float32 router."""
+    were rounded to the matmuls' dtype, for the float32 router;
+    `routed_scale`: the model's factor on the normalised gates (the
+    shared experts are not scaled)."""
     idx, gates = route_sigmoid_topk(h if h_router is None else h_router,
-                                    lw["router"], top_k)
+                                    lw["router"], top_k, routed_scale)
     gate_mat = local_gates(idx, gates, first_expert, num_local)
     picks, max_load = local_load(gate_mat, valid)
     routed = local_experts(h, gate_mat, lw["experts_gate"],

@@ -593,6 +593,9 @@ class _StackedPrograms:
             e.params, jnp.zeros((bucket, 2 + e.pages), jnp.int32),
             c.k, c.v, c.k_scale, c.v_scale, samp, e._key)
 
+    def kv_steps(self, bucket):
+        return self.eng._kv_steps(bucket)
+
     def update_gauges(self):
         """No gauge of its own: one kind of layer state."""
 
@@ -795,10 +798,12 @@ class ServingEngine:
             flag("FLAGS_chunked_prefill_tokens")
             if chunked_prefill_tokens is None else chunked_prefill_tokens)
         if per_layer:
-            # two kinds of layer state in one manager: the allocator's
-            # pages are the full layers' alone; a window layer keeps a
-            # static ring of the last window + chunk positions a slot
-            self._refuse_for_layered(arch, mode, spec_decode, prefix_cache)
+            # layer state by kind in one manager: the allocator's pages
+            # are the full-history layers' (K and V, or a latent row); a
+            # window layer keeps a static ring of the last window + chunk
+            # positions a slot
+            self._refuse_for_layered(arch, mode, spec_decode, prefix_cache,
+                                     layered.refusals(blk))
             prefix_cache = False
             self.programs = layered.LayeredPrograms(
                 self, blk, int(num_kv_blocks), dtype)
@@ -999,6 +1004,15 @@ class ServingEngine:
         self._m_kv_full = reg.gauge(
             "serving_kv_full_blocks_used", "full-history cache blocks "
             "allocated to live requests")
+        # ---- the latent (MLA) cache (PR 37): one row a position a layer
+        self._m_kv_latent = reg.gauge(
+            "serving_kv_latent_bytes_held", "bytes of latent rows (as "
+            "stored, padding included) in the blocks allocated to live "
+            "requests, all latent layers")
+        self._m_latent_ctx = reg.counter(
+            "serving_latent_ctx_tokens_total", "cached positions the step "
+            "programs attended, once a latent layer: each decode token's "
+            "context and each chunk token's positions up to its own")
         # config: explicit arg wins; the FLAGS_spec_decode string is the
         # flag-surface shorthand ("off" | "ngram" | "draft")
         from .speculative import SpecConfig, make_proposer
@@ -1068,36 +1082,33 @@ class ServingEngine:
                     "obs.serve_metrics(port, engine.registry) to expose "
                     "it elsewhere", key="obs-http-bind")
 
-    def _refuse_for_layered(self, arch, kv_mode, spec_decode, prefix_cache):
-        """What an architecture with two kinds of layer state does not
-        get yet, each refused by name at construction (no fallback).
-        `kv_mode`, `self.weight_quant` and `self.chunk_tokens` are the
-        resolved values (argument, else flag)."""
+    def _refuse_for_layered(self, arch, kv_mode, spec_decode, prefix_cache,
+                            why):
+        """What a model served by the per-layer programs does not get
+        yet, each refused by name at construction (no fallback). `why`:
+        the reason by option, true of the model's kinds of layer state
+        (`layered.refusals`). `kv_mode`, `self.weight_quant` and
+        `self.chunk_tokens` are the resolved values (argument, else
+        flag)."""
         from ..core.flags import flag
 
-        def refuse(option, why):
-            raise ValueError(f"{option} is not supported for {arch}: {why}")
+        def refuse(option, key):
+            raise ValueError(
+                f"{option} is not supported for {arch}: {why[key]}")
 
         if self.weight_quant != "none":
-            refuse(f"weight_quant={self.weight_quant!r}",
-                   "its step programs read the model's own buffers and "
-                   "have no dequantising matmul")
+            refuse(f"weight_quant={self.weight_quant!r}", "weight_quant")
         if kv_mode != "model":
-            refuse(f"kv_cache_dtype={kv_mode!r}",
-                   "the window layers' ring has no per-block scales")
+            refuse(f"kv_cache_dtype={kv_mode!r}", "kv_cache_dtype")
         spec = (str(flag("FLAGS_spec_decode")) if spec_decode is None
                 else spec_decode)
         if spec != "off":
-            refuse(f"spec_decode={spec!r}",
-                   "there is no verify program for two-kind layers")
+            refuse(f"spec_decode={spec!r}", "spec_decode")
         if prefix_cache:
-            refuse("prefix_cache=True",
-                   "a cached prefix holds no window-layer state to resume "
-                   "from")
+            refuse("prefix_cache=True", "prefix_cache")
         if self.chunk_tokens <= 0:
             refuse(f"chunked_prefill_tokens={self.chunk_tokens}",
-                   "every prompt is prefilled by chunks (the window "
-                   "layers' ring is sized by the chunk)")
+                   "chunked_prefill_tokens")
 
     # ------------------------------------------------------------- API
     def add_request(self, prompt, max_new_tokens=32, do_sample=False,
@@ -1788,8 +1799,9 @@ class ServingEngine:
         return tok, t_end
 
     def _kv_steps(self, bucket):
-        """Grid steps a layer of the `paged_decode` kernel at this slot
-        bucket; 0 where the router keeps the XLA composition."""
+        """Grid steps a layer of the `paged_decode` kernel over K and V
+        pools at this slot bucket; 0 where the router keeps the XLA
+        composition."""
         from ..ops import pallas_decode as pd
 
         full = self.programs.full_pool()     # [N, H_kv, rows, D]
@@ -1810,7 +1822,7 @@ class ServingEngine:
 
         bucket = min(default_buckets(len(active)), self.max_slots)
         if bucket not in self._kv_steps_of:
-            self._kv_steps_of[bucket] = self._kv_steps(bucket)
+            self._kv_steps_of[bucket] = self.programs.kv_steps(bucket)
         at = {"active": len(active), "bucket": int(bucket),
               "live_pages": int(
                   (self._slot_pos[active] // self.block_size + 1).sum()),

@@ -1,17 +1,19 @@
 """The serving engine's step programs for a model that declares its
 layers one by one (`model.serving_arrays()`: its own buffers, a layer
-each; `config.block_spec()`: each layer's cache kind): full-attention
-layers keep the whole history in pages the `BlockAllocator` hands out,
-sliding-window layers keep a `WindowRing` of the last `window + chunk`
-positions a slot.
+each; `config.block_spec()`: the block's sizes and each layer's kind):
+full-attention layers keep the whole history in pages the
+`BlockAllocator` hands out, sliding-window layers keep a `WindowRing` of
+the last `window + chunk` positions a slot, latent-attention layers keep
+ONE row a position (no heads, no V) in pages of the same tables.
 
-The block's mathematics is `text/models/parallel_block.block`, the same
-function the Layer's `forward` calls; what these programs add is WHERE
-the keys and values live (`attend`: scatter the step's K/V into the
-layer's pool through its table, attend over the pool), the head, the
-sampling, and the expert layer's counts. Layers are unrolled, each with
-its own pool array, and the parameters are the model's own buffers: no
-stacked second copy of either exists.
+The block's mathematics is the model's block module's
+(`text/models/parallel_block.py`, `latent_block.py`; `_BLOCKS` finds it by
+the spec's type), the same `block` function the Layer's `forward` calls;
+what these programs add is WHERE the cached state lives (`attend`:
+scatter the step's rows into the layer's pool through its table, attend
+over the pool), the head, the sampling, and the expert layer's counts.
+Layers are unrolled, each with its own pool array, and the parameters are
+the model's own buffers: no stacked second copy of either exists.
 
 Two programs, as for the other architectures: `decode_step` (a compacted
 slot bucket advances one token) and `chunk_step` (one chunk of one
@@ -23,6 +25,11 @@ entry i is absolute page `wbase + i`) and address it by position less
 only, so it is the same in either frame. Everything the host decides a
 step (tokens, positions, tables, ring views) reaches a program as ONE
 int32 array: one transfer a program call, not one a value.
+
+A latent layer's decode is the absorbed form over its pages
+(`ops/pallas_decode.paged_latent_decode`); its chunk attention runs over
+blocks of the context, as many as reach the chunk's end, so one chunk
+program serves every context length.
 
 `LayeredPrograms` is what `ServingEngine._run_chunk` and `_decode` ask
 for these programs and their operands (`engine._StackedPrograms` answers
@@ -37,19 +44,114 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import pallas_decode as pd
 from ..ops.pallas_decode import paged_decode_attention
+from ..text.models import latent_block as lb
 from ..text.models import parallel_block as pb
 from ..text.paged_cache import (TRASH_BLOCK, LayeredKVCache, WindowRing,
                                 append_rows, blocks_for, gather_context,
-                                scatter_chunk_rows)
+                                latent_row_width, scatter_chunk_rows)
+
+#: the block module of a spec: `block`, `head`, `rope_tables`, `cache_kind`
+_BLOCKS = {pb.BlockSpec: pb, lb.BlockSpec: lb}
 
 
 @dataclass(frozen=True)
 class LayeredSpec:
     """Static key of the programs."""
-    block: pb.BlockSpec
+    block: pb.BlockSpec | lb.BlockSpec
     block_size: int
     window_pages: int
+
+
+#: why a per-layer model is refused an option, by what its layers keep
+_REFUSALS = {
+    pb.SLIDING: {
+        "kv_cache_dtype": "the window layers' ring has no per-block scales",
+        "spec_decode": "there is no verify program for two-kind layers",
+        "prefix_cache": "a cached prefix holds no window-layer state to "
+                        "resume from",
+        "chunked_prefill_tokens": "every prompt is prefilled by chunks (the "
+                                  "window layers' ring is sized by the "
+                                  "chunk)"},
+    lb.LATENT: {
+        "kv_cache_dtype": "a latent pool has no per-block scales, and the "
+                          "absorbed decode kernel reads its rows as they "
+                          "are stored",
+        "spec_decode": "there is no verify program over a latent cache "
+                       "(the config's multi-token-prediction module, its "
+                       "drafter, is not built either)",
+        "prefix_cache": "latent pages are not registered under content "
+                        "hashes, and the per-layer chunk program has no "
+                        "copy-on-write of a shared page",
+        "chunked_prefill_tokens": "every prompt is prefilled by chunks (the "
+                                  "per-layer programs have no whole-prompt "
+                                  "one)"}}
+
+
+def refusals(block) -> dict:
+    """{option: why `ServingEngine` refuses it} for a model of `block`'s
+    kinds of layer state."""
+    mod = _BLOCKS[type(block)]
+    kinds = {mod.cache_kind(k) for k in block.layer_types}
+    return {"weight_quant": "its step programs read the model's own "
+                            "buffers and have no dequantising matmul",
+            **_REFUSALS[lb.LATENT if lb.LATENT in kinds else pb.SLIDING]}
+
+
+def _latent_row(c, kr, pool):
+    """(c, kr) of some positions as rows of `pool` [N, 1, bs, W]: side by
+    side, zeros up to the stored width. [T, 1, W]."""
+    pad = pool.shape[-1] - c.shape[-1] - kr.shape[-1]
+    return jnp.pad(jnp.concatenate([c, kr], axis=-1),
+                   ((0, 0), (0, pad)))[:, None, :].astype(pool.dtype)
+
+
+def _latent_decode_attend(blk, pools, li, tables, pos, rows, bs):
+    """`attend` of a latent layer in a decode step: append each slot's
+    row through its table, then the absorbed form over the slot's pages
+    (`paged_latent_decode`: every head against the one cached row)."""
+    def attend(q_nope, q_rope, c, kr, w_kvb):
+        pools[li] = append_rows(pools[li], _latent_row(c, kr, pools[li]),
+                                tables[rows, pos // bs],
+                                (pos % bs).astype(jnp.int32))
+        q = lb.absorb_query(q_nope, w_kvb, blk)
+        width = pools[li].shape[-1]
+        q = jnp.pad(jnp.concatenate([q, q_rope], axis=-1),
+                    ((0, 0), (0, 0), (0, width - blk.latent_width)))
+        olat = pd.paged_latent_decode(q, pools[li], tables, pos + 1,
+                                      blk.kv_rank, blk.scale)
+        return lb.unabsorb(olat, w_kvb, blk)
+    return attend
+
+
+def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
+    """`attend` of a latent layer in a chunk step: scatter the chunk's
+    rows through the table, then attend over the pages that hold
+    positions [0, start + C), `lb.CTX_BLOCK` positions a step (the whole
+    table where it holds fewer) with an online softmax: the steps run to
+    the chunk's end and no further (a traced count: ONE program for every
+    context length), and no [heads, C, context] tensor exists. In the
+    EXPANDED form: on the chip the absorbed one took 1.47 x as long at 6k
+    and at 14k of context (PERF.md section 6, PR 37)."""
+    block = min(lb.CTX_BLOCK, table.shape[0] * bs)
+    per = block // bs
+
+    def attend(q_nope, q_rope, c, kr, w_kvb):
+        pools[li] = scatter_chunk_rows(
+            pools[li], _latent_row(c, kr, pools[li]), start, true_end,
+            table, bs)
+        n_blocks = jnp.minimum(
+            (start + q_nope.shape[0] + block - 1) // block,
+            table.shape[0] // per)
+
+        def rows_of(j):
+            ids = jax.lax.dynamic_slice_in_dim(table, j * per, per)
+            return pools[li][ids].reshape(block, -1)
+
+        return lb.expanded_attention(q_nope, q_rope, rows_of, n_blocks,
+                                     block, w_kvb, pos, blk)
+    return attend
 
 
 def _sample(lg, any_sample, samp, key):
@@ -69,9 +171,12 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
     + R], a row a slot (`LayeredPrograms.decode` packs it): its token,
     its position, 1 for a live row (0: padding, whose tables are the
     trash block), its ring view's base page, the full layers' block table
-    [pages] and the ring view [R]. ks/vs: one pool a layer. Returns (next
-    tokens [B], local picks [L], largest expert load [L], ks, vs, key)."""
+    [pages] and the ring view [R] (R = 0 for a model without window
+    layers). ks/vs: one pool a layer (a latent model: its one pool a layer
+    in `ks`, `vs` empty). Returns (next tokens [B], local picks [L],
+    largest expert load [L], ks, vs, key)."""
     blk, bs = spec.block, spec.block_size
+    mod = _BLOCKS[type(blk)]
     tok, pos, valid, wbase = (ints[:, i] for i in range(4))
     ftables = ints[:, 4:ints.shape[1] - spec.window_pages]
     wtables = ints[:, ints.shape[1] - spec.window_pages:]
@@ -86,23 +191,28 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
         sliding = kind == pb.SLIDING
         tables, p = (wtables, wpos) if sliding else (ftables, pos)
 
-        def attend(q, k, v):
-            bid = tables[rows, p // bs]
-            off = (p % bs).astype(jnp.int32)
-            ks[li] = append_rows(ks[li], k, bid, off)
-            vs[li] = append_rows(vs[li], v, bid, off)
-            if sliding:
-                return paged_decode_attention(
-                    q, ks[li], vs[li], tables, p + 1,
-                    kv_start=jnp.maximum(p + 1 - blk.window, 0),
-                    name="paged_window_decode")
-            return paged_decode_attention(q, ks[li], vs[li], tables, p + 1)
+        if mod.cache_kind(kind) == lb.LATENT:
+            attend = _latent_decode_attend(blk, ks, li, ftables, pos, rows,
+                                           bs)
+        else:
+            def attend(q, k, v):
+                bid = tables[rows, p // bs]
+                off = (p % bs).astype(jnp.int32)
+                ks[li] = append_rows(ks[li], k, bid, off)
+                vs[li] = append_rows(vs[li], v, bid, off)
+                if sliding:
+                    return paged_decode_attention(
+                        q, ks[li], vs[li], tables, p + 1,
+                        kv_start=jnp.maximum(p + 1 - blk.window, 0),
+                        name="paged_window_decode")
+                return paged_decode_attention(q, ks[li], vs[li], tables,
+                                              p + 1)
 
-        x, n, m = pb.block(x, params["layers"][li], blk, kind, attend,
-                           rope=rope, valid=valid)
+        x, n, m = mod.block(x, params["layers"][li], blk, kind, attend,
+                            rope=rope, valid=valid)
         picks.append(n)
         loads.append(m)
-    lg = pb.logits(x, params["final_ln"], params["embed"], blk)
+    lg = mod.head(x, params, blk)
     nxt, key = _sample(lg, any_sample, samp, key)
     return (nxt, jnp.stack(picks), jnp.stack(loads), tuple(ks), tuple(vs),
             key)
@@ -120,10 +230,13 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
     `ftable` under `kv <= q`, a window layer the whole ring view under
     `0 <= q - kv < window`. Scores are computed one KV head's group at a
     time (`parallel_block.grouped_attention`), so the largest temporary
-    is [heads a KV head, C, context] in float32. `emit_token` (static):
+    is [heads a KV head, C, context] in float32. A latent layer:
+    `_latent_chunk_attend` (`ctx_pages` is then the whole table and not
+    read). `emit_token` (static):
     the prompt's final chunk samples the first token from chunk row
     `last_idx`."""
     blk, bs = spec.block, spec.block_size
+    mod = _BLOCKS[type(blk)]
     start, true_end, last_idx, wbase = (ints[i] for i in range(4))
     ftable = ints[4:ints.shape[0] - spec.window_pages]
     wtable = ints[ints.shape[0] - spec.window_pages:]
@@ -140,25 +253,29 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
         table, shift, pages = ((wtable, wbase * bs, spec.window_pages)
                                if sliding else (ftable, 0, ctx_pages))
 
-        def attend(q, k, v):
-            ks[li] = scatter_chunk_rows(ks[li], k, start - shift,
-                                        true_end - shift, table, bs)
-            vs[li] = scatter_chunk_rows(vs[li], v, start - shift,
-                                        true_end - shift, table, bs)
-            kx = gather_context(ks[li], None, table, pages)
-            vx = gather_context(vs[li], None, table, pages)
-            seen = pb.visible(pos - shift, jnp.arange(pages * bs), kind,
-                              blk.window)
-            return pb.grouped_attention(q, kx.astype(q.dtype),
-                                        vx.astype(q.dtype), seen)
+        if mod.cache_kind(kind) == lb.LATENT:
+            attend = _latent_chunk_attend(blk, ks, li, ftable, start,
+                                          true_end, pos, bs)
+        else:
+            def attend(q, k, v):
+                ks[li] = scatter_chunk_rows(ks[li], k, start - shift,
+                                            true_end - shift, table, bs)
+                vs[li] = scatter_chunk_rows(vs[li], v, start - shift,
+                                            true_end - shift, table, bs)
+                kx = gather_context(ks[li], None, table, pages)
+                vx = gather_context(vs[li], None, table, pages)
+                seen = pb.visible(pos - shift, jnp.arange(pages * bs), kind,
+                                  blk.window)
+                return pb.grouped_attention(q, kx.astype(q.dtype),
+                                            vx.astype(q.dtype), seen)
 
-        x, n, m = pb.block(x, params["layers"][li], blk, kind, attend,
-                           rope=rope, valid=valid)
+        x, n, m = mod.block(x, params["layers"][li], blk, kind, attend,
+                            rope=rope, valid=valid)
         picks.append(n)
         loads.append(m)
     if emit_token:
         x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=0)
-        lg = pb.logits(x_last, params["final_ln"], params["embed"], blk)
+        lg = mod.head(x_last, params, blk)
         tok, key = _sample(lg, any_sample, samp, key)
     else:
         tok = jnp.zeros((1,), jnp.int32)
@@ -174,40 +291,84 @@ chunk_step = functools.partial(
 
 
 class LayeredPrograms:
-    """The two-kind side of `ServingEngine`: the window layers' ring, the
-    pools, and for `_run_chunk` / `_decode` each site's step function
-    with its operands and what its result means. The counterpart of
-    `engine._StackedPrograms`, method for method."""
+    """The per-layer side of `ServingEngine`: the window layers' ring
+    (where the model has window layers), the pools, and for `_run_chunk`
+    / `_decode` each site's step function with its operands and what its
+    result means. The counterpart of `engine._StackedPrograms`, method
+    for method."""
 
     #: every prompt goes through the chunk program (no whole-prompt one)
     whole_prompt_prefill = False
 
-    def __init__(self, eng, block: pb.BlockSpec, full_blocks: int, dtype):
+    def __init__(self, eng, block, full_blocks: int, dtype):
         self.eng = eng
+        mod = _BLOCKS[type(block)]
+        kinds = [mod.cache_kind(k) for k in block.layer_types]
+        self.latent = lb.LATENT in kinds
+        self.expert_layers = sum(k != lb.DENSE for k in block.layer_types)
+        sliding = [k == pb.SLIDING for k in kinds]
         self.ring = WindowRing(eng.max_slots, block.window,
-                               eng.chunk_tokens, eng.block_size)
-        self.spec = LayeredSpec(block=block, block_size=eng.block_size,
-                                window_pages=self.ring.pages)
-        cos, sin = pb.rope_tables(eng.max_model_len, block.head_dim,
-                                  block.rope_theta)
+                               eng.chunk_tokens, eng.block_size) \
+            if any(sliding) else None
+        self.spec = LayeredSpec(
+            block=block, block_size=eng.block_size,
+            window_pages=self.ring.pages if self.ring else 0)
+        cos, sin = mod.rope_tables(eng.max_model_len, block.rope_dim,
+                                   block.rope_theta)
         eng.params.update(rope_cos=jnp.asarray(cos), rope_sin=jnp.asarray(sin))
-        self.cache = LayeredKVCache(
-            [k == pb.SLIDING for k in block.layer_types], full_blocks,
-            self.ring.num_blocks, block.num_kv_heads, eng.block_size,
-            block.head_dim, dtype)
+        if self.latent:
+            step = min(lb.CTX_BLOCK, eng.max_model_len)
+            if step % eng.block_size or eng.max_model_len % step:
+                raise ValueError(
+                    f"a chunk attends its latent context {step} positions "
+                    f"a step: kv_block_size {eng.block_size} must divide "
+                    f"that, and that max_model_len {eng.max_model_len}")
+            self.cache = LayeredKVCache(
+                sliding, full_blocks, 0, 1, eng.block_size,
+                latent_row_width(block.latent_width), dtype, latent=True)
+        else:
+            self.cache = LayeredKVCache(
+                sliding, full_blocks, self.ring.num_blocks,
+                block.num_kv_heads, eng.block_size, block.head_dim, dtype)
 
     def full_pool(self):
         """One full-history layer's pool (shape and dtype)."""
         return self.cache.k[self.cache.sliding.index(False)]
 
+    def kv_steps(self, bucket):
+        """Grid steps a layer of the decode kernel over a full-history
+        pool at this slot bucket (0: the XLA composition)."""
+        e, pool = self.eng, self.full_pool()
+        if not self.latent:
+            return e._kv_steps(bucket)
+        blk = self.spec.block
+        q = jax.ShapeDtypeStruct((bucket, blk.num_heads, pool.shape[-1]),
+                                 pool.dtype)
+        tables = jax.ShapeDtypeStruct((bucket, e.pages), jnp.int32)
+        if not pd.use_pallas_latent_decode(q, pool, tables, blk.kv_rank):
+            return 0
+        return pd.kv_steps(bucket, e.pages, pool.shape[2], 1,
+                           pool.shape[3], pool.dtype.itemsize)
+
     def chunk_buckets(self, n, ctx_need):
-        """One chunk shape, and full-layer contexts in powers of two
-        from the window up: a handful of programs, where attention over
-        the padding is a small part of a chunk's work."""
+        """One chunk shape. Window and full layers: full-layer contexts
+        in powers of two from the window up, a handful of programs, where
+        attention over the padding is a small part of a chunk's work. A
+        latent cache: ONE program, its attention's steps counted in the
+        program from the chunk's own end."""
         e = self.eng
+        if self.latent:
+            return e.chunk_tokens, e.pages
         floor = blocks_for(self.spec.block.window, e.block_size)
         return e.chunk_tokens, min(e.pages, max(
             floor, 1 << (ctx_need - 1).bit_length()))
+
+    def _ring_view(self, slot, last_pos):
+        """(table row, base page) of the slot's ring; a model with no
+        window layer has neither."""
+        if self.ring is None:
+            return np.zeros(0, np.int32), 0
+        return self.ring.view(slot, last_pos)
 
     def chunk(self, slot, req, start, n, c_bucket, is_last, ctx_pages, cow):
         """(step, number of static operands, operands)."""
@@ -215,7 +376,7 @@ class LayeredPrograms:
         sample = req.do_sample and is_last
         ids = np.zeros((1, c_bucket), np.int32)
         ids[0, :n] = req.prompt[start:start + n]
-        wrow, wbase = self.ring.view(slot, start + n - 1)
+        wrow, wbase = self._ring_view(slot, start + n - 1)
         ints = np.concatenate([
             np.array([start, start + n, req.prompt.size - 1 - start, wbase],
                      np.int32), e._tables[slot], wrow])
@@ -233,18 +394,24 @@ class LayeredPrograms:
         self.cache.swap(ck, cv)
         tok, picks, loads = jax.device_get((tok, picks, loads))
         run.attrs.update(self._moe_attrs(n, picks, loads))
+        if self.latent:
+            # every chunk position attends the positions up to its own
+            start = run.attrs["start"]
+            run.attrs["attn_pairs"] = self._latent_ctx(
+                n * start + n * (n + 1) // 2)
         return int(tok[0]) if is_last else None
 
     def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.cache
         n = len(active)
-        ints = np.zeros((bucket, 4 + e.pages + self.ring.pages), np.int32)
+        ints = np.zeros((bucket, 4 + e.pages + self.spec.window_pages),
+                        np.int32)
         ints[:, 4:] = TRASH_BLOCK
         ints[:n, 0] = [r.tokens[-1] for r in reqs]
         ints[:n, 1], ints[:n, 2] = e._slot_pos[active], 1
         ints[:n, 4:4 + e.pages] = e._tables[active]
         for j, slot in enumerate(active):
-            ints[j, 4 + e.pages:], ints[j, 3] = self.ring.view(
+            ints[j, 4 + e.pages:], ints[j, 3] = self._ring_view(
                 slot, e._slot_pos[slot])
         return decode_step, 2, (
             self.spec, any_sample, e.params, e._put(ints), c.k, c.v,
@@ -255,41 +422,64 @@ class LayeredPrograms:
         self.cache.swap(ck, cv)
         nxt, picks, loads = jax.device_get((nxt, picks, loads))
         run.attrs.update(self._moe_attrs(n_active, picks, loads))
+        if self.latent:
+            e = self.eng
+            live = [i for i, r in enumerate(e._slot_req)
+                    if r is not None and r.prefill_done]
+            run.attrs["ctx_tokens"] = self._latent_ctx(
+                int(e._slot_pos[live].sum()) + len(live))
         return np.asarray(nxt)
 
     def decode_jaxpr(self, bucket, samp):
         e, c = self.eng, self.cache
-        ints = jnp.zeros((bucket, 4 + e.pages + self.ring.pages), jnp.int32)
+        ints = jnp.zeros((bucket, 4 + e.pages + self.spec.window_pages),
+                         jnp.int32)
         fn = functools.partial(_decode_impl, self.spec, False)
         return jax.make_jaxpr(fn)(e.params, ints, c.k, c.v, samp, e._key)
 
     def kv_held(self):
-        """(full-layer blocks allocated to live requests, bytes of the
+        """(full-history blocks allocated to live requests, bytes of the
         occupied slots' window rings over all window layers)."""
         e = self.eng
-        return (sum(len(b) for b in e._slot_blocks),
-                e.num_active * self.ring.tokens_reserved()
-                * self.cache.bytes_per_token(True))
+        window = 0 if self.ring is None else (
+            e.num_active * self.ring.tokens_reserved()
+            * self.cache.bytes_per_token(True))
+        return sum(len(b) for b in e._slot_blocks), window
+
+    def _paged_bytes(self, blocks):
+        """Bytes `blocks` allocated blocks hold over the full-history
+        layers (K and V, or the latent rows as stored)."""
+        return blocks * self.eng.block_size * self.cache.bytes_per_token(
+            False)
 
     def update_gauges(self):
         full_blocks, window_bytes = self.kv_held()
         self.eng._m_kv_full.set(full_blocks)
         self.eng._m_kv_window.set(window_bytes)
+        if self.latent:
+            self.eng._m_kv_latent.set(self._paged_bytes(full_blocks))
+
+    def _latent_ctx(self, positions):
+        """`positions` attended a layer, over the latent layers: the
+        span attribute's value, counted in the registry too."""
+        n = int(positions) * len(self.cache.k)
+        self.eng._m_latent_ctx.inc(n)
+        return n
 
     def _moe_attrs(self, tokens, picks, loads):
         """Span attributes of one step, and the registry's share:
         `picks`/`loads` [L] are the program's counts a layer (local picks,
-        the largest held expert's), `tokens` the step's real tokens."""
+        the largest held expert's; 0 on a layer without experts),
+        `tokens` the step's real tokens."""
         e = self.eng
-        routed = int(tokens) * len(picks)
+        routed = int(tokens) * self.expert_layers
         e._m_moe_picks.inc(int(picks.sum()))
         e._m_moe_tokens.inc(routed)
         full_blocks, window_bytes = self.kv_held()
-        held = (full_blocks * e.block_size
-                * self.cache.bytes_per_token(False) + window_bytes)
         return {"moe_tokens": routed, "moe_local_picks": int(picks.sum()),
                 "moe_max_load": int(loads.sum()),
-                "kv_bytes_held": int(held),
+                "kv_bytes_held": int(self._paged_bytes(full_blocks)
+                                     + window_bytes),
                 "live_tokens": int(sum(
                     e._slot_pos[i] if r.prefill_done else r.prefill_pos
                     for i, r in enumerate(e._slot_req)

@@ -120,7 +120,7 @@ def _cdiv(a, b: int):
 
 
 def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
-                   has_scale, packed, has_start=False):
+                   has_scale, packed, has_start=False, v_cols=None):
     """One (slot, kv_block) grid step: every KV head's GQA query group
     attends to one compute block of `pps` cache pages, merged into the
     running flash state.
@@ -147,13 +147,24 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
     page is masked from below, and a slot's first step is the block that
     holds that page instead of block 0. Without it the program is the one
     above, unchanged.
+
+    `v_cols` (a latent pool, `paged_latent_decode`): there is no V pool —
+    a position's value is the first `v_cols` columns of its key's own row,
+    so each page is copied ONCE and its tile serves both matmuls (all its
+    columns for the scores, the first `v_cols` for the values); the refs
+    then come without `v_hbm` and `v_buf`, one DMA semaphore row is used,
+    and o/acc are `v_cols` wide.
     """
     if has_start:
         start_ref, *rest = rest
     if has_scale:
         ks_ref, vs_ref, *rest = rest
-    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, step_ref,
-     acc, m_s, l_s) = rest
+    if v_cols is None:
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, step_ref,
+         acc, m_s, l_s) = rest
+    else:
+        q_ref, k_hbm, o_ref, k_buf, sems, step_ref, acc, m_s, l_s = rest
+        v_buf = k_buf
     si, ji = pl.program_id(0), pl.program_id(1)
     n_s, n_j = pl.num_programs(0), pl.num_programs(1)
     hkv, rows, d = k_buf.shape[2:]
@@ -190,8 +201,9 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
             pid = tab_ref[s, first + i]
             do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, i],
                                      sems.at[0, buf]))
-            do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, i],
-                                     sems.at[1, buf]))
+            if v_cols is None:
+                do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, i],
+                                         sems.at[1, buf]))
             return c
         jax.lax.fori_loop(lo, n, page, None)
         return lo, n
@@ -290,7 +302,10 @@ def _decode_kernel(tab_ref, len_ref, *rest, scale, block_size, pps,
             l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
             if has_scale:
                 p = p * vs_row
-            pv = jax.lax.dot_general(p.astype(cdt), tokens(v_buf, buf, h),
+            v = tokens(v_buf, buf, h)
+            if v_cols is not None:
+                v = v[:, :v_cols]
+            pv = jax.lax.dot_general(p.astype(cdt), v,
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             acc[h] = acc[h] * alpha + pv
@@ -328,7 +343,9 @@ def paged_decode_attention_raw(q, k_cache, v_cache, block_tables, seq_lens,
 def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
                       k_scale=None, v_scale=None, kv_int4=False,
                       pages_per_step_=None, kv_start=None,
-                      name="paged_decode"):
+                      name="paged_decode", v_cols=None, scale=None):
+    """`v_cols`/`scale`: the latent pool's call (`v_cache` None; see the
+    kernel); `scale` defaults to 1 / sqrt(D)."""
     s_n, hq, d = q.shape
     n_blocks, hkv, rows, dc = k_cache.shape
     bs = rows
@@ -354,15 +371,20 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
     tables = jnp.pad(jnp.maximum(block_tables, 0).astype(jnp.int32),
                      ((0, 0), (0, n_blk * pps - pages)))
     lens = seq_lens.astype(jnp.int32)
-    scale = 1.0 / float(np.sqrt(d))
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(d))
     has_scale = k_scale is not None
     has_start = kv_start is not None
+    shared = v_cols is not None
+    d_out = v_cols if shared else d
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_size=bs,
                                pps=pps, has_scale=has_scale, packed=kv_int4,
-                               has_start=has_start)
+                               has_start=has_start, v_cols=v_cols)
 
-    qo_spec = pl.BlockSpec((1, hkv, gp, d), lambda s, j, *refs: (s, 0, 0, 0))
+    def qo_spec(width):
+        return pl.BlockSpec((1, hkv, gp, width),
+                            lambda s, j, *refs: (s, 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [tables, lens]
     if has_start:
@@ -372,17 +394,18 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
         # the per-block scales (tiny: S*P f32 in SMEM)
         args += [k_scale[tables].astype(jnp.float32),
                  v_scale[tables].astype(jnp.float32)]
+    pools = [k_cache] if shared else [k_cache, v_cache]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(args),
         grid=(s_n, n_blk),
-        in_specs=[qo_spec, pool_spec, pool_spec],
-        out_specs=[qo_spec],
+        in_specs=[qo_spec(d)] + [pool_spec] * len(pools),
+        out_specs=[qo_spec(d_out)],
         scratch_shapes=[
-            pltpu.VMEM((2, pps, hkv, rows, d), k_cache.dtype),
-            pltpu.VMEM((2, pps, hkv, rows, d), v_cache.dtype),
+            pltpu.VMEM((2, pps, hkv, rows, d), c.dtype) for c in pools
+        ] + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((hkv, gp, d), jnp.float32),
+            pltpu.VMEM((hkv, gp, d_out), jnp.float32),
             pltpu.VMEM((hkv, gp, 128), jnp.float32),
             pltpu.VMEM((hkv, gp, 128), jnp.float32),
         ],
@@ -390,10 +413,10 @@ def _paged_decode_x32(q, k_cache, v_cache, block_tables, seq_lens,
     out, = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((s_n, hkv, gp, d), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((s_n, hkv, gp, d_out), q.dtype)],
         interpret=_interpret(), name=name,
-    )(*args, q4, k_cache, v_cache)
-    return out[:, :, :g].reshape(s_n, hq, d)
+    )(*args, q4, *pools)
+    return out[:, :, :g].reshape(s_n, hq, d_out)
 
 
 # ------------------------------------------------------- XLA composition
@@ -445,11 +468,14 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_tables, seq_lens,
 # --------------------------------------------------------------- routing
 
 def decode_gate_reason(n_elems, dtype, platform, head_dim=None,
-                       block_size=None):
+                       block_size=None, latent_cols=None):
     """Why the decode router would decline this shape — ONE definition
     consulted by both `use_pallas_decode` and analysis D4, so the reported
     reason is the real one. Returns (reason, severity): legitimate gates
-    are notes, no-reason is the should-have-routed warning."""
+    are notes, no-reason is the should-have-routed warning.
+    `latent_cols`: the call is over a latent pool whose rows are
+    `head_dim` wide and whose first `latent_cols` columns are the values
+    (576 -> 640 and 512 for rkv 512, dr 64); such a pool is float."""
     from ..core.flags import flag
 
     if not flag("FLAGS_pallas_decode"):
@@ -463,6 +489,11 @@ def decode_gate_reason(n_elems, dtype, platform, head_dim=None,
                 "bandwidth saving)"), "note"
     if dtype is not None and dtype not in _SUPPORTED_DTYPES:
         return f"dtype {dtype} unsupported by the decode kernel", "note"
+    if latent_cols is not None and (latent_cols % 128
+                                    or dtype in ("int8", "int4")):
+        return (f"latent pool of {latent_cols} value columns in {dtype}: "
+                "the kernel slices the values off the key's tile at a "
+                "lane boundary (128) and reads no per-block scales"), "note"
     if head_dim is not None and head_dim % 128:
         return (f"head_dim {head_dim} not lane-aligned (128) — the cache "
                 "tile would need repacking"), "note"
@@ -508,3 +539,66 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens,
     return paged_decode_attention_xla(q, k_cache, v_cache, block_tables,
                                       seq_lens, k_scale, v_scale, kv_int4,
                                       kv_start)
+
+
+# ------------------------------------------------------ latent (MLA) pool
+# One row a position, shared by every query head: the first `v_cols`
+# columns are the latent c (the value, in the absorbed form), the next
+# ones the rotary key, the rest padding up to whole lanes
+# (`paged_cache.latent_row_width`). The query comes absorbed
+# (`latent_block.absorb_query`) and padded to the row's width, so a
+# score is ONE dot product over the row; what comes back is the sum of
+# P c in the latent space.
+
+def paged_latent_decode_raw(q, pool, block_tables, seq_lens, v_cols, scale,
+                            pages_per_step_=None):
+    """The Pallas kernel path: the `paged_decode` body with the ONE cache
+    row as the single "KV head" and all the query heads as its group (the
+    matmuls' M), each page copied once and its tile used for the scores
+    (all columns) and the values (the first `v_cols`). q [S, H, W]; pool
+    [N, 1, bs, W]; returns [S, H, v_cols]."""
+    with _x64_guard():
+        return _paged_decode_x32(q, pool, None, block_tables, seq_lens,
+                                 pages_per_step_=pages_per_step_,
+                                 name="paged_latent_decode", v_cols=v_cols,
+                                 scale=scale)
+
+
+def paged_latent_decode_xla(q, pool, block_tables, seq_lens, v_cols, scale):
+    """The gather + masked-softmax composition: the kernel's oracle and
+    the off-TPU route."""
+    s_n = q.shape[0]
+    _, _, bs, w = pool.shape
+    rows = pool[jnp.maximum(block_tables, 0)].reshape(s_n, -1, w)
+    t = rows.shape[1]
+    scores = jnp.einsum("shw,stw->sht", q, rows.astype(q.dtype),
+                        preferred_element_type=jnp.float32) \
+        * np.float32(scale)
+    valid = jnp.arange(t)[None, :] < seq_lens[:, None]
+    scores = jnp.where(valid[:, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("sht,str->shr", probs,
+                      rows[..., :v_cols].astype(q.dtype))
+
+
+def use_pallas_latent_decode(q, pool, block_tables, v_cols) -> bool:
+    s_n, hq, w = q.shape
+    bs = pool.shape[2]
+    _, sev = decode_gate_reason(
+        s_n * hq * block_tables.shape[1] * bs, str(pool.dtype),
+        jax.default_backend(), head_dim=w, block_size=bs,
+        latent_cols=v_cols)
+    return sev == "warning"
+
+
+def paged_latent_decode(q, pool, block_tables, seq_lens, v_cols, scale):
+    """Routed decode attention over a latent pool (kernel on TPU above
+    threshold, XLA composition everywhere else): row s of q [S, H, W]
+    attends positions [0, seq_lens[s]) of its table's pages; returns
+    sum_j P_j c_j, [S, H, v_cols]."""
+    if use_pallas_latent_decode(q, pool, block_tables, v_cols):
+        return paged_latent_decode_raw(q, pool, block_tables, seq_lens,
+                                       v_cols, scale)
+    return paged_latent_decode_xla(q, pool, block_tables, seq_lens, v_cols,
+                                   scale)

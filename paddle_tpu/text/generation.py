@@ -347,9 +347,9 @@ def generate(model, input_ids, max_new_tokens=32, max_length=None,
     if hasattr(model, "serving_arrays") and engine == "static":
         raise ValueError(
             f"the static single-program engine does not know {arch} (a "
-            "model that declares its layers one by one, of two cache "
-            "kinds): pass engine=\"paged\" or put the model behind "
-            "ServingEngine")
+            "model that declares its layers one by one, each with its "
+            "own kind of cache): pass engine=\"paged\" or put the model "
+            "behind ServingEngine")
     from ..core.flags import flag
 
     if weight_quant in (None, "none"):

@@ -12,5 +12,10 @@ from .cohere2_moe import (
     Cohere2MoeForCausalLM,
     cohere2_moe_tiny_config,
 )
+from .pangu_ultra_moe import (
+    PanguUltraMoEConfig,
+    PanguUltraMoEForCausalLM,
+    pangu_ultra_moe_tiny_config,
+)
 
 __all__ = [n for n in dir() if not n.startswith("_")]

@@ -47,6 +47,16 @@ class BlockSpec:
     num_shared_experts: int
     logit_scale: float
 
+    @property
+    def rope_dim(self) -> int:
+        return self.head_dim
+
+
+def cache_kind(kind: str) -> str:
+    """The layer state a layer of attention kind `kind` keeps: its own
+    name (full history, or a window)."""
+    return kind
+
 
 def layer_norm_f32(x, gain, eps):
     """(x - mean) / sqrt(var + eps) * gain, in float32."""
@@ -164,6 +174,11 @@ def logits(x, final_gain, embed, spec: BlockSpec):
     out = jax.lax.dot_general(h, embed, (((1,), (1,)), ((), ())),
                               preferred_element_type=_F32)
     return out * np.float32(spec.logit_scale)
+
+
+def head(x, params, spec: BlockSpec):
+    """`logits` from the programs' parameter dictionary (tied head)."""
+    return logits(x, params["final_ln"], params["embed"], spec)
 
 
 def forward_sequence(params, ids, spec: BlockSpec):

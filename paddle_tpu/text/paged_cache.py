@@ -418,13 +418,23 @@ class WindowRing:
 
 
 class LayeredKVCache:
-    """Pools for a model whose layers are of two kinds: one `[N, H_kv,
-    block_size, D]` array a layer for k and for v (no stacked layer axis:
-    each layer's pool is its own donated buffer, written in place by its
-    layer's scatter and read by its layer's kernel without a slice of a
-    stacked array being copied out and back). Full layers' pools have
-    `full_blocks` blocks, addressed through the `BlockAllocator`'s tables;
-    window layers' have the `WindowRing`'s.
+    """Pools for a model that declares its layers one by one: one array a
+    layer (no stacked layer axis: each layer's pool is its own donated
+    buffer, written in place by its layer's scatter and read by its
+    layer's kernel without a slice of a stacked array being copied out
+    and back). Three kinds of layer state:
+
+      full     `[N, H_kv, block_size, D]` for k and for v, `full_blocks`
+               blocks addressed through the `BlockAllocator`'s tables;
+      window   the same arrays with the `WindowRing`'s blocks;
+      latent   (`latent=True`: every layer) ONE array `[N, 1, block_size,
+               W]`, the latent and the rotary key of a position side by
+               side in one row, no heads and no separate V (`v` is empty);
+               `head_dim` is the row's width `W`, the model's `rkv + dr`
+               rounded up to whole lanes (`latent_row_width`), so that a
+               page is a lane-aligned tile the decode kernel copies whole.
+               With `H_kv` = 1 the row functions below (`append_rows`,
+               `scatter_chunk_rows`, `gather_context`) serve it unchanged.
 
     THREAD CONTRACT (D15): single-owner like `PagedKVCache`; `swap` is the
     one sanctioned mutation point."""
@@ -432,7 +442,8 @@ class LayeredKVCache:
     _thread_contract = ("swap",)
 
     def __init__(self, sliding, full_blocks: int, window_blocks: int,
-                 num_kv_heads: int, block_size: int, head_dim: int, dtype):
+                 num_kv_heads: int, block_size: int, head_dim: int, dtype,
+                 latent: bool = False):
         self.contract = ThreadContract("LayeredKVCache")
         if int(block_size) % 8:
             raise ValueError(
@@ -440,6 +451,10 @@ class LayeredKVCache:
                 "(sublane alignment of the (block_size, head_dim) tile)")
         #: per layer: True where the layer keeps a window only
         self.sliding = tuple(bool(x) for x in sliding)
+        self.latent = bool(latent)
+        if self.latent and (any(self.sliding) or int(num_kv_heads) != 1):
+            raise ValueError("a latent pool has one row a position and "
+                             "keeps the whole history")
 
         def pool(is_sliding):
             n = int(window_blocks) if is_sliding else int(full_blocks)
@@ -447,21 +462,31 @@ class LayeredKVCache:
                               int(head_dim)), dtype)
 
         self.k = tuple(pool(x) for x in self.sliding)
-        self.v = tuple(pool(x) for x in self.sliding)
+        self.v = () if self.latent else tuple(pool(x) for x in self.sliding)
 
     def swap(self, k, v):
         self.contract.check("swap")
         self.k, self.v = tuple(k), tuple(v)
 
     def bytes_per_token(self, is_sliding: bool) -> int:
-        """K and V bytes one position takes in all layers of one kind."""
+        """Bytes one position takes in all layers of one kind: K and V,
+        or a latent pool's one row (as stored: its padding is held
+        too)."""
         n = sum(1 for x in self.sliding if x == bool(is_sliding))
         _, hkv, _, d = self.k[0].shape
-        return 2 * n * hkv * d * self.k[0].dtype.itemsize
+        arrays = 1 if self.latent else 2
+        return arrays * n * hkv * d * self.k[0].dtype.itemsize
 
     @property
     def hbm_bytes(self) -> int:
         return sum(int(a.nbytes) for a in self.k + self.v)
+
+
+def latent_row_width(width: int) -> int:
+    """The stored width of a latent pool's row: `width` values rounded up
+    to whole 128-lane tiles (576 -> 640: 1,280 B a position in bfloat16
+    where the model's own values are 1,152)."""
+    return -(-int(width) // 128) * 128
 
 
 # ---------------------------------------------------- in-program updates

@@ -166,6 +166,21 @@ def test_paged_decode_two_kinds(for_chip, kind):
     assert ("paged_window_decode" in text) == windowed
 
 
+def test_paged_latent_decode(for_chip):
+    """What openpangu-ultra-moe-718b.serve-longdoc runs: 128 query heads
+    on ONE cached row of 640 (512 latent + 64 rotary + padding), 16 slots
+    x 1024 pages of 16; the values are the first 512 columns of the key's
+    own tile (a lane-aligned slice, each page copied once)."""
+    from paddle_tpu.ops.pallas_decode import paged_latent_decode_raw
+
+    text = for_chip(
+        lambda q, pool, tab, lens: paged_latent_decode_raw(
+            q, pool, tab, lens, 512, 192 ** -0.5),
+        ((16, 128, 640), BF16), ((1 + 16 * 1024, 1, 16, 640), BF16),
+        ((16, 1024), jnp.int32), ((16,), jnp.int32)).as_text()
+    assert "paged_latent_decode" in text
+
+
 # ------------------------------------- the dense serving step programs
 # `inference/engine.py`'s decode program and one chunk program as
 # mistral-7b.serve-chat runs them (32/8 heads x 128, FFN 14336, 16 slots x

@@ -425,7 +425,10 @@ REQUIRED_SERVING_METRICS = (
     # PR 31: expert layers and the two-kind cache (NOT in MUST_COUNT — a
     # dense model with one kind of layer state never moves them)
     "serving_moe_local_picks_total", "serving_moe_routed_tokens_total",
-    "serving_kv_window_bytes_held", "serving_kv_full_blocks_used")
+    "serving_kv_window_bytes_held", "serving_kv_full_blocks_used",
+    # PR 37: the latent (MLA) cache (NOT in MUST_COUNT — a model that
+    # caches K and V never moves them)
+    "serving_kv_latent_bytes_held", "serving_latent_ctx_tokens_total")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
