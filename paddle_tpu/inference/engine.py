@@ -1013,6 +1013,10 @@ class ServingEngine:
             "serving_latent_ctx_tokens_total", "cached positions the step "
             "programs attended, once a latent layer: each decode token's "
             "context and each chunk token's positions up to its own")
+        self._m_latent_kernel_blocks = reg.counter(
+            "serving_latent_chunk_kernel_blocks_total", "context blocks the "
+            "chunk programs attended through the latent chunk kernel, once "
+            "a latent layer (PR 38); zero where the composition ran")
         # config: explicit arg wins; the FLAGS_spec_decode string is the
         # flag-surface shorthand ("off" | "ngram" | "draft")
         from .speculative import SpecConfig, make_proposer

@@ -27,9 +27,10 @@ step (tokens, positions, tables, ring views) reaches a program as ONE
 int32 array: one transfer a program call, not one a value.
 
 A latent layer's decode is the absorbed form over its pages
-(`ops/pallas_decode.paged_latent_decode`); its chunk attention runs over
-blocks of the context, as many as reach the chunk's end, so one chunk
-program serves every context length.
+(`ops/pallas_decode.paged_latent_decode`); its chunk attention is the
+expanded form over blocks of the context, as many as reach the chunk's
+end, so one chunk program serves every context length: on the chip ONE
+kernel a layer (`ops/pallas_latent_chunk`), elsewhere the composition.
 
 `LayeredPrograms` is what `ServingEngine._run_chunk` and `_decode` ask
 for these programs and their operands (`engine._StackedPrograms` answers
@@ -45,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import pallas_decode as pd
+from ..ops import pallas_latent_chunk as plc
 from ..ops.pallas_decode import paged_decode_attention
 from ..text.models import latent_block as lb
 from ..text.models import parallel_block as pb
@@ -125,15 +127,33 @@ def _latent_decode_attend(blk, pools, li, tables, pos, rows, bs):
     return attend
 
 
+def _chunk_kernel_block(blk, dtype, q_rows, ctx_rows, width) -> int:
+    """Context positions a grid step of the chunk kernel takes for a chunk
+    of `q_rows` over a table of `ctx_rows` positions cached `width` wide,
+    0 where the composition runs instead (`plc.chunk_gate_reason` says
+    why). The program and the engine's `attn_kernel_blocks` both ask
+    here."""
+    if not plc.use_latent_chunk_kernel(
+            dtype, q_rows, ctx_rows, blk.num_heads, blk.qk_nope_dim,
+            blk.qk_rope_dim, blk.v_dim, blk.kv_rank, width):
+        return 0
+    return plc.context_block(q_rows, ctx_rows)
+
+
 def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
     """`attend` of a latent layer in a chunk step: scatter the chunk's
     rows through the table, then attend over the pages that hold
-    positions [0, start + C), `lb.CTX_BLOCK` positions a step (the whole
-    table where it holds fewer) with an online softmax: the steps run to
-    the chunk's end and no further (a traced count: ONE program for every
-    context length), and no [heads, C, context] tensor exists. In the
-    EXPANDED form: on the chip the absorbed one took 1.47 x as long at 6k
-    and at 14k of context (PERF.md section 6, PR 37)."""
+    positions [0, start + C) in the EXPANDED form (the absorbed one took
+    1.47 x as long as a composition: PERF.md section 6, PR 37), a block
+    of the context at a time with an online softmax: the steps run to the
+    chunk's end and no further (a traced count: ONE program for every
+    context length), and no [heads, C, context] tensor exists. On the
+    chip that is `plc.latent_chunk_attention_raw` over the table's rows
+    (a step's scores never leave VMEM; 0.26 ms a 512 positions a layer
+    where the composition takes 1.1: PERF.md section 6, PR 38); where
+    `_chunk_kernel_block` says 0, the composition
+    `lb.expanded_attention`, `lb.CTX_BLOCK` positions a step (the whole
+    table where it holds fewer), which is also the kernel's oracle."""
     block = min(lb.CTX_BLOCK, table.shape[0] * bs)
     per = block // bs
 
@@ -141,6 +161,11 @@ def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
         pools[li] = scatter_chunk_rows(
             pools[li], _latent_row(c, kr, pools[li]), start, true_end,
             table, bs)
+        if _chunk_kernel_block(blk, q_nope.dtype, q_nope.shape[0],
+                               table.shape[0] * bs, pools[li].shape[-1]):
+            rows = pools[li][table].reshape(table.shape[0] * bs, -1)
+            return plc.latent_chunk_attention_raw(
+                q_nope, q_rope, rows, start, w_kvb, blk.kv_rank, blk.scale)
         n_blocks = jnp.minimum(
             (start + q_nope.shape[0] + block - 1) // block,
             table.shape[0] // per)
@@ -399,6 +424,8 @@ class LayeredPrograms:
             start = run.attrs["start"]
             run.attrs["attn_pairs"] = self._latent_ctx(
                 n * start + n * (n + 1) // 2)
+            run.attrs["attn_kernel_blocks"] = self._chunk_kernel_blocks(
+                start, run.attrs["bucket"])
         return int(tok[0]) if is_last else None
 
     def decode(self, active, reqs, bucket, any_sample):
@@ -458,6 +485,18 @@ class LayeredPrograms:
         self.eng._m_kv_window.set(window_bytes)
         if self.latent:
             self.eng._m_kv_latent.set(self._paged_bytes(full_blocks))
+
+    def _chunk_kernel_blocks(self, start, c_bucket):
+        """Context blocks x latent layers the chunk kernel computed in a
+        chunk of `c_bucket` rows at `start` (its steps reach the padded
+        chunk's end, as the program's do); 0 where the composition ran."""
+        pool, ctx_rows = self.full_pool(), self.eng.pages * self.eng.block_size
+        block = _chunk_kernel_block(self.spec.block, pool.dtype, c_bucket,
+                                    ctx_rows, pool.shape[-1])
+        n = len(self.cache.k) * plc.live_blocks(
+            int(start), int(c_bucket), block, ctx_rows) if block else 0
+        self.eng._m_latent_kernel_blocks.inc(n)
+        return n
 
     def _latent_ctx(self, positions):
         """`positions` attended a layer, over the latent layers: the

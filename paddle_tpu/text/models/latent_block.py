@@ -186,7 +186,11 @@ def expanded_attention(q_nope, q_rope, rows_of, n_blocks, block, w_kvb,
                        q_pos, spec: BlockSpec):
     """The published form, a context block at a time: each cached latent
     goes through Wkvb to heads of keys and values. q_nope [Q, nh, dn],
-    q_rope [Q, nh, dr] -> [Q, nh, dv]."""
+    q_rope [Q, nh, dr] -> [Q, nh, dv]. This composition is what the
+    Layer's `forward` runs, what a serving chunk runs off the chip or at
+    widths the kernel's gate declines, and the oracle of the kernel that
+    runs a serving chunk on the chip (`ops/pallas_latent_chunk.py`: the
+    same products and precisions, the scores kept in VMEM; PR 38)."""
     wuk, wuv = _kvb(w_kvb, spec)
 
     def expand(c):
@@ -216,9 +220,11 @@ def absorbed_attention(q_nope, q_rope, rows_of, n_blocks, block, w_kvb,
     """The absorbed form over the same blocks: every head attends to the
     latent rows themselves (score = qlat . c + q_rope . kr, the sum of
     P c taken in the latent space), no row is expanded. What a decode
-    step does through `paged_latent_decode`; for a chunk it measured
-    slower than the expanded form (PERF.md section 6, PR 37) and is kept
-    as the tests' second form."""
+    step does through `paged_latent_decode`; for a chunk it measured 1.47
+    x slower than the expanded form, composition against composition
+    (PERF.md section 6, PR 37), and a chunk on the chip is now the expanded
+    form as ONE kernel (PR 38), 4 x faster again than that composition.
+    Kept as the tests' second form: the same numbers by another route."""
     olat = _attend_blocks(absorb_query(q_nope, w_kvb, spec), q_rope,
                           lambda c: (c, c), "qhr,br->hqb", "hqb,br->hqr",
                           spec.kv_rank, rows_of, n_blocks, block, q_pos,

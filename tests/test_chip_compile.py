@@ -51,9 +51,11 @@ def for_chip(monkeypatch, one_chip):
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from paddle_tpu.ops import (_pallas_common, pallas_attention,
-                                pallas_decode, pallas_norm, quantized)
+                                pallas_decode, pallas_latent_chunk,
+                                pallas_norm, quantized)
 
-    for mod in (pallas_attention, pallas_decode, pallas_norm, quantized):
+    for mod in (pallas_attention, pallas_decode, pallas_latent_chunk,
+                pallas_norm, quantized):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(_pallas_common, "interpret", lambda: False)
     was = (jax.config.jax_enable_compilation_cache,
@@ -179,6 +181,25 @@ def test_paged_latent_decode(for_chip):
         ((16, 128, 640), BF16), ((1 + 16 * 1024, 1, 16, 640), BF16),
         ((16, 1024), jnp.int32), ((16,), jnp.int32)).as_text()
     assert "paged_latent_decode" in text
+
+
+def test_latent_chunk_attention(for_chip):
+    """What a chunk of openpangu-ultra-moe-718b.serve-longdoc runs a
+    layer: 512 queries x 128 heads (128 + 64 deep, values 128) over the
+    rows of a 16384-position table, 640 wide, the chunk's first position
+    an operand; a step takes 8 heads over 1024 positions, the softmax
+    state and the expanded block in 24 MB of VMEM (over the default scope:
+    the call raises its limit)."""
+    from paddle_tpu.ops import pallas_latent_chunk as plc
+
+    assert plc.context_block(512, 16384) == 1024
+    text = for_chip(
+        lambda qn, qr, rows, start, w: plc.latent_chunk_attention_raw(
+            qn, qr, rows, start, w, 512, 192 ** -0.5),
+        ((512, 128, 128), BF16), ((512, 128, 64), BF16),
+        ((16384, 640), BF16), ((), jnp.int32),
+        ((512, 128 * 256), BF16)).as_text()
+    assert plc.NAME in text
 
 
 # ------------------------------------- the dense serving step programs

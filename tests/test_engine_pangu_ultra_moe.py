@@ -1,9 +1,11 @@
 """`ServingEngine(PanguUltraMoEForCausalLM(cfg))`: chunk prefill and then
 decode through the paged latent cache against the reference's full forward
 pass, `paged_latent_decode` in the interpreter against its XLA
-composition, the latent pool's bytes, the spans' new attributes, and the
-options this architecture refuses by name. Small sizes, seeded weights,
-the CPU backend (Pallas in the interpreter)."""
+composition, the chunk kernel (`latent_chunk_attn`) forced through the
+interpreter against the composition's tokens, the latent pool's bytes, the
+spans' new attributes, and the options this architecture refuses by name.
+Small sizes, seeded weights, the CPU backend (Pallas in the interpreter)."""
+import functools
 import time
 import unittest.mock as mock
 
@@ -19,6 +21,7 @@ from paddle_tpu.inference import engine as engine_mod
 from paddle_tpu.inference import layered
 from paddle_tpu.inference.engine import ServingEngine
 from paddle_tpu.ops import pallas_decode as pd
+from paddle_tpu.ops import pallas_latent_chunk as plc
 from paddle_tpu.text.models import latent_block as lb
 from paddle_tpu.text.paged_cache import blocks_for, latent_row_width
 
@@ -142,6 +145,7 @@ def test_the_latent_pool_holds_one_row_a_position(served):
     want = sum(p * (p + 1) // 2 + sum(p + j for j in range(1, n))
                for p, n in zip(PROMPTS, NEW))
     assert m["serving_latent_ctx_tokens_total"] == LAYERS * want
+    assert m["serving_latent_chunk_kernel_blocks_total"] == 0
     # two expert layers of three; 4 of 16 experts held, top-4: ~1 pick
     tokens = sum(PROMPTS) + sum(n - 1 for n in NEW)
     assert m["serving_moe_routed_tokens_total"] == 2 * tokens
@@ -196,6 +200,8 @@ def test_spans_carry_the_latent_attributes_and_one_transfer_a_decode():
     chunks = [r.attrs for r in runs if r.name == "serving.chunk.run"]
     assert [c["attn_pairs"] for c in chunks] == [
         LAYERS * (16 * 17 // 2), LAYERS * (14 * 16 + 14 * 15 // 2)]
+    # off the chip the composition runs: no block went through the kernel
+    assert [c["attn_kernel_blocks"] for c in chunks] == [0, 0]
     assert all("ctx_tokens" not in c for c in chunks)
     decodes = [r.attrs for r in runs if r.name == "serving.decode.run"]
     assert [d["ctx_tokens"] for d in decodes] == [
@@ -208,7 +214,89 @@ def test_spans_carry_the_latent_attributes_and_one_transfer_a_decode():
     assert builds["serving.chunk.build"][1:] == [2]
 
 
-# ------------------------------------------------------------ the kernel
+# ------------------------------------------------------ the chunk kernel
+
+@pytest.fixture
+def chunk_kernel_forced(monkeypatch):
+    """The chunk kernel in the interpreter where the router would keep the
+    composition (the CPU backend, widths of 16 and 8), a step of it 32
+    context positions so that a prompt's later chunks merge blocks. The
+    choice is no part of a program's key: the executables are dropped on
+    both sides."""
+    monkeypatch.setattr(plc, "use_latent_chunk_kernel",
+                        lambda *a, **k: True)
+    monkeypatch.setattr(plc, "_CTX_BLOCKS", (32,))
+    engine_mod._SERVING_EXECUTABLES.clear()
+    jax.clear_caches()
+    yield
+    engine_mod._SERVING_EXECUTABLES.clear()
+    jax.clear_caches()
+
+
+def test_the_chunk_kernel_serves_the_compositions_tokens(
+        served, chunk_kernel_forced):
+    """The same six requests with every chunk's attention through the
+    kernel: the tokens the composition served, which are the float32
+    reference's argmax at every position."""
+    model, w = tiny_model(21)
+    eng = _engine(model)
+    rids = [eng.add_request(p, max_new_tokens=n)
+            for p, n in zip(served["prompts"], NEW)]
+    eng.run()
+    for rid, prompt, toks in zip(rids, served["prompts"],
+                                 served["tokens"]):
+        np.testing.assert_array_equal(eng.completed[rid], toks)
+        gap, wrong = _served_logits_gap(w, prompt, eng.completed[rid])
+        assert wrong == 0 and gap == 0.0, (len(prompt), gap, wrong)
+
+
+def test_chunk_spans_count_the_kernels_blocks(chunk_kernel_forced):
+    """`attn_kernel_blocks` on every `serving.chunk.run`: the blocks of 32
+    positions that hold [0, start + 16), the padded chunk's end, once a
+    latent layer; host arithmetic beside `attn_pairs`."""
+    from paddle_tpu import obs
+
+    model, _ = tiny_model(5)
+    eng = _engine(model)
+    t0 = time.perf_counter()
+    eng.add_request(np.arange(70) % 256, max_new_tokens=2)
+    eng.run()
+    chunks = [r.attrs for r in obs.span_events()
+              if r.start >= t0 and r.name == "serving.chunk.run"]
+    assert [c["start"] for c in chunks] == [0, 16, 32, 48, 64]
+    assert [c["attn_kernel_blocks"] for c in chunks] == [
+        LAYERS * b for b in (1, 1, 2, 2, 3)]
+    assert all(c["attn_pairs"] > 0 for c in chunks)
+    total = eng.metrics()["serving_latent_chunk_kernel_blocks_total"]
+    assert total["samples"][0]["value"] == LAYERS * 9
+
+
+def test_the_chunk_program_names_its_kernel(chunk_kernel_forced):
+    """One `latent_chunk_attn` call a layer with five operands (the
+    chunk's first position, both query parts, Wkvb, the table's rows) in
+    the chunk program, with and without the first token: no hidden
+    fallback. On the composition's route the program holds no kernel."""
+    model, _ = tiny_model(1)
+    eng = _engine(model)
+    lp, c = eng.programs, eng.cache
+    ids = jnp.zeros((1, CHUNK), jnp.int32)
+    ints = jnp.zeros(4 + eng.pages, jnp.int32)
+    samp = eng._samp([], 1, False)
+
+    def calls(emit_token):
+        fn = functools.partial(layered._chunk_impl, lp.spec, False,
+                               emit_token, eng.pages)
+        return _kernel_calls(jax.make_jaxpr(fn)(
+            eng.params, ids, ints, c.k, c.v, samp, eng._key))
+
+    for emit_token in (False, True):
+        assert calls(emit_token) == {plc.NAME: [5] * LAYERS}
+    with mock.patch.object(plc, "use_latent_chunk_kernel",
+                           lambda *a, **k: False):
+        assert calls(False) == {}
+
+
+# ----------------------------------------------------- the decode kernel
 
 def _latent_case(dtype, seed=0):
     """5 slots over 12 pages of 16 rows, 256 wide with 128 value columns;

@@ -428,7 +428,10 @@ REQUIRED_SERVING_METRICS = (
     "serving_kv_window_bytes_held", "serving_kv_full_blocks_used",
     # PR 37: the latent (MLA) cache (NOT in MUST_COUNT — a model that
     # caches K and V never moves them)
-    "serving_kv_latent_bytes_held", "serving_latent_ctx_tokens_total")
+    "serving_kv_latent_bytes_held", "serving_latent_ctx_tokens_total",
+    # PR 38: the latent chunk kernel (zero off the chip and on any model
+    # whose chunk attention is a composition)
+    "serving_latent_chunk_kernel_blocks_total")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
