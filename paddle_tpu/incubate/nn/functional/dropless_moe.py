@@ -9,6 +9,9 @@ experts, as one chip of an expert-parallel deployment computes it:
     routed = sum_{e in T, e held here} g_e * Wd_e( silu(Wg_e h) * (Wu_e h) )
     shared = (1/S) * sum_j Wd'_j( silu(Wg'_j h) * (Wu'_j h) )
 
+A model may clamp every SwiGLU (`swiglu_limit` L > 0: silu(min(Wg h, L))
+* clip(Wu h, -L, L)); 0 leaves the products as written above.
+
 No capacity factor and no token dropped: every pick that lands on a held
 expert is computed. The weights `g_e` are normalised over all k picks,
 wherever they land, so the parts that the ranks of a deployment compute
@@ -69,14 +72,25 @@ def local_load(gate_mat, valid=None):
     return jnp.sum(per_expert), jnp.max(per_expert)
 
 
-def local_experts(h, gate_mat, w_gate, w_up, w_down):
+def clamp_gate(y, limit):
+    """A SwiGLU's gate projection capped at `limit` (0: as it is)."""
+    return jnp.minimum(y, limit) if limit else y
+
+
+def clamp_up(y, limit):
+    """A SwiGLU's up projection clipped to [-limit, limit] (0: as it is)."""
+    return jnp.clip(y, -limit, limit) if limit else y
+
+
+def local_experts(h, gate_mat, w_gate, w_up, w_down, limit=0.0):
     """sum_e gate_mat[:, e] * Wd_e(silu(Wg_e h) * (Wu_e h)) over the held
     experts, float32 [T, H]. Weights stacked [n, H, F], [n, H, F],
-    [n, F, H]."""
+    [n, F, H]; `limit`: the SwiGLU clamp."""
     with jax.named_scope("moe.experts"):
         def one(acc, xs):
             wg, wu, wd, g = xs
-            y = (jax.nn.silu(h @ wg) * (h @ wu)) @ wd
+            y = (jax.nn.silu(clamp_gate(h @ wg, limit))
+                 * clamp_up(h @ wu, limit)) @ wd
             return acc + g[:, None] * y.astype(_F32), None
 
         acc0 = jnp.zeros((h.shape[0], w_down.shape[-1]), _F32)
@@ -84,29 +98,35 @@ def local_experts(h, gate_mat, w_gate, w_up, w_down):
         return acc
 
 
-def shared_experts_mean(h, w_gate, w_up, w_down, num_shared: int):
+def shared_experts_mean(h, w_gate, w_up, w_down, num_shared: int,
+                        limit=0.0):
     """The mean of `num_shared` SwiGLU experts of equal width, stored side
     by side ([H, S*F], [H, S*F], [S*F, H]; expert j owns columns
-    [j*F, (j+1)*F)): one wide SwiGLU gives their sum."""
+    [j*F, (j+1)*F)): one wide SwiGLU gives their sum (the clamp acts on
+    each column alone, so it holds for the sum too)."""
     with jax.named_scope("moe.shared"):
-        y = (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+        y = (jax.nn.silu(clamp_gate(h @ w_gate, limit))
+             * clamp_up(h @ w_up, limit)) @ w_down
         return y / num_shared
 
 
 def moe_forward(h, lw, *, top_k, first_expert, num_local, num_shared,
-                valid=None, h_router=None, routed_scale=1.0):
+                valid=None, h_router=None, routed_scale=1.0,
+                swiglu_limit=0.0):
     """(routed + shared [T, H] float32, picks, max_load) for one layer's
     weights `lw` (keys `router`, `experts_gate|up|down`,
     `shared_gate|up|down`). `h_router`: the same activations before they
     were rounded to the matmuls' dtype, for the float32 router;
     `routed_scale`: the model's factor on the normalised gates (the
-    shared experts are not scaled)."""
+    shared experts are not scaled); `swiglu_limit`: the SwiGLU clamp of
+    every expert, routed and shared."""
     idx, gates = route_sigmoid_topk(h if h_router is None else h_router,
                                     lw["router"], top_k, routed_scale)
     gate_mat = local_gates(idx, gates, first_expert, num_local)
     picks, max_load = local_load(gate_mat, valid)
     routed = local_experts(h, gate_mat, lw["experts_gate"],
-                           lw["experts_up"], lw["experts_down"])
+                           lw["experts_up"], lw["experts_down"],
+                           swiglu_limit)
     shared = shared_experts_mean(h, lw["shared_gate"], lw["shared_up"],
-                                 lw["shared_down"], num_shared)
+                                 lw["shared_down"], num_shared, swiglu_limit)
     return routed + shared.astype(_F32), picks, max_load

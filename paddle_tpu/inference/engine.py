@@ -26,7 +26,9 @@ TPU-native design:
     decode; scatter, gather and `dense_block.attend_many` for chunk and
     verify; the sequence in hand for the whole-prompt prefill). A model
     that declares its layers one by one has `inference/layered.py`'s
-    programs around `parallel_block.block`, in the same idiom.
+    programs around its block module (`parallel_block`, `latent_block`,
+    `gated_delta_block`), in the same idiom; a linear-attention layer's
+    state lives a SLOT, and the first chunk of every request resets it.
   - Slot state entering the decode program is COMPACTED: tokens /
     positions / block-table rows / sampling params of the active slots
     are gathered into bucket-sized arrays (cheap — the KV pool itself is
@@ -1017,6 +1019,18 @@ class ServingEngine:
             "serving_latent_chunk_kernel_blocks_total", "context blocks the "
             "chunk programs attended through the latent chunk kernel, once "
             "a latent layer (PR 38); zero where the composition ran")
+        # ---- recurrent state beside the pages (PR 40): a linear layer's
+        # state a slot, reset by the first chunk of each request
+        self._m_state_slots = reg.counter(
+            "serving_state_slot_steps_total", "slot states the decode "
+            "programs advanced one token, once a linear-attention layer")
+        self._m_state_tokens = reg.counter(
+            "serving_state_tokens_total", "prompt tokens the chunk programs "
+            "ran through the recurrence, once a linear-attention layer")
+        self._m_state_bytes = reg.gauge(
+            "serving_recurrent_state_bytes_held", "bytes of recurrent state "
+            "(a float32 matrix a value head and the conv's last inputs) "
+            "the occupied slots hold, all linear-attention layers")
         # config: explicit arg wins; the FLAGS_spec_decode string is the
         # flag-surface shorthand ("off" | "ngram" | "draft")
         from .speculative import SpecConfig, make_proposer

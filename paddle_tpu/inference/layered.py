@@ -4,11 +4,14 @@ each; `config.block_spec()`: the block's sizes and each layer's kind):
 full-attention layers keep the whole history in pages the
 `BlockAllocator` hands out, sliding-window layers keep a `WindowRing` of
 the last `window + chunk` positions a slot, latent-attention layers keep
-ONE row a position (no heads, no V) in pages of the same tables.
+ONE row a position (no heads, no V) in pages of the same tables, and
+linear-attention layers a fixed-size state a SLOT (a float32 matrix a
+value head and the conv's last inputs), addressed by the slot's index.
 
 The block's mathematics is the model's block module's
-(`text/models/parallel_block.py`, `latent_block.py`; `_BLOCKS` finds it by
-the spec's type), the same `block` function the Layer's `forward` calls;
+(`text/models/parallel_block.py`, `latent_block.py`,
+`gated_delta_block.py`; `_BLOCKS` finds it by the spec's type), the same
+`block` function the Layer's `forward` calls;
 what these programs add is WHERE the cached state lives (`attend`:
 scatter the step's rows into the layer's pool through its table, attend
 over the pool), the head, the sampling, and the expert layer's counts.
@@ -32,6 +35,16 @@ expanded form over blocks of the context, as many as reach the chunk's
 end, so one chunk program serves every context length: on the chip ONE
 kernel a layer (`ops/pallas_latent_chunk`), elsewhere the composition.
 
+A linear layer's chunk reads its slot's state (zero where the chunk
+starts the prompt: a slot's state is reset by the first chunk of every
+request it admits), runs the chunk in the WY form, sub-chunks of
+`wy_chunk` (jnp; padded rows take beta = 0, g = 0 and leave the state as
+they found it), and writes the state and the conv's last real inputs
+back. Its decode advances every row of the bucket one token through
+`ops/pallas_gated_delta.gated_delta_decode`, in place, through the
+slot's index (a trailing int32 column of the packed operand, in the
+chunk's too); rows that hold no request name the trash slot.
+
 `LayeredPrograms` is what `ServingEngine._run_chunk` and `_decode` ask
 for these programs and their operands (`engine._StackedPrograms` answers
 for the dense architectures' stacked ones).
@@ -46,16 +59,30 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import pallas_decode as pd
+from ..ops import pallas_gated_delta as pgd
 from ..ops import pallas_latent_chunk as plc
 from ..ops.pallas_decode import paged_decode_attention
+from ..text.models import gated_delta_block as gd
 from ..text.models import latent_block as lb
 from ..text.models import parallel_block as pb
 from ..text.paged_cache import (TRASH_BLOCK, LayeredKVCache, WindowRing,
                                 append_rows, blocks_for, gather_context,
                                 latent_row_width, scatter_chunk_rows)
 
-#: the block module of a spec: `block`, `head`, `rope_tables`, `cache_kind`
-_BLOCKS = {pb.BlockSpec: pb, lb.BlockSpec: lb}
+#: the block module of a spec: `block`, `head`, `rope_for`, `cache_kind`,
+#: `expert_layer`
+_BLOCKS = {pb.BlockSpec: pb, lb.BlockSpec: lb, gd.BlockSpec: gd}
+
+
+def _mla(block):
+    """The spec a latent layer's attention reads (a hybrid model's
+    `mla`)."""
+    return getattr(block, "mla", block)
+
+
+def _kinds(block) -> list:
+    mod = _BLOCKS[type(block)]
+    return [mod.cache_kind(k) for k in block.layer_types]
 
 
 @dataclass(frozen=True)
@@ -88,17 +115,32 @@ _REFUSALS = {
                         "copy-on-write of a shared page",
         "chunked_prefill_tokens": "every prompt is prefilled by chunks (the "
                                   "per-layer programs have no whole-prompt "
-                                  "one)"}}
+                                  "one)"},
+    gd.RECURRENT: {
+        "kv_cache_dtype": "a linear layer's recurrent state is a float32 "
+                          "matrix a slot with no quantised form",
+        "spec_decode": "a recurrent state cannot be rolled back past a "
+                       "rejected draft token (the config's multi-token-"
+                       "prediction modules, its drafters, are not built "
+                       "either)",
+        "prefix_cache": "a cached prefix holds no recurrent state: a hit "
+                        "would need each linear layer's state after the "
+                        "prefix, which pages do not keep",
+        "chunked_prefill_tokens": "every prompt is prefilled by chunks (the "
+                                  "chunk program carries the slot's "
+                                  "recurrent state)"}}
 
 
 def refusals(block) -> dict:
     """{option: why `ServingEngine` refuses it} for a model of `block`'s
-    kinds of layer state."""
-    mod = _BLOCKS[type(block)]
-    kinds = {mod.cache_kind(k) for k in block.layer_types}
-    return {"weight_quant": "its step programs read the model's own "
-                            "buffers and have no dequantising matmul",
-            **_REFUSALS[lb.LATENT if lb.LATENT in kinds else pb.SLIDING]}
+    kinds of layer state: the reasons of every kind it keeps, joined."""
+    why = {"weight_quant": "its step programs read the model's own "
+                           "buffers and have no dequantising matmul"}
+    for kind in dict.fromkeys(_kinds(block)):
+        for option, reason in _REFUSALS.get(kind, {}).items():
+            why[option] = f"{why[option]}; {reason}" if option in why \
+                else reason
+    return why
 
 
 def _latent_row(c, kr, pool):
@@ -113,6 +155,8 @@ def _latent_decode_attend(blk, pools, li, tables, pos, rows, bs):
     """`attend` of a latent layer in a decode step: append each slot's
     row through its table, then the absorbed form over the slot's pages
     (`paged_latent_decode`: every head against the one cached row)."""
+    blk = _mla(blk)
+
     def attend(q_nope, q_rope, c, kr, w_kvb):
         pools[li] = append_rows(pools[li], _latent_row(c, kr, pools[li]),
                                 tables[rows, pos // bs],
@@ -133,6 +177,7 @@ def _chunk_kernel_block(blk, dtype, q_rows, ctx_rows, width) -> int:
     0 where the composition runs instead (`plc.chunk_gate_reason` says
     why). The program and the engine's `attn_kernel_blocks` both ask
     here."""
+    blk = _mla(blk)
     if not plc.use_latent_chunk_kernel(
             dtype, q_rows, ctx_rows, blk.num_heads, blk.qk_nope_dim,
             blk.qk_rope_dim, blk.v_dim, blk.kv_rank, width):
@@ -156,6 +201,7 @@ def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
     table where it holds fewer), which is also the kernel's oracle."""
     block = min(lb.CTX_BLOCK, table.shape[0] * bs)
     per = block // bs
+    blk = _mla(blk)
 
     def attend(q_nope, q_rope, c, kr, w_kvb):
         pools[li] = scatter_chunk_rows(
@@ -179,6 +225,52 @@ def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
     return attend
 
 
+def _recurrent_decode_attend(blk, ks, vs, li, slots):
+    """`attend` of a linear layer in a decode step: row b's conv runs
+    over its slot's last K - 1 inputs and its token's (which then join
+    the slot's), and `gated_delta_decode` advances the slot's state one
+    token in place. [B, nv, dv]."""
+    def attend(mixed, b, a, lw):
+        x_ext = jnp.concatenate([vs[li][slots], mixed[:, None, :]], axis=1)
+        vs[li] = vs[li].at[slots].set(x_ext[:, 1:])
+        q, k, v, beta, g = (y[:, 0] for y in gd.delta_inputs(
+            x_ext, b[:, None], a[:, None], lw, blk))
+        o, ks[li] = pgd.gated_delta_decode(ks[li], slots, q, k, v, beta,
+                                           jnp.exp(g))
+        return o
+    return attend
+
+
+def _slot_state(pool, slot, fresh):
+    """A slot's state as a chunk starts from it: zero where the chunk
+    starts the prompt (`fresh`), whatever the slot's last request left."""
+    return jnp.where(fresh, 0.0, pool[slot])
+
+
+def _recurrent_chunk_attend(blk, ks, vs, li, slot, start, true_end,
+                            valid):
+    """`attend` of a linear layer in a chunk step: the slot's two states,
+    zero where the chunk starts the prompt; the conv over them and the
+    chunk's inputs, the WY form over the chunk, both states written back
+    (the conv's: the last K - 1 REAL inputs). Padded rows take beta = 0
+    and g = 0: the state passes them unchanged."""
+    keep = blk.conv_kernel - 1
+
+    def attend(mixed, b, a, lw):
+        fresh = start == 0
+        x_ext = jnp.concatenate([_slot_state(vs[li], slot, fresh), mixed])
+        vs[li] = vs[li].at[slot].set(jax.lax.dynamic_slice_in_dim(
+            x_ext, true_end - start, keep))
+        q, k, v, beta, g = gd.delta_inputs(x_ext, b, a, lw, blk)
+        beta = jnp.where(valid[:, None], beta, 0.0)
+        g = jnp.where(valid[:, None], g, 0.0)
+        s0 = _slot_state(ks[li], slot, fresh)
+        o, s = gd.chunked(s0, q, k, v, beta, g, blk.wy_chunk)
+        ks[li] = ks[li].at[slot].set(s)
+        return o
+    return attend
+
+
 def _sample(lg, any_sample, samp, key):
     from .engine import _sample_batched
 
@@ -193,18 +285,21 @@ def _sample(lg, any_sample, samp, key):
 def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
                  vs, samp, key):
     """ONE decode step for a compacted slot bucket. `ints` [B, 4 + pages
-    + R], a row a slot (`LayeredPrograms.decode` packs it): its token,
-    its position, 1 for a live row (0: padding, whose tables are the
-    trash block), its ring view's base page, the full layers' block table
-    [pages] and the ring view [R] (R = 0 for a model without window
-    layers). ks/vs: one pool a layer (a latent model: its one pool a layer
-    in `ks`, `vs` empty). Returns (next tokens [B], local picks [L],
+    + R (+ 1)], a row a slot (`LayeredPrograms.decode` packs it): its
+    token, its position, 1 for a live row (0: padding, whose tables are
+    the trash block), its ring view's base page, the full layers' block
+    table [pages], the ring view [R] (R = 0 for a model without window
+    layers) and, for a model with linear layers, its slot (padding: the
+    trash slot). ks/vs: a layer's arrays (`LayeredKVCache`; None where
+    the kind has no second). Returns (next tokens [B], local picks [L],
     largest expert load [L], ks, vs, key)."""
     blk, bs = spec.block, spec.block_size
     mod = _BLOCKS[type(blk)]
+    kinds = _kinds(blk)
+    end = ints.shape[1] - (gd.RECURRENT in kinds)
     tok, pos, valid, wbase = (ints[:, i] for i in range(4))
-    ftables = ints[:, 4:ints.shape[1] - spec.window_pages]
-    wtables = ints[:, ints.shape[1] - spec.window_pages:]
+    ftables = ints[:, 4:end - spec.window_pages]
+    wtables = ints[:, end - spec.window_pages:end]
     rows = jnp.arange(ints.shape[0])
     x = params["embed"][tok]
     rope = (params["rope_cos"][pos], params["rope_sin"][pos])
@@ -216,9 +311,11 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
         sliding = kind == pb.SLIDING
         tables, p = (wtables, wpos) if sliding else (ftables, pos)
 
-        if mod.cache_kind(kind) == lb.LATENT:
+        if kinds[li] == lb.LATENT:
             attend = _latent_decode_attend(blk, ks, li, ftables, pos, rows,
                                            bs)
+        elif kinds[li] == gd.RECURRENT:
+            attend = _recurrent_decode_attend(blk, ks, vs, li, ints[:, -1])
         else:
             def attend(q, k, v):
                 bid = tables[rows, p // bs]
@@ -246,25 +343,28 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
 def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
                 ctx_pages: int, params, ids, ints, ks, vs, samp, key):
     """Prefill ONE chunk of one prompt: positions [start, true_end) of
-    ids [1, C] (the rest is padding). `ints` [4 + pages + R]
+    ids [1, C] (the rest is padding). `ints` [4 + pages + R (+ 1)]
     (`LayeredPrograms.chunk` packs it): start, true_end, last_idx, the
-    ring view's base page, the full layers' block table and the ring
-    view. Each layer scatters the chunk's K/V
-    through its table and attends every chunk position over what its kind
+    ring view's base page, the full layers' block table, the ring view
+    and, for a model with linear layers, the slot. Each layer scatters
+    the chunk's K/V through its table and attends every chunk position over what its kind
     sees: a full layer the first `ctx_pages` (static, bucketed) pages of
     `ftable` under `kv <= q`, a window layer the whole ring view under
     `0 <= q - kv < window`. Scores are computed one KV head's group at a
     time (`parallel_block.grouped_attention`), so the largest temporary
     is [heads a KV head, C, context] in float32. A latent layer:
     `_latent_chunk_attend` (`ctx_pages` is then the whole table and not
-    read). `emit_token` (static):
+    read); a linear layer: `_recurrent_chunk_attend`. `emit_token`
+    (static):
     the prompt's final chunk samples the first token from chunk row
     `last_idx`."""
     blk, bs = spec.block, spec.block_size
     mod = _BLOCKS[type(blk)]
+    kinds = _kinds(blk)
+    end = ints.shape[0] - (gd.RECURRENT in kinds)
     start, true_end, last_idx, wbase = (ints[i] for i in range(4))
-    ftable = ints[4:ints.shape[0] - spec.window_pages]
-    wtable = ints[ints.shape[0] - spec.window_pages:]
+    ftable = ints[4:end - spec.window_pages]
+    wtable = ints[end - spec.window_pages:end]
     c = ids.shape[1]
     pos = start + jnp.arange(c)
     x = params["embed"][ids[0]]
@@ -278,9 +378,12 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
         table, shift, pages = ((wtable, wbase * bs, spec.window_pages)
                                if sliding else (ftable, 0, ctx_pages))
 
-        if mod.cache_kind(kind) == lb.LATENT:
+        if kinds[li] == lb.LATENT:
             attend = _latent_chunk_attend(blk, ks, li, ftable, start,
                                           true_end, pos, bs)
+        elif kinds[li] == gd.RECURRENT:
+            attend = _recurrent_chunk_attend(blk, ks, vs, li, ints[-1],
+                                             start, true_end, valid)
         else:
             def attend(q, k, v):
                 ks[li] = scatter_chunk_rows(ks[li], k, start - shift,
@@ -328,9 +431,11 @@ class LayeredPrograms:
     def __init__(self, eng, block, full_blocks: int, dtype):
         self.eng = eng
         mod = _BLOCKS[type(block)]
-        kinds = [mod.cache_kind(k) for k in block.layer_types]
+        kinds = _kinds(block)
         self.latent = lb.LATENT in kinds
-        self.expert_layers = sum(k != lb.DENSE for k in block.layer_types)
+        self.latent_layers = kinds.count(lb.LATENT)
+        self.linear_layers = kinds.count(gd.RECURRENT)
+        self.expert_layers = sum(map(mod.expert_layer, block.layer_types))
         sliding = [k == pb.SLIDING for k in kinds]
         self.ring = WindowRing(eng.max_slots, block.window,
                                eng.chunk_tokens, eng.block_size) \
@@ -338,9 +443,9 @@ class LayeredPrograms:
         self.spec = LayeredSpec(
             block=block, block_size=eng.block_size,
             window_pages=self.ring.pages if self.ring else 0)
-        cos, sin = mod.rope_tables(eng.max_model_len, block.rope_dim,
-                                   block.rope_theta)
+        cos, sin = mod.rope_for(block, eng.max_model_len)
         eng.params.update(rope_cos=jnp.asarray(cos), rope_sin=jnp.asarray(sin))
+        heads, width = block.num_kv_heads, block.head_dim
         if self.latent:
             step = min(lb.CTX_BLOCK, eng.max_model_len)
             if step % eng.block_size or eng.max_model_len % step:
@@ -348,17 +453,22 @@ class LayeredPrograms:
                     f"a chunk attends its latent context {step} positions "
                     f"a step: kv_block_size {eng.block_size} must divide "
                     f"that, and that max_model_len {eng.max_model_len}")
-            self.cache = LayeredKVCache(
-                sliding, full_blocks, 0, 1, eng.block_size,
-                latent_row_width(block.latent_width), dtype, latent=True)
-        else:
-            self.cache = LayeredKVCache(
-                sliding, full_blocks, self.ring.num_blocks,
-                block.num_kv_heads, eng.block_size, block.head_dim, dtype)
+            heads, width = 1, latent_row_width(_mla(block).latent_width)
+        recurrent = {}
+        if self.linear_layers:
+            # the engine's slots and the trash slot (index max_slots)
+            recurrent = dict(slots=eng.max_slots + 1,
+                             state_shape=block.state_shape,
+                             conv_shape=block.conv_shape)
+        self.cache = LayeredKVCache(
+            kinds, full_blocks, self.ring.num_blocks if self.ring else 0,
+            heads, eng.block_size, width, dtype, **recurrent)
 
     def full_pool(self):
         """One full-history layer's pool (shape and dtype)."""
-        return self.cache.k[self.cache.sliding.index(False)]
+        first = next(i for i, k in enumerate(self.cache.kinds)
+                     if k in (pb.FULL, lb.LATENT))
+        return self.cache.k[first]
 
     def kv_steps(self, bucket):
         """Grid steps a layer of the decode kernel over a full-history
@@ -366,7 +476,7 @@ class LayeredPrograms:
         e, pool = self.eng, self.full_pool()
         if not self.latent:
             return e._kv_steps(bucket)
-        blk = self.spec.block
+        blk = _mla(self.spec.block)
         q = jax.ShapeDtypeStruct((bucket, blk.num_heads, pool.shape[-1]),
                                  pool.dtype)
         tables = jax.ShapeDtypeStruct((bucket, e.pages), jnp.int32)
@@ -404,7 +514,8 @@ class LayeredPrograms:
         wrow, wbase = self._ring_view(slot, start + n - 1)
         ints = np.concatenate([
             np.array([start, start + n, req.prompt.size - 1 - start, wbase],
-                     np.int32), e._tables[slot], wrow])
+                     np.int32), e._tables[slot], wrow,
+            np.full(int(bool(self.linear_layers)), slot, np.int32)])
         return chunk_step, 4, (
             self.spec, sample, is_last, ctx_pages, e.params,
             e._put(ids), e._put(ints), c.k, c.v,
@@ -426,20 +537,31 @@ class LayeredPrograms:
                 n * start + n * (n + 1) // 2)
             run.attrs["attn_kernel_blocks"] = self._chunk_kernel_blocks(
                 start, run.attrs["bucket"])
+        if self.linear_layers:
+            run.attrs["state_tokens"] = n * self.linear_layers
+            self.eng._m_state_tokens.inc(run.attrs["state_tokens"])
         return int(tok[0]) if is_last else None
+
+    def _ints_width(self):
+        """Columns of a decode row: token, position, live, ring base,
+        the table, the ring view, and the slot where there is a state."""
+        return (4 + self.eng.pages + self.spec.window_pages
+                + bool(self.linear_layers))
 
     def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.cache
         n = len(active)
-        ints = np.zeros((bucket, 4 + e.pages + self.spec.window_pages),
-                        np.int32)
+        ints = np.zeros((bucket, self._ints_width()), np.int32)
         ints[:, 4:] = TRASH_BLOCK
         ints[:n, 0] = [r.tokens[-1] for r in reqs]
         ints[:n, 1], ints[:n, 2] = e._slot_pos[active], 1
         ints[:n, 4:4 + e.pages] = e._tables[active]
         for j, slot in enumerate(active):
-            ints[j, 4 + e.pages:], ints[j, 3] = self._ring_view(
-                slot, e._slot_pos[slot])
+            ints[j, 4 + e.pages:4 + e.pages + self.spec.window_pages], \
+                ints[j, 3] = self._ring_view(slot, e._slot_pos[slot])
+        if self.linear_layers:
+            ints[:, -1] = e.max_slots                 # the trash slot
+            ints[:n, -1] = active
         return decode_step, 2, (
             self.spec, any_sample, e.params, e._put(ints), c.k, c.v,
             e._samp(reqs, bucket - n, any_sample), e._key)
@@ -455,12 +577,15 @@ class LayeredPrograms:
                     if r is not None and r.prefill_done]
             run.attrs["ctx_tokens"] = self._latent_ctx(
                 int(e._slot_pos[live].sum()) + len(live))
+        if self.linear_layers:
+            run.attrs["state_slots"] = n_active * self.linear_layers
+            run.attrs["state_bytes_held"] = self._state_bytes()
+            self.eng._m_state_slots.inc(run.attrs["state_slots"])
         return np.asarray(nxt)
 
     def decode_jaxpr(self, bucket, samp):
         e, c = self.eng, self.cache
-        ints = jnp.zeros((bucket, 4 + e.pages + self.spec.window_pages),
-                         jnp.int32)
+        ints = jnp.zeros((bucket, self._ints_width()), jnp.int32)
         fn = functools.partial(_decode_impl, self.spec, False)
         return jax.make_jaxpr(fn)(e.params, ints, c.k, c.v, samp, e._key)
 
@@ -485,6 +610,13 @@ class LayeredPrograms:
         self.eng._m_kv_window.set(window_bytes)
         if self.latent:
             self.eng._m_kv_latent.set(self._paged_bytes(full_blocks))
+        if self.linear_layers:
+            self.eng._m_state_bytes.set(self._state_bytes())
+
+    def _state_bytes(self):
+        """Bytes of recurrent state the occupied slots hold, all linear
+        layers (a slot's is held whole from admission to its end)."""
+        return self.eng.num_active * self.cache.state_bytes_per_slot()
 
     def _chunk_kernel_blocks(self, start, c_bucket):
         """Context blocks x latent layers the chunk kernel computed in a
@@ -493,7 +625,7 @@ class LayeredPrograms:
         pool, ctx_rows = self.full_pool(), self.eng.pages * self.eng.block_size
         block = _chunk_kernel_block(self.spec.block, pool.dtype, c_bucket,
                                     ctx_rows, pool.shape[-1])
-        n = len(self.cache.k) * plc.live_blocks(
+        n = self.latent_layers * plc.live_blocks(
             int(start), int(c_bucket), block, ctx_rows) if block else 0
         self.eng._m_latent_kernel_blocks.inc(n)
         return n
@@ -501,7 +633,7 @@ class LayeredPrograms:
     def _latent_ctx(self, positions):
         """`positions` attended a layer, over the latent layers: the
         span attribute's value, counted in the registry too."""
-        n = int(positions) * len(self.cache.k)
+        n = int(positions) * self.latent_layers
         self.eng._m_latent_ctx.inc(n)
         return n
 

@@ -17,5 +17,6 @@ from .pangu_ultra_moe import (
     PanguUltraMoEForCausalLM,
     pangu_ultra_moe_tiny_config,
 )
+from .gigachat3_5 import GigaChat35Config, GigaChat35ForCausalLM
 
 __all__ = [n for n in dir() if not n.startswith("_")]

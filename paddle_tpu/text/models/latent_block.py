@@ -16,7 +16,14 @@ chunk-prefill and decode programs (`inference/layered.py`).
     x'     = x1 + RMS(f; g_post_ffn)                sandwich norm
 
 Layers of one model differ in their FFN (`DENSE`, `EXPERTS`), not in
-their cache: every layer keeps the latent row of every position. What
+their cache: every layer keeps the latent row of every position.
+
+Static options that openPangu leaves off and GigaChat3.5's MLA layers
+turn on (PR 40; `gated_delta_block.py` calls this block for them):
+RoPE pairs (2i, 2i + 1) with YaRN frequencies, the softmax scale times
+mscale^2, the attention's output gated per channel by sigmoid(h Wgate)
+before Wo, every norm gain zero-centred ((1 + w)), and the SwiGLU's gate
+capped and its up-projection clipped at `swiglu_limit`. What
 differs between the callers is where those rows live, and that is the
 `attend` argument: `attend(q_nope [T, nh, dn], q_rope [T, nh, dr],
 c [T, rkv], kr [T, dr], w_kvb [rkv, nh * (dn + dv)]) -> [T, nh, dv]`.
@@ -37,7 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...incubate.nn.functional.dropless_moe import moe_forward
+from ...incubate.nn.functional.dropless_moe import (clamp_gate, clamp_up,
+                                                    moe_forward)
 
 _F32 = jnp.float32
 DENSE = "dense"
@@ -68,6 +76,18 @@ class BlockSpec:
     num_local_experts: int
     num_shared_experts: int
     routed_scale: float
+    #: RoPE pairs (2i, 2i + 1) (openPangu: (i, i + dr / 2))
+    rope_interleave: bool = False
+    #: YaRN (factor, original positions, beta_fast, beta_slow); () none
+    rope_yarn: tuple = ()
+    #: the softmax scale's factor is mscale ** 2
+    mscale: float = 1.0
+    #: a = a * sigmoid(h Wgate) before Wo
+    gated_attention: bool = False
+    #: every norm's gain is (1 + w)
+    zero_centred: bool = False
+    #: gate <= limit and |up| <= limit in every SwiGLU; 0: no clamp
+    swiglu_limit: float = 0.0
 
     @property
     def latent_width(self) -> int:
@@ -89,12 +109,23 @@ class BlockSpec:
 
     @property
     def scale(self) -> float:
-        return 1.0 / float(np.sqrt(self.qk_nope_dim + self.qk_rope_dim))
+        return 1.0 / float(np.sqrt(self.qk_nope_dim + self.qk_rope_dim)) \
+            * self.mscale ** 2
 
 
 def cache_kind(kind: str) -> str:
     """The layer state a layer of FFN kind `kind` keeps."""
     return LATENT
+
+
+def expert_layer(kind: str) -> bool:
+    return kind == EXPERTS
+
+
+def gain(w, spec: BlockSpec):
+    """A norm's gain from its weight: w, or (1 + w) in float32 where the
+    model's gains are zero-centred."""
+    return w.astype(_F32) + 1.0 if spec.zero_centred else w
 
 
 def rms_norm_f32(x, gain, eps):
@@ -114,14 +145,50 @@ def sandwich_add(x, y, gain, eps):
     return (x.astype(_F32) + rms_norm_f32(y, gain, eps)).astype(x.dtype)
 
 
-def rope_tables(positions: int, dr: int, theta: float):
+def yarn_frequencies(inv, dr: int, theta: float, factor: float,
+                     original: int, beta_fast: float, beta_slow: float):
+    """YaRN's blend of the rotary frequencies `inv` [dr / 2]: pairs that
+    turn fewer than `beta_slow` times over the `original` positions are
+    interpolated (divided by `factor`), more than `beta_fast` times kept,
+    a linear ramp between (DeepSeek-V3's `yarn_find_correction_range`)."""
+    def dim(turns):
+        return (dr * np.log(original / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(dim(beta_fast)), 0)
+    high = min(np.ceil(dim(beta_slow)), dr - 1)
+    ramp = np.clip((np.arange(dr // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return inv / factor * ramp + inv * (1 - ramp)
+
+
+def rope_tables(positions: int, dr: int, theta: float, yarn: tuple = ()):
     """(cos, sin) float32 [positions, dr] for the rotate-half rotation,
     angles worked out in float64; pair i's angle sits at columns i and
-    i + dr / 2."""
+    i + dr / 2. `yarn`: `BlockSpec.rope_yarn`."""
     inv = 1.0 / (theta ** (np.arange(0, dr, 2, dtype=np.float64) / dr))
+    if yarn:
+        inv = yarn_frequencies(inv, dr, theta, *yarn)
     ang = np.outer(np.arange(positions, dtype=np.float64), inv)
     ang = np.concatenate([ang, ang], axis=-1)
     return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
+
+
+def rope_for(spec: BlockSpec, positions: int):
+    """The programs' rotary tables for `positions` positions."""
+    return rope_tables(positions, spec.rope_dim, spec.rope_theta,
+                       spec.rope_yarn)
+
+
+def apply_rope(x, cos, sin, spec: BlockSpec):
+    """RoPE on x [T, ..., dr] by the spec's pairing. Pairs (2i, 2i + 1)
+    are taken apart into halves first (evens, then odds) and rotated as
+    pairs (i, i + dr / 2): the same rotation in another column order,
+    and q and the cached key share that order, so every score is the
+    interleaved one."""
+    if spec.rope_interleave:
+        lead = x.shape[:-1]
+        x = x.reshape(lead + (-1, 2)).swapaxes(-1, -2).reshape(x.shape)
+    return rope_half(x, cos, sin)
 
 
 def rope_half(x, cos, sin):
@@ -249,11 +316,12 @@ def attend_sequence(spec: BlockSpec, form=expanded_attention):
 
 # ----------------------------------------------------------------- block
 
-def dense_ffn(h, lw):
-    """Wd(silu(Wg h) * (Wu h)), float32 [T, H]."""
+def dense_ffn(h, lw, limit=0.0):
+    """Wd(silu(Wg h) * (Wu h)), float32 [T, H]; `limit`: the SwiGLU clamp
+    (`dropless_moe.clamp_gate`, `clamp_up`)."""
     with jax.named_scope("ffn.dense"):
-        return ((jax.nn.silu(h @ lw["gate"]) * (h @ lw["up"]))
-                @ lw["down"]).astype(_F32)
+        return ((jax.nn.silu(clamp_gate(h @ lw["gate"], limit))
+                 * clamp_up(h @ lw["up"], limit)) @ lw["down"]).astype(_F32)
 
 
 def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
@@ -261,32 +329,45 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
     """One layer over a block of tokens x [T, H]. `lw`: the layer's
     arrays (the four sandwich gains `ln_in`, `ln_post_attn`, `ln_pre_ffn`,
     `ln_post_ffn`; `q_a`, `q_a_ln`, `q_b`, `kv_a`, `kv_a_ln`, `kv_b`,
-    `o`; `gate`/`up`/`down` of a dense layer or the expert layer's);
-    `rope`: (cos, sin) [T, dr] at the tokens' positions; `valid` [T] bool
-    leaves padded rows out of the expert counts. Returns (x', picks,
-    max_load), the counts 0 on a dense layer."""
+    `o`, `attn_gate` where the attention is gated; `gate`/`up`/`down` of
+    a dense layer or the expert layer's); `rope`: (cos, sin) [T, dr] at
+    the tokens' positions; `valid` [T] bool leaves padded rows out of the
+    expert counts. Returns (x', picks, max_load), the counts 0 on a dense
+    layer."""
     t = x.shape[0]
     nh, dn, dr = spec.num_heads, spec.qk_nope_dim, spec.qk_rope_dim
     eps = spec.eps
-    h = rms_norm(x, lw["ln_in"], eps)
+    h = rms_norm(x, gain(lw["ln_in"], spec), eps)
     with jax.named_scope("attn.latent.q"):
-        cq = rms_norm(h @ lw["q_a"], lw["q_a_ln"], eps)
+        cq = rms_norm(h @ lw["q_a"], gain(lw["q_a_ln"], spec), eps)
         q = (cq @ lw["q_b"]).reshape(t, nh, dn + dr)
-        q_nope, q_rope = q[..., :dn], rope_half(q[..., dn:], *rope)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], *rope, spec)
     with jax.named_scope("attn.latent.kv"):
         ckv = h @ lw["kv_a"]
-        c = rms_norm(ckv[:, :spec.kv_rank], lw["kv_a_ln"], eps)
-        kr = rope_half(ckv[:, spec.kv_rank:], *rope)
+        c = rms_norm(ckv[:, :spec.kv_rank], gain(lw["kv_a_ln"], spec), eps)
+        kr = apply_rope(ckv[:, spec.kv_rank:], *rope, spec)
     with jax.named_scope("attn.latent"):
         a = attend(q_nope, q_rope, c, kr, lw["kv_b"])
+    if spec.gated_attention:
+        with jax.named_scope("attn.latent.gate"):
+            a = (a.astype(_F32) * jax.nn.sigmoid(
+                (h @ lw["attn_gate"]).astype(_F32)).reshape(a.shape)
+                 ).astype(a.dtype)
     a = a.reshape(t, nh * spec.v_dim) @ lw["o"]
-    x1 = sandwich_add(x, a, lw["ln_post_attn"], eps)
+    x1 = sandwich_add(x, a, gain(lw["ln_post_attn"], spec), eps)
+    return ffn_sublayer(x1, lw, spec, kind, valid)
+
+
+def ffn_sublayer(x1, lw, spec: BlockSpec, kind: str, valid=None):
+    """x1 + RMS(ffn(RMS(x1; g_pre_ffn)); g_post_ffn) with the layer's FFN
+    kind: (x', picks, max_load)."""
+    eps = spec.eps
     # the router reads the norm before it is rounded to the matmuls'
     # dtype: a pick that flips on that rounding swaps a whole expert
-    h32 = rms_norm_f32(x1, lw["ln_pre_ffn"], eps)
-    h2 = h32.astype(x.dtype)
+    h32 = rms_norm_f32(x1, gain(lw["ln_pre_ffn"], spec), eps)
+    h2 = h32.astype(x1.dtype)
     if kind == DENSE:
-        ffn = dense_ffn(h2, lw)
+        ffn = dense_ffn(h2, lw, spec.swiglu_limit)
         picks = max_load = jnp.zeros((), jnp.int32)
     else:
         ffn, picks, max_load = moe_forward(
@@ -294,15 +375,15 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
             first_expert=spec.first_expert,
             num_local=spec.num_local_experts,
             num_shared=spec.num_shared_experts, valid=valid,
-            routed_scale=spec.routed_scale)
-    return (sandwich_add(x1, ffn, lw["ln_post_ffn"], eps), picks,
-            max_load)
+            routed_scale=spec.routed_scale, swiglu_limit=spec.swiglu_limit)
+    return (sandwich_add(x1, ffn, gain(lw["ln_post_ffn"], spec), eps),
+            picks, max_load)
 
 
 def head(x, params, spec: BlockSpec):
     """RMS_f(x) Wlm^T in float32 (the head is untied from the embedding
     and read in its own dtype: no float32 copy of it is made)."""
-    h = rms_norm(x, params["final_ln"], spec.eps)
+    h = rms_norm(x, gain(params["final_ln"], spec), spec.eps)
     return jax.lax.dot_general(h, params["head"], (((1,), (1,)), ((), ())),
                                preferred_element_type=_F32)
 
@@ -311,9 +392,8 @@ def forward_sequence(params, ids, spec: BlockSpec, form=expanded_attention):
     """Logits [T, V] float32 of one whole sequence `ids` [T]: the plain
     forward pass, every layer attending over the sequence in hand."""
     x = params["embed"][ids]
-    rope = tuple(jnp.asarray(a) for a in rope_tables(
-        ids.shape[0], spec.rope_dim, spec.rope_theta))
+    tables = tuple(jnp.asarray(a) for a in rope_for(spec, ids.shape[0]))
     for lw, kind in zip(params["layers"], spec.layer_types):
         x, _, _ = block(x, lw, spec, kind, attend_sequence(spec, form),
-                        rope=rope)
+                        rope=tables)
     return head(x, params, spec)

@@ -124,26 +124,33 @@ def pangu_ultra_moe_tiny_config(**kw) -> PanguUltraMoEConfig:
 
 class _Weights(nn.Layer):
     """A bag of parameters: {name: shape}, all normal(0, std) — or ones
-    for the names in `ones` (norm gains)."""
+    for the names in `ones` (norm gains), zeros for those in `zeros`
+    (zero-centred gains, biases)."""
 
-    def __init__(self, shapes: dict, config: PanguUltraMoEConfig, ones=()):
+    def __init__(self, shapes: dict, config, ones=(), zeros=()):
         super().__init__()
         for name, shape in shapes.items():
             init = Constant(1.0) if name in ones \
+                else Constant(0.0) if name in zeros \
                 else Normal(0.0, config.initializer_range)
             setattr(self, name, self.create_parameter(
                 list(shape), dtype=config.dtype, default_initializer=init))
 
 
-def _gain(c, width):
+def _gain(c, width, zero_centred=False):
+    if zero_centred:
+        return _Weights({"weight": (width,)}, c, zeros=("weight",))
     return _Weights({"weight": (width,)}, c, ones=("weight",))
 
 
-def _attention(c: PanguUltraMoEConfig) -> _Weights:
+def _attention(c, zero_centred=False, gated=False) -> _Weights:
     """The latent attention's parameters, matrices [in, out]; `kv_b_proj`
-    holds each head's [Wuk | Wuv] columns side by side, as published."""
+    holds each head's [Wuk | Wuv] columns side by side, as published;
+    `gate_proj` (`gated`) the output gate's [H, heads x dv]."""
     h, nh = c.hidden_size, c.num_attention_heads
     dq = c.qk_nope_head_dim + c.qk_rope_head_dim
+    gains = ("q_a_layernorm", "kv_a_layernorm")
+    extra = {"gate_proj": (h, nh * c.v_head_dim)} if gated else {}
     return _Weights({
         "q_a_proj": (h, c.q_lora_rank),
         "q_a_layernorm": (c.q_lora_rank,),
@@ -152,8 +159,8 @@ def _attention(c: PanguUltraMoEConfig) -> _Weights:
         "kv_a_layernorm": (c.kv_lora_rank,),
         "kv_b_proj": (c.kv_lora_rank,
                       nh * (c.qk_nope_head_dim + c.v_head_dim)),
-        "o_proj": (nh * c.v_head_dim, h)}, c,
-        ones=("q_a_layernorm", "kv_a_layernorm"))
+        "o_proj": (nh * c.v_head_dim, h), **extra}, c,
+        **{"zeros" if zero_centred else "ones": gains})
 
 
 class PanguUltraMoESparseMoe(nn.Layer):

@@ -58,6 +58,16 @@ def cache_kind(kind: str) -> str:
     return kind
 
 
+def expert_layer(kind: str) -> bool:
+    """Every layer of this block has its experts."""
+    return True
+
+
+def rope_for(spec: BlockSpec, positions: int):
+    """The programs' rotary tables for `positions` positions."""
+    return rope_tables(positions, spec.rope_dim, spec.rope_theta)
+
+
 def layer_norm_f32(x, gain, eps):
     """(x - mean) / sqrt(var + eps) * gain, in float32."""
     xf = x.astype(_F32)

@@ -417,69 +417,99 @@ class WindowRing:
         return self.pages * self.block_size
 
 
+#: the kinds of layer state `LayeredKVCache` keeps (the block modules'
+#: `cache_kind` names: `parallel_block.FULL` / `SLIDING`,
+#: `latent_block.LATENT`, `gated_delta_block.RECURRENT`)
+FULL, WINDOW, LATENT, RECURRENT = ("full_attention", "sliding_attention",
+                                   "latent", "recurrent")
+
+
 class LayeredKVCache:
     """Pools for a model that declares its layers one by one: one array a
     layer (no stacked layer axis: each layer's pool is its own donated
     buffer, written in place by its layer's scatter and read by its
     layer's kernel without a slice of a stacked array being copied out
-    and back). Three kinds of layer state:
+    and back) and, where the kind has one, a second (`v[i]`, else None).
+    Four kinds of layer state, one a layer (`kinds`):
 
-      full     `[N, H_kv, block_size, D]` for k and for v, `full_blocks`
-               blocks addressed through the `BlockAllocator`'s tables;
-      window   the same arrays with the `WindowRing`'s blocks;
-      latent   (`latent=True`: every layer) ONE array `[N, 1, block_size,
-               W]`, the latent and the rotary key of a position side by
-               side in one row, no heads and no separate V (`v` is empty);
-               `head_dim` is the row's width `W`, the model's `rkv + dr`
-               rounded up to whole lanes (`latent_row_width`), so that a
-               page is a lane-aligned tile the decode kernel copies whole.
-               With `H_kv` = 1 the row functions below (`append_rows`,
-               `scatter_chunk_rows`, `gather_context`) serve it unchanged.
+      full       `k`, `v` `[N, H_kv, block_size, D]`, `full_blocks` blocks
+                 addressed through the `BlockAllocator`'s tables;
+      window     the same arrays with the `WindowRing`'s blocks;
+      latent     `k` `[N, 1, block_size, W]` in the allocator's blocks: the
+                 latent and the rotary key of a position side by side in
+                 one row, no heads and no V; `head_dim` is the row's
+                 width `W`, the model's `rkv + dr` rounded up to whole
+                 lanes (`latent_row_width`), so that a page is a
+                 lane-aligned tile the decode kernel copies whole. With
+                 `H_kv` = 1 the row functions below serve it unchanged;
+      recurrent  a fixed-size state a SLOT, not pages: `k` `[slots,
+                 *state_shape]` float32 (a linear-attention layer's
+                 matrix a value head) and `v` `[slots, *conv_shape]`
+                 float32 (its causal conv's last inputs, as the layer's
+                 float32 projection gave them).
+                 `slots` is the engine's slots + 1: the last is the trash
+                 slot that a bucket's rows without a request write.
 
     THREAD CONTRACT (D15): single-owner like `PagedKVCache`; `swap` is the
     one sanctioned mutation point."""
 
     _thread_contract = ("swap",)
 
-    def __init__(self, sliding, full_blocks: int, window_blocks: int,
+    def __init__(self, kinds, full_blocks: int, window_blocks: int,
                  num_kv_heads: int, block_size: int, head_dim: int, dtype,
-                 latent: bool = False):
+                 slots: int = 0, state_shape=(), conv_shape=()):
         self.contract = ThreadContract("LayeredKVCache")
         if int(block_size) % 8:
             raise ValueError(
                 f"kv block_size {block_size} must be a multiple of 8 "
                 "(sublane alignment of the (block_size, head_dim) tile)")
+        self.kinds = tuple(kinds)
+        unknown = set(self.kinds) - {FULL, WINDOW, LATENT, RECURRENT}
+        if unknown:
+            raise ValueError(f"unknown kinds of layer state {unknown}")
         #: per layer: True where the layer keeps a window only
-        self.sliding = tuple(bool(x) for x in sliding)
-        self.latent = bool(latent)
-        if self.latent and (any(self.sliding) or int(num_kv_heads) != 1):
-            raise ValueError("a latent pool has one row a position and "
-                             "keeps the whole history")
+        self.sliding = tuple(k == WINDOW for k in self.kinds)
+        if LATENT in self.kinds and int(num_kv_heads) != 1:
+            raise ValueError("a latent pool has one row a position")
 
-        def pool(is_sliding):
-            n = int(window_blocks) if is_sliding else int(full_blocks)
-            return jnp.zeros((n, int(num_kv_heads), int(block_size),
+        def pages(n):
+            return jnp.zeros((int(n), int(num_kv_heads), int(block_size),
                               int(head_dim)), dtype)
 
-        self.k = tuple(pool(x) for x in self.sliding)
-        self.v = () if self.latent else tuple(pool(x) for x in self.sliding)
+        def arrays(kind):
+            if kind == RECURRENT:
+                return (jnp.zeros((int(slots),) + tuple(state_shape),
+                                  jnp.float32),
+                        jnp.zeros((int(slots),) + tuple(conv_shape),
+                                  jnp.float32))
+            n = window_blocks if kind == WINDOW else full_blocks
+            return pages(n), None if kind == LATENT else pages(n)
+
+        self.k, self.v = (tuple(a) for a in zip(*map(arrays, self.kinds)))
 
     def swap(self, k, v):
         self.contract.check("swap")
         self.k, self.v = tuple(k), tuple(v)
 
     def bytes_per_token(self, is_sliding: bool) -> int:
-        """Bytes one position takes in all layers of one kind: K and V,
-        or a latent pool's one row (as stored: its padding is held
-        too)."""
-        n = sum(1 for x in self.sliding if x == bool(is_sliding))
-        _, hkv, _, d = self.k[0].shape
-        arrays = 1 if self.latent else 2
-        return arrays * n * hkv * d * self.k[0].dtype.itemsize
+        """Bytes one position takes in all window layers (`is_sliding`)
+        or all full-history ones: K and V, or a latent pool's one row (as
+        stored: its padding is held too)."""
+        want = (WINDOW,) if is_sliding else (FULL, LATENT)
+        return sum(a.shape[1] * a.shape[3] * a.dtype.itemsize
+                   for kind, k, v in zip(self.kinds, self.k, self.v)
+                   if kind in want for a in (k, v) if a is not None)
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot's recurrent state takes over all recurrent
+        layers (the matrix and the conv's inputs)."""
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for kind, k, v in zip(self.kinds, self.k, self.v)
+                   if kind == RECURRENT for a in (k, v))
 
     @property
     def hbm_bytes(self) -> int:
-        return sum(int(a.nbytes) for a in self.k + self.v)
+        return sum(int(a.nbytes) for a in self.k + self.v if a is not None)
 
 
 def latent_row_width(width: int) -> int:

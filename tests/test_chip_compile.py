@@ -202,6 +202,24 @@ def test_latent_chunk_attention(for_chip):
     assert plc.NAME in text
 
 
+def test_gated_delta_decode(for_chip, monkeypatch):
+    """What a decode tick of gigachat3.5-432b-a28b.serve-longgen runs in
+    each linear layer: 64 slots' states `[64 + 1, 64, 128, 128]` float32
+    advanced one token (the pool aliased in and out: the decode program
+    donates it, so there it is written in place), 32 value heads a grid
+    step; the slot ids, beta and alpha scalar-prefetched."""
+    from paddle_tpu.ops import pallas_gated_delta as pgd
+
+    monkeypatch.setattr(pgd, "_interpret", lambda: False)
+    f32 = jnp.float32
+    compiled = for_chip(
+        pgd.gated_delta_decode_raw, ((65, 64, 128, 128), f32),
+        ((64,), jnp.int32), ((64, 64, 128), f32), ((64, 64, 128), f32),
+        ((64, 64, 128), f32), ((64, 64), f32), ((64, 64), f32))
+    assert "gated_delta_decode" in compiled.as_text()
+    assert pgd.head_group(64, 128, 128) == 32
+
+
 # ------------------------------------- the dense serving step programs
 # `inference/engine.py`'s decode program and one chunk program as
 # mistral-7b.serve-chat runs them (32/8 heads x 128, FFN 14336, 16 slots x

@@ -123,7 +123,8 @@ def test_the_latent_pool_holds_one_row_a_position(served):
     token; the allocator's pages follow the requests and all come back."""
     eng = served["eng"]
     c = eng.cache
-    assert eng.ring is None and c.latent and c.v == ()
+    assert eng.ring is None and c.kinds == ("latent",) * LAYERS
+    assert c.v == (None,) * LAYERS
     assert len(c.k) == LAYERS and c.sliding == (False,) * LAYERS
     w = latent_row_width(TINY["kv_lora_rank"] + TINY["qk_rope_head_dim"])
     assert w == 128 and latent_row_width(576) == 640

@@ -431,7 +431,10 @@ REQUIRED_SERVING_METRICS = (
     "serving_kv_latent_bytes_held", "serving_latent_ctx_tokens_total",
     # PR 38: the latent chunk kernel (zero off the chip and on any model
     # whose chunk attention is a composition)
-    "serving_latent_chunk_kernel_blocks_total")
+    "serving_latent_chunk_kernel_blocks_total",
+    # PR 40: recurrent state a slot (zero on a model without linear layers)
+    "serving_state_slot_steps_total", "serving_state_tokens_total",
+    "serving_recurrent_state_bytes_held")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
