@@ -18,12 +18,17 @@ wherever they land, so the parts that the ranks of a deployment compute
 add up to the whole layer (the all-to-all and the sum across ranks are the
 deployment's; on one chip the layer runs without them).
 
-How the held experts are computed: a scan over them, each applied to the
-whole block of tokens and weighted by its gate (zero where it was not
-picked). Exact, and each expert's weights are read once a call; it costs
-`n_local x tokens` rows of matmul where the picks need `~k x n_local / E`
-of that. A grouped kernel over tokens sorted by expert is the optimisation
-this leaves open (PERF.md).
+How the held experts are computed: on the chip, one grouped kernel
+(`ops/pallas_grouped_experts.py`) over the picks sorted by expert. It
+fetches only the experts some live row picked, each once a call, and
+computes only the picked rows, up to a tile: a decode tick reads the few
+experts its rows picked, a chunk computes `~k x n_local / E` rows a token
+where the scan computes `n_local`. It runs through ONE module-level
+`jax.jit` function, so a program's expert layers trace it once a process
+and lower it once a program (one private function, called a layer).
+Elsewhere, and where `grouped_gate_reason` names a reason, a scan over the
+held experts, each applied to the whole block of tokens and weighted by
+its gate (zero where it was not picked): the kernel's oracle.
 
 `incubate/distributed/models/moe/moe_layer.py` is the GShard layer
 (capacity, dropped tokens, biased GELU experts): another mechanism.
@@ -32,6 +37,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ....ops import pallas_grouped_experts as pge
+from ....ops.pallas_grouped_experts import clamp_gate, clamp_up
 
 _F32 = jnp.float32
 
@@ -62,31 +70,29 @@ def local_gates(idx, gates, first_expert: int, num_local: int):
 
 
 def local_load(gate_mat, valid=None):
-    """(picks, max_load) int32 scalars: how many (token, held expert)
-    picks the block made, and the most any one held expert got. `valid`
-    [T] bool leaves padded rows out."""
+    """(picks, max_load, read) int32 scalars: how many (token, held
+    expert) picks the block made, the most any one held expert got, and
+    how many held experts got one (what the grouped kernel reads).
+    `valid` [T] bool leaves padded rows out."""
     picked = gate_mat > 0
     if valid is not None:
         picked = picked & valid[:, None]
     per_expert = jnp.sum(picked.astype(jnp.int32), axis=0)
-    return jnp.sum(per_expert), jnp.max(per_expert)
+    return (jnp.sum(per_expert), jnp.max(per_expert),
+            jnp.sum((per_expert > 0).astype(jnp.int32)))
 
 
-def clamp_gate(y, limit):
-    """A SwiGLU's gate projection capped at `limit` (0: as it is)."""
-    return jnp.minimum(y, limit) if limit else y
-
-
-def clamp_up(y, limit):
-    """A SwiGLU's up projection clipped to [-limit, limit] (0: as it is)."""
-    return jnp.clip(y, -limit, limit) if limit else y
-
-
-def local_experts(h, gate_mat, w_gate, w_up, w_down, limit=0.0):
+def local_experts(h, gate_mat, w_gate, w_up, w_down, limit=0.0,
+                  valid=None):
     """sum_e gate_mat[:, e] * Wd_e(silu(Wg_e h) * (Wu_e h)) over the held
     experts, float32 [T, H]. Weights stacked [n, H, F], [n, H, F],
-    [n, F, H]; `limit`: the SwiGLU clamp."""
+    [n, F, H]; `limit`: the SwiGLU clamp; `valid` [T] bool: rows the
+    grouped kernel leaves out (0 there; the scan computes every row)."""
     with jax.named_scope("moe.experts"):
+        if pge.use_grouped_experts(h, w_gate):
+            return pge.grouped_experts(h, gate_mat, w_gate, w_up, w_down,
+                                       valid, limit)
+
         def one(acc, xs):
             wg, wu, wd, g = xs
             y = (jax.nn.silu(clamp_gate(h @ wg, limit))
@@ -113,20 +119,23 @@ def shared_experts_mean(h, w_gate, w_up, w_down, num_shared: int,
 def moe_forward(h, lw, *, top_k, first_expert, num_local, num_shared,
                 valid=None, h_router=None, routed_scale=1.0,
                 swiglu_limit=0.0):
-    """(routed + shared [T, H] float32, picks, max_load) for one layer's
-    weights `lw` (keys `router`, `experts_gate|up|down`,
+    """(routed + shared [T, H] float32, picks, max_load, read) for one
+    layer's weights `lw` (keys `router`, `experts_gate|up|down`,
     `shared_gate|up|down`). `h_router`: the same activations before they
     were rounded to the matmuls' dtype, for the float32 router;
     `routed_scale`: the model's factor on the normalised gates (the
     shared experts are not scaled); `swiglu_limit`: the SwiGLU clamp of
-    every expert, routed and shared."""
+    every expert, routed and shared. `read`: the held experts whose
+    weights the layer read (every one where the scan runs)."""
     idx, gates = route_sigmoid_topk(h if h_router is None else h_router,
                                     lw["router"], top_k, routed_scale)
     gate_mat = local_gates(idx, gates, first_expert, num_local)
-    picks, max_load = local_load(gate_mat, valid)
+    picks, max_load, read = local_load(gate_mat, valid)
+    if not pge.use_grouped_experts(h, lw["experts_gate"]):
+        read = jnp.int32(num_local)         # the scan reads every one
     routed = local_experts(h, gate_mat, lw["experts_gate"],
                            lw["experts_up"], lw["experts_down"],
-                           swiglu_limit)
+                           swiglu_limit, valid)
     shared = shared_experts_mean(h, lw["shared_gate"], lw["shared_up"],
                                  lw["shared_down"], num_shared, swiglu_limit)
-    return routed + shared.astype(_F32), picks, max_load
+    return routed + shared.astype(_F32), picks, max_load, read

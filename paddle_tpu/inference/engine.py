@@ -1000,6 +1000,11 @@ class ServingEngine:
             "serving_moe_routed_tokens_total", "tokens routed, counted "
             "once an expert layer: the held experts' share of the picks "
             "is local_picks / (routed_tokens x experts per token)")
+        self._m_moe_read = reg.counter(
+            "serving_moe_experts_read_total", "held experts whose weights "
+            "a layer read (those with a live pick on the grouped kernel, "
+            "every one on the scan), summed over expert layers and step "
+            "programs")
         self._m_kv_window = reg.gauge(
             "serving_kv_window_bytes_held", "bytes of window-layer state "
             "(the rings of occupied slots, all window layers)")

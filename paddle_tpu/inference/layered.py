@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.compile_cache import frameless_locations
 from ..ops import pallas_decode as pd
 from ..ops import pallas_gated_delta as pgd
 from ..ops import pallas_latent_chunk as plc
@@ -291,8 +292,9 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
     table [pages], the ring view [R] (R = 0 for a model without window
     layers) and, for a model with linear layers, its slot (padding: the
     trash slot). ks/vs: a layer's arrays (`LayeredKVCache`; None where
-    the kind has no second). Returns (next tokens [B], local picks [L],
-    largest expert load [L], ks, vs, key)."""
+    the kind has no second). Returns (next tokens [B], the expert counts
+    [3, L] (local picks, the largest held expert's, held experts picked;
+    a layer), ks, vs, key)."""
     blk, bs = spec.block, spec.block_size
     mod = _BLOCKS[type(blk)]
     kinds = _kinds(blk)
@@ -306,7 +308,7 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
     valid = valid > 0
     wpos = pos - wbase * bs
     ks, vs = list(ks), list(vs)
-    picks, loads = [], []
+    counts = []
     for li, kind in enumerate(blk.layer_types):
         sliding = kind == pb.SLIDING
         tables, p = (wtables, wpos) if sliding else (ftables, pos)
@@ -330,14 +332,12 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
                 return paged_decode_attention(q, ks[li], vs[li], tables,
                                               p + 1)
 
-        x, n, m = mod.block(x, params["layers"][li], blk, kind, attend,
-                            rope=rope, valid=valid)
-        picks.append(n)
-        loads.append(m)
+        x, *layer_counts = mod.block(x, params["layers"][li], blk, kind,
+                                     attend, rope=rope, valid=valid)
+        counts.append(jnp.stack(layer_counts))
     lg = mod.head(x, params, blk)
     nxt, key = _sample(lg, any_sample, samp, key)
-    return (nxt, jnp.stack(picks), jnp.stack(loads), tuple(ks), tuple(vs),
-            key)
+    return nxt, jnp.stack(counts, axis=1), tuple(ks), tuple(vs), key
 
 
 def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
@@ -372,7 +372,7 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
     rope = (params["rope_cos"][at], params["rope_sin"][at])
     valid = pos < true_end
     ks, vs = list(ks), list(vs)
-    picks, loads = [], []
+    counts = []
     for li, kind in enumerate(blk.layer_types):
         sliding = kind == pb.SLIDING
         table, shift, pages = ((wtable, wbase * bs, spec.window_pages)
@@ -397,25 +397,38 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
                 return pb.grouped_attention(q, kx.astype(q.dtype),
                                             vx.astype(q.dtype), seen)
 
-        x, n, m = mod.block(x, params["layers"][li], blk, kind, attend,
-                            rope=rope, valid=valid)
-        picks.append(n)
-        loads.append(m)
+        x, *layer_counts = mod.block(x, params["layers"][li], blk, kind,
+                                     attend, rope=rope, valid=valid)
+        counts.append(jnp.stack(layer_counts))
     if emit_token:
         x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=0)
         lg = mod.head(x_last, params, blk)
         tok, key = _sample(lg, any_sample, samp, key)
     else:
         tok = jnp.zeros((1,), jnp.int32)
-    return (tok, jnp.stack(picks), jnp.stack(loads), tuple(ks), tuple(vs),
-            key)
+    return tok, jnp.stack(counts, axis=1), tuple(ks), tuple(vs), key
 
 
-decode_step = functools.partial(
-    jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5))(_decode_impl)
-chunk_step = functools.partial(
+class _Step:
+    """A step program's `jax.jit`, lowered under
+    `compile_cache.frameless_locations`: these programs hold Pallas
+    kernels (the grouped experts' and the attention's), whose serialized
+    bodies would otherwise key the persistent cache by the trace history
+    of the process, so that a fresh process missed."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+
+    def lower(self, *args):
+        with frameless_locations():
+            return self.jitted.lower(*args)
+
+
+decode_step = _Step(functools.partial(
+    jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5))(_decode_impl))
+chunk_step = _Step(functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(7, 8))(_chunk_impl)
+    donate_argnums=(7, 8))(_chunk_impl))
 
 
 class LayeredPrograms:
@@ -436,6 +449,9 @@ class LayeredPrograms:
         self.latent_layers = kinds.count(lb.LATENT)
         self.linear_layers = kinds.count(gd.RECURRENT)
         self.expert_layers = sum(map(mod.expert_layer, block.layer_types))
+        #: held experts over the expert layers: what a step could read
+        self.experts_held = (self.expert_layers
+                             * _mla(block).num_local_experts)
         sliding = [k == pb.SLIDING for k in kinds]
         self.ring = WindowRing(eng.max_slots, block.window,
                                eng.chunk_tokens, eng.block_size) \
@@ -526,10 +542,10 @@ class LayeredPrograms:
         (None unless the prompt's last chunk). The counts come with the
         token: one fetch, which is also the barrier a non-final chunk's
         span needs."""
-        tok, picks, loads, ck, cv, self.eng._key = out
+        tok, counts, ck, cv, self.eng._key = out
         self.cache.swap(ck, cv)
-        tok, picks, loads = jax.device_get((tok, picks, loads))
-        run.attrs.update(self._moe_attrs(n, picks, loads))
+        tok, counts = jax.device_get((tok, counts))
+        run.attrs.update(self._moe_attrs(n, counts))
         if self.latent:
             # every chunk position attends the positions up to its own
             start = run.attrs["start"]
@@ -567,10 +583,10 @@ class LayeredPrograms:
             e._samp(reqs, bucket - n, any_sample), e._key)
 
     def decode_done(self, out, n_active, run):
-        nxt, picks, loads, ck, cv, self.eng._key = out
+        nxt, counts, ck, cv, self.eng._key = out
         self.cache.swap(ck, cv)
-        nxt, picks, loads = jax.device_get((nxt, picks, loads))
-        run.attrs.update(self._moe_attrs(n_active, picks, loads))
+        nxt, counts = jax.device_get((nxt, counts))
+        run.attrs.update(self._moe_attrs(n_active, counts))
         if self.latent:
             e = self.eng
             live = [i for i, r in enumerate(e._slot_req)
@@ -637,18 +653,21 @@ class LayeredPrograms:
         self.eng._m_latent_ctx.inc(n)
         return n
 
-    def _moe_attrs(self, tokens, picks, loads):
+    def _moe_attrs(self, tokens, counts):
         """Span attributes of one step, and the registry's share:
-        `picks`/`loads` [L] are the program's counts a layer (local picks,
-        the largest held expert's; 0 on a layer without experts),
-        `tokens` the step's real tokens."""
+        `counts` [3, L] are the program's counts a layer (local picks, the
+        largest held expert's, held experts with a pick; 0 on a layer
+        without experts), `tokens` the step's real tokens."""
         e = self.eng
         routed = int(tokens) * self.expert_layers
-        e._m_moe_picks.inc(int(picks.sum()))
+        picks, loads, read = (int(c) for c in counts.sum(axis=1))
+        e._m_moe_picks.inc(picks)
         e._m_moe_tokens.inc(routed)
+        e._m_moe_read.inc(read)
         full_blocks, window_bytes = self.kv_held()
-        return {"moe_tokens": routed, "moe_local_picks": int(picks.sum()),
-                "moe_max_load": int(loads.sum()),
+        return {"moe_tokens": routed, "moe_local_picks": picks,
+                "moe_max_load": loads, "moe_experts_read": read,
+                "moe_experts_held": self.experts_held,
                 "kv_bytes_held": int(self._paged_bytes(full_blocks)
                                      + window_bytes),
                 "live_tokens": int(sum(

@@ -299,5 +299,5 @@ def forward_sequence(params, ids, spec: BlockSpec, form=chunked):
     for lw, kind in zip(params["layers"], spec.layer_types):
         attend = (attend_linear_sequence(spec, form) if kind[0] == LINEAR
                   else lb.attend_sequence(spec.mla))
-        x, _, _ = block(x, lw, spec, kind, attend, rope=tables)
+        x, *_ = block(x, lw, spec, kind, attend, rope=tables)
     return head(x, params, spec)

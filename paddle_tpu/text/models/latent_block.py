@@ -332,8 +332,8 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
     `o`, `attn_gate` where the attention is gated; `gate`/`up`/`down` of
     a dense layer or the expert layer's); `rope`: (cos, sin) [T, dr] at
     the tokens' positions; `valid` [T] bool leaves padded rows out of the
-    expert counts. Returns (x', picks, max_load), the counts 0 on a dense
-    layer."""
+    expert counts. Returns (x', picks, max_load, experts read), the
+    counts 0 on a dense layer."""
     t = x.shape[0]
     nh, dn, dr = spec.num_heads, spec.qk_nope_dim, spec.qk_rope_dim
     eps = spec.eps
@@ -360,7 +360,7 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
 
 def ffn_sublayer(x1, lw, spec: BlockSpec, kind: str, valid=None):
     """x1 + RMS(ffn(RMS(x1; g_pre_ffn)); g_post_ffn) with the layer's FFN
-    kind: (x', picks, max_load)."""
+    kind: (x', picks, max_load, experts read)."""
     eps = spec.eps
     # the router reads the norm before it is rounded to the matmuls'
     # dtype: a pick that flips on that rounding swaps a whole expert
@@ -368,16 +368,16 @@ def ffn_sublayer(x1, lw, spec: BlockSpec, kind: str, valid=None):
     h2 = h32.astype(x1.dtype)
     if kind == DENSE:
         ffn = dense_ffn(h2, lw, spec.swiglu_limit)
-        picks = max_load = jnp.zeros((), jnp.int32)
+        picks = max_load = read = jnp.zeros((), jnp.int32)
     else:
-        ffn, picks, max_load = moe_forward(
+        ffn, picks, max_load, read = moe_forward(
             h2, lw, h_router=h32, top_k=spec.top_k,
             first_expert=spec.first_expert,
             num_local=spec.num_local_experts,
             num_shared=spec.num_shared_experts, valid=valid,
             routed_scale=spec.routed_scale, swiglu_limit=spec.swiglu_limit)
     return (sandwich_add(x1, ffn, gain(lw["ln_post_ffn"], spec), eps),
-            picks, max_load)
+            picks, max_load, read)
 
 
 def head(x, params, spec: BlockSpec):
@@ -394,6 +394,6 @@ def forward_sequence(params, ids, spec: BlockSpec, form=expanded_attention):
     x = params["embed"][ids]
     tables = tuple(jnp.asarray(a) for a in rope_for(spec, ids.shape[0]))
     for lw, kind in zip(params["layers"], spec.layer_types):
-        x, _, _ = block(x, lw, spec, kind, attend_sequence(spec, form),
-                        rope=tables)
+        x, *_ = block(x, lw, spec, kind, attend_sequence(spec, form),
+                      rope=tables)
     return head(x, params, spec)

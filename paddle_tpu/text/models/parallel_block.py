@@ -152,7 +152,7 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
     arrays (`ln`, `q`, `k`, `v`, `o`, and the expert layer's); `rope`:
     (cos, sin) [T, D] at the tokens' positions, read on sliding layers;
     `valid` [T] bool leaves padded rows out of the expert counts.
-    Returns (x', picks, max_load)."""
+    Returns (x', picks, max_load, experts read)."""
     t = x.shape[0]
     nh, nkv, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     # the router reads the norm before it is rounded to the matmuls'
@@ -168,13 +168,13 @@ def block(x, lw, spec: BlockSpec, kind: str, attend, rope=None,
     with jax.named_scope("attn.window" if kind == SLIDING else "attn.full"):
         a = attend(q, k, v)
     a = a.reshape(t, nh * hd) @ lw["o"]
-    ffn, picks, max_load = moe_forward(
+    ffn, picks, max_load, read = moe_forward(
         h, lw, h_router=h32, top_k=spec.top_k,
         first_expert=spec.first_expert,
         num_local=spec.num_local_experts,
         num_shared=spec.num_shared_experts, valid=valid)
     out = x.astype(_F32) + a.astype(_F32) + ffn
-    return out.astype(x.dtype), picks, max_load
+    return out.astype(x.dtype), picks, max_load, read
 
 
 def logits(x, final_gain, embed, spec: BlockSpec):
@@ -199,6 +199,6 @@ def forward_sequence(params, ids, spec: BlockSpec):
     cos, sin = (jnp.asarray(a) for a in
                 rope_tables(t, spec.head_dim, spec.rope_theta))
     for lw, kind in zip(params["layers"], spec.layer_types):
-        x, _, _ = block(x, lw, spec, kind, attend_sequence(spec, kind),
-                        rope=(cos, sin))
+        x, *_ = block(x, lw, spec, kind, attend_sequence(spec, kind),
+                      rope=(cos, sin))
     return logits(x, params["final_ln"], params["embed"], spec)

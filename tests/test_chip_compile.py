@@ -51,11 +51,11 @@ def for_chip(monkeypatch, one_chip):
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from paddle_tpu.ops import (_pallas_common, pallas_attention,
-                                pallas_decode, pallas_latent_chunk,
-                                pallas_norm, quantized)
+                                pallas_decode, pallas_grouped_experts,
+                                pallas_latent_chunk, pallas_norm, quantized)
 
-    for mod in (pallas_attention, pallas_decode, pallas_latent_chunk,
-                pallas_norm, quantized):
+    for mod in (pallas_attention, pallas_decode, pallas_grouped_experts,
+                pallas_latent_chunk, pallas_norm, quantized):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.setattr(_pallas_common, "interpret", lambda: False)
     was = (jax.config.jax_enable_compilation_cache,
@@ -218,6 +218,160 @@ def test_gated_delta_decode(for_chip, monkeypatch):
         ((64, 64, 128), f32), ((64, 64), f32), ((64, 64), f32))
     assert "gated_delta_decode" in compiled.as_text()
     assert pgd.head_group(64, 128, 128) == 32
+
+
+# (hidden, FFN width, held experts) of the three sparse cells' models:
+# command-a-plus-05-2026 (rank 0 of 8), openpangu-ultra-moe-718b and
+# gigachat3.5-432b-a28b (rank 0 of 32)
+HELD_EXPERTS = {"cohere": (4096, 4096, 16), "pangu": (7680, 2048, 8),
+                "gigachat": (7168, 2048, 8)}
+
+
+@pytest.mark.parametrize("rows", [16, 64, 512],
+                         ids=["decode16", "decode64", "chunk512"])
+@pytest.mark.parametrize("model", list(HELD_EXPERTS))
+def test_grouped_experts(for_chip, model, rows):
+    """A layer's held experts as the grouped kernel: a decode bucket's
+    rows in one tile an expert, a chunk's in tiles of 128; the three
+    weight blocks of a step within 32 MiB (512 FFN columns at 4096 wide,
+    256 at 7168 and 7680). Every route-time reason is absent at these
+    shapes."""
+    from paddle_tpu.ops import pallas_grouped_experts as pge
+
+    hidden, ffn, n = HELD_EXPERTS[model]
+    shapes = [((rows, hidden), BF16), ((rows, n), jnp.float32),
+              ((n, hidden, ffn), BF16), ((n, hidden, ffn), BF16),
+              ((n, ffn, hidden), BF16), ((rows,), jnp.bool_)]
+    h, w = (jax.ShapeDtypeStruct(*s) for s in shapes[::2][:2])
+    assert pge.grouped_gate_reason(h, w, "tpu")[1] == "warning"
+    text = for_chip(lambda *a: pge.grouped_experts(*a, limit=10.0),
+                    *shapes).as_text()
+    assert pge.NAME in text
+
+
+def test_a_kernel_program_keys_the_persistent_cache_by_itself(
+        for_chip, one_chip):
+    """jax's persistent-cache key strips the program's locations but not a
+    Pallas kernel's serialized body, whose operations carry the Python
+    traceback of whoever traced them (or a shared jitted helper) first: the
+    same kernel program lowered afresh from two call sites keys the cache
+    twice, which is how every program holding a kernel missed the cache in
+    each fresh process on the chip. Lowered inside
+    `frameless_locations`, the locations name no frames: one key, and the
+    process's setting is as it was afterwards."""
+    import hashlib
+
+    from jax._src import cache_key
+
+    from paddle_tpu.core.compile_cache import frameless_locations
+    from paddle_tpu.ops import pallas_grouped_experts as pge
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((16, 256), BF16), ((16, 4), jnp.float32), ((4, 256, 256), BF16),
+        ((4, 256, 256), BF16), ((4, 256, 256), BF16))]
+
+    def site_a():
+        return jax.jit(lambda *a: pge.grouped_experts(*a)).lower(*args)
+
+    def site_b():
+        return jax.jit(lambda *a: pge.grouped_experts(*a)).lower(*args)
+
+    def key(site):
+        jax.clear_caches()
+        ir = site().compiler_ir("stablehlo")
+        return hashlib.sha1(cache_key._canonicalize_ir(
+            ir, cache_key.IgnoreCallbacks.NO)).hexdigest()
+
+    was = jax.config.jax_traceback_in_locations_limit
+    assert key(site_a) != key(site_b)
+    with frameless_locations():
+        assert jax.config.jax_traceback_in_locations_limit == 0
+        assert key(site_a) == key(site_b)
+    assert jax.config.jax_traceback_in_locations_limit == was
+
+
+def test_only_the_layered_programs_lower_without_frames(monkeypatch,
+                                                        tmp_path):
+    """The layered step programs (the sparse models', which hold the
+    grouped kernel) lower inside `frameless_locations`; turning the
+    persistent cache on leaves every other program's locations, and so
+    its key, as they were."""
+    from paddle_tpu.core import compile_cache
+    from paddle_tpu.inference import layered
+
+    seen = []
+
+    class Jitted:
+        def lower(self, *args):
+            seen.append(jax.config.jax_traceback_in_locations_limit)
+
+    was = jax.config.jax_traceback_in_locations_limit
+    for step in (layered.decode_step, layered.chunk_step):
+        monkeypatch.setattr(step, "jitted", Jitted())
+        step.lower()
+    assert seen == [0, 0] and was != 0
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    limit = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        compile_cache.enable_compile_cache()
+        assert jax.config.jax_traceback_in_locations_limit == was
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          limit)
+
+
+def test_layered_decode_program_holds_the_grouped_kernel_once(
+        for_chip, one_chip, monkeypatch):
+    """A layered decode program at bucket 16 of a small Cohere-shaped
+    model (4 expert layers, 8 held experts of 256 x 256, bf16), lowered
+    for the described v5e: the grouped kernel's `tpu_custom_call` appears
+    ONCE, in one private function that each expert layer calls. Lowered
+    at every call site instead, it cost 11-15 s of set-up a process in
+    the sparse cells."""
+    import re
+
+    import numpy as np
+
+    from benchmark import weights
+    from benchmark.families import cohere2_moe as fam
+    from paddle_tpu.inference import layered
+    from paddle_tpu.inference.engine import ServingEngine
+    from paddle_tpu.ops import pallas_grouped_experts as pge
+
+    cfg = {"name": "tiny", "family": "cohere2_moe", "hidden_size": 256,
+           "intermediate_size": 256, "vocab_size": 256, "head_dim": 128,
+           "num_attention_heads": 2, "num_key_value_heads": 1,
+           "num_experts": 8, "num_experts_per_tok": 8,
+           "num_shared_experts": 1,
+           "expert_parallel": {"chips": 4, "rank": 1, "experts_total": 32},
+           "sliding_window": 16, "layer_norm_eps": 1e-5,
+           "rope_theta": 50000, "logit_scale": 1,
+           "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+           "num_hidden_layers": {"serve": 4},
+           "max_position_embeddings": 256, "tie_word_embeddings": True,
+           "dtype": "bfloat16"}
+    model = fam.build_model(cfg, 4, "serve")
+    weights.assign(model, weights.make(fam.weight_spec(cfg, 4), 0,
+                                       "bfloat16"))
+    eng = ServingEngine(model, max_slots=16, kv_block_size=16,
+                        max_model_len=256, chunked_prefill_tokens=64)
+    lp = eng.programs
+    # the router asks `jax.default_backend()`, which is the CPU here
+    # (`for_chip` steers the kernels off the interpreter)
+    monkeypatch.setattr(pge, "use_grouped_experts", lambda *a: True)
+    ints = np.zeros((16, lp._ints_width()), np.int32)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=one_chip),
+        (eng.params, ints, lp.cache.k, lp.cache.v,
+         eng._samp([], 16, False), eng._key))
+    text = layered.decode_step.lower(lp.spec, False, *args).as_text()
+    assert text.count("tpu_custom_call") == 1
+    funcs = re.split(r"(?=func\.func )", text)
+    home = [re.match(r"func\.func \w+ @(\S+)\(", f).group(1)
+            for f in funcs if "tpu_custom_call" in f]
+    assert len(home) == 1 and home[0] != "main"
+    assert text.count(f"call @{home[0]}(") == lp.expert_layers == 4
 
 
 # ------------------------------------- the dense serving step programs

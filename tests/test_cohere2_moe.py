@@ -149,13 +149,17 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
         part = dict(lw, experts_gate=lw["experts_gate"][held],
                     experts_up=lw["experts_up"][held],
                     experts_down=lw["experts_down"][held])
-        out, picks, max_load = moe.moe_forward(
+        out, picks, max_load, read = moe.moe_forward(
             h, part, top_k=4, first_expert=4 * rank, num_local=4,
             num_shared=2)
         mine = np.isin(idx64, np.arange(4 * rank, 4 * rank + 4))
         assert int(picks) == mine.sum()
         assert int(max_load) == max(
             (idx64 == e).sum() for e in range(4 * rank, 4 * rank + 4))
+        # the scan (the CPU's path) reads every held expert
+        assert int(read) == 4
+        assert int(moe.local_load(moe.local_gates(
+            idx, gates, 4 * rank, 4))[2]) == len(np.unique(idx64[mine]))
         total += np.asarray(out, np.float64) - shared_mean
     np.testing.assert_allclose(total, routed_all, atol=2e-5, rtol=0)
     # the shared experts are averaged (a sum would be twice this) ...

@@ -5,6 +5,8 @@ Reference parity: test model of
 load_state_dict.py:476 — save on one mesh/placement, load on another;
 slice-intersection assembly must be exact.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -13,6 +15,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
+
+
+def _by_number(names):
+    """`names` sorted with each run of digits read as a number."""
+    return sorted(names, key=lambda n: [int(t) if t.isdigit() else t
+                                        for t in re.split(r"(\d+)", n)])
 
 
 def _mesh(shape, names):
@@ -118,7 +126,9 @@ class TestReshardOnLoad:
         # counter advanced; a fresh process regenerates identical names.
         # Remap the second model's opt-state keys onto the saved ones.
         opt_sd2 = opt2.state_dict()
-        remap = dict(zip(sorted(opt_sd2), sorted(opt.state_dict())))
+        # in the counter's order: as text "tensor_11" sorts before
+        # "tensor_9", and one model's names may cross a power of ten
+        remap = dict(zip(_by_number(opt_sd2), _by_number(opt.state_dict())))
         opt_sd2 = {remap[k]: v for k, v in opt_sd2.items()}
         tgt = {"model": model2.state_dict(), "opt": opt_sd2}
         dist.load_state_dict(tgt, str(tmp_path / "c"))

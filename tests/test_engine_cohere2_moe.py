@@ -186,15 +186,23 @@ def test_spans_carry_the_expert_and_cache_attributes():
     for r in runs:
         a = r.attrs
         assert {"moe_tokens", "moe_local_picks", "moe_max_load",
-                "kv_bytes_held", "live_tokens"} <= set(a)
+                "moe_experts_read", "moe_experts_held", "kv_bytes_held",
+                "live_tokens"} <= set(a)
         assert a["moe_tokens"] == 4 * (a.get("tokens") or a["active"])
         assert 0 <= a["moe_max_load"] <= a["moe_local_picks"] \
             <= a["moe_tokens"] * 4
+        # 4 expert layers x 4 held; the scan (the CPU's path) reads every
+        # one (the kernel's count: tests/test_pallas_grouped_experts.py)
+        assert a["moe_experts_held"] == 16
+        assert a["moe_experts_read"] == 16
         # one slot live: its full pages + 3 window layers' rings
         per_tok = 2 * 2 * 16 * 4
         assert a["kv_bytes_held"] == (
             blocks_for(36, BS) * BS * per_tok
             + 3 * eng.ring.tokens_reserved() * per_tok)
+    read = eng.metrics()["serving_moe_experts_read_total"]
+    assert read["samples"][0]["value"] == sum(
+        r.attrs["moe_experts_read"] for r in runs) > 0
 
 
 # ------------------------------------------------------------ the kernel

@@ -228,6 +228,10 @@ def test_spans_and_the_registry_count_the_state():
     per_slot = 3 * (4 * 16 * 16 * 4 + 3 * 128 * 4)
     assert [d["state_slots"] for d in decodes] == [3] * 5
     assert {d["state_bytes_held"] for d in decodes} == {per_slot}
+    # 3 expert layers x 4 held; the scan (the CPU's path) reads every one
+    for a in chunks + decodes:
+        assert a["moe_experts_held"] == 12
+        assert a["moe_experts_read"] == 12
     assert eng.cache.state_bytes_per_slot() == per_slot
     # the MLA layer alone attends latent rows
     assert [c["attn_pairs"] for c in chunks] == [
@@ -238,6 +242,9 @@ def test_spans_and_the_registry_count_the_state():
     assert m["serving_state_tokens_total"] == 3 * 30
     assert m["serving_state_slot_steps_total"] == 3 * 5
     assert m["serving_recurrent_state_bytes_held"] == 0      # drained
+    read = eng.metrics()["serving_moe_experts_read_total"]
+    assert read["samples"][0]["value"] == sum(
+        a["moe_experts_read"] for a in chunks + decodes)
     # every host value of a tick is the one packed operand
     builds = [r.attrs["h2d"] for r in mine
               if r.name == "serving.decode.build"]

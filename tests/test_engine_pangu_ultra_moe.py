@@ -192,11 +192,16 @@ def test_spans_carry_the_latent_attributes_and_one_transfer_a_decode():
     for r in runs:
         a = r.attrs
         assert {"moe_tokens", "moe_local_picks", "moe_max_load",
-                "kv_bytes_held", "live_tokens"} <= set(a)
+                "moe_experts_read", "moe_experts_held", "kv_bytes_held",
+                "live_tokens"} <= set(a)
         # two expert layers; one slot live: its pages, held ahead
         assert a["moe_tokens"] == 2 * (a.get("tokens") or a["active"])
         assert 0 <= a["moe_max_load"] <= a["moe_local_picks"] \
             <= a["moe_tokens"] * 4
+        # 2 expert layers x 4 held (the dense layer holds none); the scan
+        # (the CPU's path) reads every one
+        assert a["moe_experts_held"] == 8
+        assert a["moe_experts_read"] == 8
         assert a["kv_bytes_held"] == blocks_for(36, BS) * BS * per_tok
     chunks = [r.attrs for r in runs if r.name == "serving.chunk.run"]
     assert [c["attn_pairs"] for c in chunks] == [

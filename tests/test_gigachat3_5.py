@@ -293,7 +293,7 @@ def test_the_shares_of_4_ranks_add_up_to_the_uncut_layer():
         cut = slice(4 * rank, 4 * rank + 4)
         mine = dict(lw, **{n: lw[n][cut] for n in (
             "experts_gate", "experts_up", "experts_down")})
-        out, n, _ = moe.moe_forward(h, mine, top_k=k, first_expert=4 * rank,
+        out, n, *_ = moe.moe_forward(h, mine, top_k=k, first_expert=4 * rank,
                                     num_local=4, num_shared=1,
                                     routed_scale=2.5, swiglu_limit=limit)
         total += np.asarray(out, np.float64) - shared

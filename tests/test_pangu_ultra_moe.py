@@ -153,7 +153,7 @@ def test_the_shares_of_all_32_ranks_add_up_to_the_uncut_layer():
     for rank in range(32):
         mine = dict(lw, **{n: lw[n][rank:rank + 1] for n in (
             "experts_gate", "experts_up", "experts_down")})
-        out, n, _ = moe.moe_forward(h, mine, top_k=k, first_expert=rank,
+        out, n, *_ = moe.moe_forward(h, mine, top_k=k, first_expert=rank,
                                     num_local=1, num_shared=1,
                                     routed_scale=2.5)
         total += np.asarray(out, np.float64) - shared

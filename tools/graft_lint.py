@@ -434,7 +434,9 @@ REQUIRED_SERVING_METRICS = (
     "serving_latent_chunk_kernel_blocks_total",
     # PR 40: recurrent state a slot (zero on a model without linear layers)
     "serving_state_slot_steps_total", "serving_state_tokens_total",
-    "serving_recurrent_state_bytes_held")
+    "serving_recurrent_state_bytes_held",
+    # held experts with a live pick (zero on a dense model)
+    "serving_moe_experts_read_total")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
