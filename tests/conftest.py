@@ -11,8 +11,24 @@ JAX_PLATFORMS is set here as well as on the command line, and
 started with jax already imported still initializes only the CPU client.
 The accelerator path is exercised by `python chip_smoke.py` on a machine
 that holds a TPU, never by this suite.
+
+Two run-wide mechanisms live here too (`tests/test_conftest_limits.py`
+tests both):
+
+* one persistent compile cache for the whole run: every worker compiles
+  into and loads from one fresh directory, removed when the run ends, so a
+  program compiled by any test is loaded, not compiled again, by every
+  later test and worker that lowers the same HLO;
+* a time limit for every test (`TIME_LIMIT_S`, or `time_limit(seconds)`
+  on the test): a test over it fails alone, with its stack.
 """
+import faulthandler
+import functools
 import os
+import shutil
+import signal
+import tempfile
+import threading
 
 # must be set before the CPU client initializes (read at client creation)
 flags = os.environ.get("XLA_FLAGS", "")
@@ -40,6 +56,110 @@ import pytest
 
 assert jax.devices()[0].platform == "cpu"
 assert len(jax.devices()) == 8, jax.devices()
+
+#: seconds a test may take, set-up and tear-down included: about three times
+#: the longest test that has no limit of its own
+#: (`@pytest.mark.time_limit(s)`, which says why beside it)
+TIME_LIMIT_S = 180
+#: a test stuck past its limit where the alarm cannot reach it (a C call
+#: that does not return) gets as long again, at most this many seconds,
+#: before every thread's stack is dumped and its process ended: under
+#: xdist the worker is replaced and the run goes on
+HUNG_GRACE_S = 30
+
+_CACHE_DIR = pytest.StashKey[str]()
+_STDERR_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    """Point jax's persistent compile cache at the run's directory before
+    anything compiles: the controller (or a run without workers) makes it,
+    xdist workers are handed it. Set with `jax.config`, not the
+    environment, so that the processes tests start see nothing new."""
+    worker = getattr(config, "workerinput", None)
+    if worker is None:
+        path = tempfile.mkdtemp(prefix="paddle_tpu_test_compile_cache_")
+    else:
+        path = worker["paddle_tpu_compile_cache"]
+    config.stash[_CACHE_DIR] = path
+    jax.config.update("jax_compilation_cache_dir", path)
+    # every program, however small or quick to compile
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # fd 2 is the terminal here, not a test's captured output
+    config.stash[_STDERR_FD] = os.dup(2)
+    if getattr(config.option, "maxschedchunk", False) is None:  # xdist's
+        config.option.maxschedchunk = SCHED_CHUNK
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    node.workerinput["paddle_tpu_compile_cache"] = \
+        node.config.stash[_CACHE_DIR]
+
+
+def pytest_unconfigure(config):
+    if not hasattr(config, "workerinput") and _CACHE_DIR in config.stash:
+        shutil.rmtree(config.stash[_CACHE_DIR], ignore_errors=True)
+    if _STDERR_FD in config.stash:
+        os.close(config.stash[_STDERR_FD])
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """Arm the test's time limit around its set-up, call and tear-down."""
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)              # signals reach the main thread alone
+    marker = item.get_closest_marker("time_limit")
+    limit = int(marker.args[0]) if marker else TIME_LIMIT_S
+
+    def over(signum, frame):
+        pytest.fail(f"{item.nodeid} ran past its time limit of {limit} s")
+
+    was = signal.signal(signal.SIGALRM, over)
+    faulthandler.dump_traceback_later(
+        limit + min(limit, HUNG_GRACE_S), exit=True,
+        file=item.config.stash[_STDERR_FD])
+    signal.alarm(limit)
+    try:
+        return (yield)
+    finally:
+        signal.alarm(0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, was)
+
+
+@pytest.fixture(scope="session")
+def forget_programs():
+    """The function that drops every trace and executable held in memory:
+    jax's and the serving engine's (`_SERVING_EXECUTABLES`, keyed on the
+    model's shapes, not on the code traced). Only for code that patches
+    what jax traces, so that the patch is traced anew and nothing traced
+    with it outlives the patch; each use says why in one line. Programs of
+    unpatched code then load from the run's compile cache."""
+    from paddle_tpu.inference import engine
+
+    def forget():
+        engine._SERVING_EXECUTABLES.clear()
+        jax.clear_caches()
+    return forget
+
+
+@pytest.fixture
+def fresh_programs(forget_programs):
+    """`forget_programs`, for a test to call once it has patched what jax
+    traces; what the test traced is forgotten after it too."""
+    yield forget_programs
+    forget_programs()
+
+
+@pytest.fixture(scope="module")
+def built(request):
+    """The test module's `tiny_model` by its arguments, each model built
+    once for the module's tests. For tests that leave a model as it was:
+    an engine reads its model, and a planted fault patches code that each
+    forward traces anew."""
+    return functools.cache(request.module.tiny_model)
 
 
 @pytest.fixture(autouse=True)
@@ -78,7 +198,23 @@ QUICK_MODULES = {
 }
 
 
+#: the modules that hold the suite's longest tests (60-200 s each: eager
+#: DenseNet, GoogLeNet and MobileNetV3 training, `partition()` parity over
+#: 8 virtual devices, graft_lint's spmd smoke), run first in this order so
+#: that no worker starts one while the others run out of work
+LONG_MODULES = ("test_vision_models.py", "test_vision_extended.py",
+                "test_partitioner.py", "test_analysis.py")
+#: the most tests xdist hands a worker at once. Its default hands each
+#: worker a quarter of its share up front, and one run of long tests
+#: then keeps one worker busy long after the others are done.
+SCHED_CHUNK = 8
+
+
 def pytest_collection_modifyitems(config, items):
+    first = {m: i for i, m in enumerate(LONG_MODULES)}
+    # stable: a module's tests keep their order
+    items.sort(key=lambda it: first.get(
+        os.path.basename(str(it.fspath)), len(first)))
     for item in items:
         mod = os.path.basename(str(item.fspath))
         if mod in QUICK_MODULES:

@@ -1082,6 +1082,17 @@ class TestStaleSuppressions:
         p.write_text(json.dumps({"suppressions": entries}))
         return str(p)
 
+    @staticmethod
+    def _without_smokes(monkeypatch, graft_lint):
+        """A full-coverage run whose every smoke finds nothing: the AST
+        lint alone matches the baseline. The smokes are tests of their
+        own (the plan smoke alone took 200-260 s, over a test's limit)."""
+        for name in ("audit_serving", "audit_obs", "audit_ckpt",
+                     "audit_spmd", "audit_conc", "audit_router",
+                     "audit_plan_smoke", "audit_quant"):
+            monkeypatch.setattr(graft_lint, name, lambda: [])
+        monkeypatch.setattr(graft_lint, "audit_model", lambda n: [])
+
     def test_partial_run_reports_stale_as_note(self, tmp_path):
         sys.path.insert(0, os.path.join(REPO, "tools"))
         import graft_lint
@@ -1097,10 +1108,7 @@ class TestStaleSuppressions:
         sys.path.insert(0, os.path.join(REPO, "tools"))
         import graft_lint
 
-        for name in ("audit_serving", "audit_obs", "audit_ckpt",
-                     "audit_spmd", "audit_conc", "audit_router"):
-            monkeypatch.setattr(graft_lint, name, lambda: [])
-        monkeypatch.setattr(graft_lint, "audit_model", lambda n: [])
+        self._without_smokes(monkeypatch, graft_lint)
         fs = graft_lint.run(models=graft_lint.CI_MODELS, ast=True,
                             baseline_path=self._baseline_file(tmp_path))
         stale = [f for f in fs if f.detector == "stale-suppression"]
@@ -1112,10 +1120,7 @@ class TestStaleSuppressions:
         sys.path.insert(0, os.path.join(REPO, "tools"))
         import graft_lint
 
-        for name in ("audit_serving", "audit_obs", "audit_ckpt",
-                     "audit_spmd", "audit_conc", "audit_router"):
-            monkeypatch.setattr(graft_lint, name, lambda: [])
-        monkeypatch.setattr(graft_lint, "audit_model", lambda n: [])
+        self._without_smokes(monkeypatch, graft_lint)
         path = self._baseline_file(tmp_path)
         fs = graft_lint.run(models=graft_lint.CI_MODELS, ast=True,
                             baseline_path=path, prune_baseline=True)
@@ -1168,6 +1173,20 @@ def test_spmd_smoke_audits_clean():
     part = [f for f in findings if f.loc == "spmd/partitioner_step"]
     assert part and not [f for f in part
                          if f.severity in ("warning", "error")]
+
+
+def test_quant_smoke_audits_clean():
+    """graft_lint's `quant` smoke: int8 and int4 weight-only engines and
+    an int4-KV engine against a full-precision twin on one stream pass
+    their own gates (the stale-suppression tests used to run it, and
+    checked nothing of what it found)."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import graft_lint
+
+    findings = graft_lint.audit_quant()
+    bad = [f for f in findings if f.severity in ("warning", "error")]
+    assert bad == [], bad
+    assert {f.detector for f in findings} >= {"quant-bytes"}
 
 
 def test_lint_gate_model_list_includes_spmd():

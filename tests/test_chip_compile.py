@@ -250,7 +250,7 @@ def test_grouped_experts(for_chip, model, rows):
 
 
 def test_a_kernel_program_keys_the_persistent_cache_by_itself(
-        for_chip, one_chip):
+        for_chip, one_chip, forget_programs):
     """jax's persistent-cache key strips the program's locations but not a
     Pallas kernel's serialized body, whose operations carry the Python
     traceback of whoever traced them (or a shared jitted helper) first: the
@@ -277,7 +277,7 @@ def test_a_kernel_program_keys_the_persistent_cache_by_itself(
         return jax.jit(lambda *a: pge.grouped_experts(*a)).lower(*args)
 
     def key(site):
-        jax.clear_caches()
+        forget_programs()           # each site traces the kernel afresh
         ir = site().compiler_ir("stablehlo")
         return hashlib.sha1(cache_key._canonicalize_ir(
             ir, cache_key.IgnoreCallbacks.NO)).hexdigest()

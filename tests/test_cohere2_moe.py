@@ -55,7 +55,8 @@ def test_forward_agrees_with_the_reference(seed):
         np.testing.assert_allclose(out[b], ref, atol=5e-5, rtol=0)
 
 
-def test_the_window_and_the_rotation_matter_at_this_size(monkeypatch):
+def test_the_window_and_the_rotation_matter_at_this_size(monkeypatch,
+                                                         fresh_programs):
     """The comparison above can see what it guards: without the window
     mask, or with half-split in place of interleaved rotation, the same
     logits are off by far more than its tolerance."""
@@ -66,9 +67,8 @@ def test_the_window_and_the_rotation_matter_at_this_size(monkeypatch):
     def off_by(patch, **kw):
         with monkeypatch.context() as m:
             m.setattr(pb, patch, kw["fn"])
-            jax.clear_caches()
+            fresh_programs()        # the patched function is traced anew
             out = np.asarray(model(paddle.to_tensor(ids))._data)[0]
-        jax.clear_caches()
         return np.abs(out - ref).max()
 
     causal_only = lambda q, k, kind, window: q[:, None] >= k[None, :]

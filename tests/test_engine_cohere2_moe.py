@@ -13,7 +13,6 @@ import pytest
 from test_cohere2_moe import LAYERS, TINY, tiny_model
 
 from benchmark.families import cohere2_moe as fam
-from paddle_tpu.inference import engine as engine_mod
 from paddle_tpu.inference.engine import ServingEngine
 from paddle_tpu.ops import pallas_decode as pd
 from paddle_tpu.text.models import parallel_block as pb
@@ -43,12 +42,12 @@ def _served_logits_gap(w, prompt, toks):
 
 
 @pytest.fixture(scope="module")
-def served():
+def served(built):
     """Four greedy requests through one engine of three slots: prompts
     below the window, across it inside one chunk, over several chunks
     (several ring turns), and one that crosses the window while
     decoding; the fourth reuses a slot a longer request left."""
-    model, w = tiny_model(21)
+    model, w = built(21)
     eng = _engine(model)
     rng = np.random.default_rng(21)
     prompts = [rng.integers(0, 256, n) for n in (40, 7, 53, 20)]
@@ -79,10 +78,11 @@ def test_prefill_by_chunks_then_decode_agree_with_the_reference(served):
 
 
 @pytest.mark.parametrize("site", ["chunk", "decode"])
-def test_a_dropped_window_mask_is_seen_at_each_site(monkeypatch, site):
+def test_a_dropped_window_mask_is_seen_at_each_site(monkeypatch, site,
+                                                    fresh_programs, built):
     """The comparison of (b) fails if the window mask is taken out of
     either attention site the engine has for this architecture."""
-    model, w = tiny_model(21)
+    model, w = built(21)
     if site == "chunk":
         monkeypatch.setattr(
             pb, "visible",
@@ -95,18 +95,13 @@ def test_a_dropped_window_mask_is_seen_at_each_site(monkeypatch, site):
 
         from paddle_tpu.inference import layered
         monkeypatch.setattr(layered, "paged_decode_attention", no_start)
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
-    try:
-        eng = _engine(model)
-        prompt = np.random.default_rng(4).integers(0, 256, 53)
-        rid = eng.add_request(prompt, max_new_tokens=20)
-        toks = eng.run()[rid]
-        gap, wrong = _served_logits_gap(w, prompt, toks)
-        assert wrong > 0 and gap > 1e-3
-    finally:
-        engine_mod._SERVING_EXECUTABLES.clear()
-        jax.clear_caches()
+    fresh_programs()                # the planted fault is traced anew
+    eng = _engine(model)
+    prompt = np.random.default_rng(4).integers(0, 256, 53)
+    rid = eng.add_request(prompt, max_new_tokens=20)
+    toks = eng.run()[rid]
+    gap, wrong = _served_logits_gap(w, prompt, toks)
+    assert wrong > 0 and gap > 1e-3
 
 
 def test_cache_manager_holds_a_window_and_returns_everything(served):

@@ -11,7 +11,6 @@ interpreter)."""
 import dataclasses
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,7 +18,6 @@ from test_engine_cohere2_moe import _kernel_calls
 from test_gigachat3_5 import LAYERS, TINY, tiny_model
 
 from benchmark.families import gigachat3_5 as fam
-from paddle_tpu.inference import engine as engine_mod
 from paddle_tpu.inference import layered
 from paddle_tpu.inference.engine import ServingEngine
 from paddle_tpu.ops import pallas_gated_delta as pgd
@@ -30,21 +28,14 @@ CHUNK, BS, MAX_LEN, WY = 16, 8, 128, 8
 
 
 @pytest.fixture
-def wy8(monkeypatch):
+def wy8(monkeypatch, fresh_programs):
     """The WY form 8 rows a sub-chunk (a chunk of 16 is two of them):
     the engine's chunks then end inside and on sub-chunk edges."""
     real = GigaChat35Config.block_spec
     monkeypatch.setattr(GigaChat35Config, "block_spec",
                         lambda self: dataclasses.replace(real(self),
                                                          wy_chunk=WY))
-    _fresh_programs()
-    yield
-    _fresh_programs()
-
-
-def _fresh_programs():
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
+    fresh_programs()                # the patched spec is traced anew
 
 
 def _engine(model, **kw):
@@ -75,22 +66,22 @@ NEW = (30, 12, 20, 8, 9, 17)
 
 
 @pytest.fixture(scope="module")
-def served():
+def served(built, forget_programs):
     """Six greedy requests through one engine of three slots, slow decays
     (`dt_bias` -4): slots are reused, a state carries far."""
     with pytest.MonkeyPatch.context() as m:
         real = GigaChat35Config.block_spec
         m.setattr(GigaChat35Config, "block_spec",
                   lambda self: dataclasses.replace(real(self), wy_chunk=WY))
-        _fresh_programs()
-        model, w = tiny_model(21, dt_bias=-4.0)
+        forget_programs()           # the patched spec is traced anew
+        model, w = built(21, dt_bias=-4.0)
         eng = _engine(model)
         rng = np.random.default_rng(21)
         prompts = [rng.integers(0, 256, n) for n in PROMPTS]
         rids = [eng.add_request(p, max_new_tokens=n)
                 for p, n in zip(prompts, NEW)]
         eng.run()
-    _fresh_programs()
+    forget_programs()               # nothing traced with WY 8 outlives it
     return {"w": w, "eng": eng, "prompts": prompts,
             "tokens": [eng.completed[r] for r in rids]}
 
@@ -107,12 +98,13 @@ def test_prefill_by_chunks_then_decode_agree_with_the_reference(served):
 
 @pytest.mark.parametrize("fault", ["decay", "beta", "stale_state"])
 def test_a_planted_fault_fails_the_served_comparison(monkeypatch, wy8,
-                                                     fault):
+                                                     fault, fresh_programs,
+                                                     built):
     """The comparison above fails with the decay g left out (alpha = 1),
     with beta left out (= 1), and with a slot's state NOT reset where a
     new request's first chunk starts (the last request's state and conv
     inputs carried into the next): one slot, two requests."""
-    model, w = tiny_model(21, dt_bias=-4.0)
+    model, w = built(21, dt_bias=-4.0)
     if fault == "stale_state":
         monkeypatch.setattr(layered, "_slot_state",
                             lambda pool, slot, fresh: pool[slot])
@@ -125,7 +117,7 @@ def test_a_planted_fault_fails_the_served_comparison(monkeypatch, wy8,
                 return q, kk, v, beta, jnp.zeros_like(g)
             return q, kk, v, jnp.ones_like(beta), g
         monkeypatch.setattr(gd, "delta_inputs", planted)
-    _fresh_programs()
+    fresh_programs()                # the planted fault is traced anew
     eng = _engine(model, max_slots=1)
     rng = np.random.default_rng(4)
     eng.add_request(rng.integers(0, 256, 40), max_new_tokens=12)
@@ -187,7 +179,8 @@ def test_a_reused_slot_serves_the_next_request_exactly(wy8):
 
 
 def test_the_decode_kernel_serves_the_compositions_tokens(monkeypatch,
-                                                          wy8):
+                                                          wy8,
+                                                          fresh_programs):
     """`gated_delta_decode` forced through the interpreter where the
     router keeps the composition (the CPU backend, values of 16 lanes):
     the same tokens, and the decode program names the kernel."""
@@ -203,7 +196,7 @@ def test_the_decode_kernel_serves_the_compositions_tokens(monkeypatch,
 
     _, plain = serve()
     monkeypatch.setattr(pgd, "use_kernel", lambda shape: True)
-    _fresh_programs()
+    fresh_programs()                # the forced kernel is traced anew
     eng, forced = serve()
     for a, b in zip(plain, forced):
         np.testing.assert_array_equal(a, b)

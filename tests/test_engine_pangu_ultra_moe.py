@@ -17,7 +17,6 @@ from test_engine_cohere2_moe import _kernel_calls
 from test_pangu_ultra_moe import LAYERS, TINY, tiny_model
 
 from benchmark.families import pangu_ultra_moe as fam
-from paddle_tpu.inference import engine as engine_mod
 from paddle_tpu.inference import layered
 from paddle_tpu.inference.engine import ServingEngine
 from paddle_tpu.ops import pallas_decode as pd
@@ -56,11 +55,11 @@ NEW = (30, 12, 20, 8, 9, 17)
 
 
 @pytest.fixture(scope="module")
-def served():
+def served(built, forget_programs):
     """Six greedy requests through one engine of three slots: slots are
     reused, decode crosses page boundaries, the context blocks of the
     chunk attention (patched to 32 positions) are merged online."""
-    model, w = tiny_model(21)
+    model, w = built(21)
     with pytest.MonkeyPatch.context() as m:
         m.setattr(lb, "CTX_BLOCK", 32)
         eng = _engine(model)
@@ -74,8 +73,7 @@ def served():
             held.append((eng.allocator.num_blocks - 1
                          - eng.allocator.available,
                          sum(len(b) for b in eng._slot_blocks)))
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
+    forget_programs()               # programs traced with CTX_BLOCK 32
     return {"w": w, "eng": eng, "prompts": prompts,
             "tokens": [eng.completed[r] for r in rids], "held": held}
 
@@ -92,29 +90,25 @@ def test_prefill_by_chunks_then_decode_agree_with_the_reference(served):
 
 
 @pytest.mark.parametrize("fault", ["rotary", "sandwich"])
-def test_a_planted_fault_fails_the_served_comparison(monkeypatch, fault):
+def test_a_planted_fault_fails_the_served_comparison(monkeypatch, fault,
+                                                     fresh_programs, built):
     """The comparison above fails with the rotary part of the score left
     out (`q_rope . kr` = 0: neither cached nor attended) and with the
     sandwich norms skipped, through chunk prefill and decode alike."""
-    model, w = tiny_model(21)
+    model, w = built(21)
     if fault == "rotary":
         monkeypatch.setattr(lb, "rope_half",
                             lambda x, cos, sin: jnp.zeros_like(x))
     else:
         monkeypatch.setattr(lb, "sandwich_add",
                             lambda x, y, gain, eps: x + y.astype(x.dtype))
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
-    try:
-        eng = _engine(model)
-        prompt = np.random.default_rng(4).integers(0, 256, 53)
-        rid = eng.add_request(prompt, max_new_tokens=20)
-        toks = eng.run()[rid]
-        gap, wrong = _served_logits_gap(w, prompt, toks)
-        assert wrong > 0 and gap > 1e-3
-    finally:
-        engine_mod._SERVING_EXECUTABLES.clear()
-        jax.clear_caches()
+    fresh_programs()                # the planted fault is traced anew
+    eng = _engine(model)
+    prompt = np.random.default_rng(4).integers(0, 256, 53)
+    rid = eng.add_request(prompt, max_new_tokens=20)
+    toks = eng.run()[rid]
+    gap, wrong = _served_logits_gap(w, prompt, toks)
+    assert wrong > 0 and gap > 1e-3
 
 
 def test_the_latent_pool_holds_one_row_a_position(served):
@@ -223,7 +217,7 @@ def test_spans_carry_the_latent_attributes_and_one_transfer_a_decode():
 # ------------------------------------------------------ the chunk kernel
 
 @pytest.fixture
-def chunk_kernel_forced(monkeypatch):
+def chunk_kernel_forced(monkeypatch, fresh_programs):
     """The chunk kernel in the interpreter where the router would keep the
     composition (the CPU backend, widths of 16 and 8), a step of it 32
     context positions so that a prompt's later chunks merge blocks. The
@@ -232,19 +226,15 @@ def chunk_kernel_forced(monkeypatch):
     monkeypatch.setattr(plc, "use_latent_chunk_kernel",
                         lambda *a, **k: True)
     monkeypatch.setattr(plc, "_CTX_BLOCKS", (32,))
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
-    yield
-    engine_mod._SERVING_EXECUTABLES.clear()
-    jax.clear_caches()
+    fresh_programs()                # the forced choice is traced anew
 
 
 def test_the_chunk_kernel_serves_the_compositions_tokens(
-        served, chunk_kernel_forced):
+        served, chunk_kernel_forced, built):
     """The same six requests with every chunk's attention through the
     kernel: the tokens the composition served, which are the float32
     reference's argmax at every position."""
-    model, w = tiny_model(21)
+    model, w = built(21)
     eng = _engine(model)
     rids = [eng.add_request(p, max_new_tokens=n)
             for p, n in zip(served["prompts"], NEW)]

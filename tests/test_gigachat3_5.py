@@ -75,13 +75,13 @@ def _params(model):
 
 
 @pytest.mark.parametrize("seed", [11, 2 ** 31 + 3])
-def test_forward_agrees_with_the_reference(seed):
+def test_forward_agrees_with_the_reference(seed, built):
     """Logits of every position, float32. Tolerance 2e-4 on logits of
     size ~5: both sides are float32 at "highest" and differ in summation
     order and in the recurrence's form (the program's WY form, the
     reference's token by token; measured 5e-5); a planted fault (below)
     moves them by 1e-1 and more."""
-    model, w = tiny_model(seed)
+    model, w = built(seed)
     ids = np.random.default_rng(seed).integers(0, 256, (2, 80))
     out = np.asarray(model(paddle.to_tensor(ids))._data)
     assert out.shape == (2, 80, 256) and out.dtype == np.float32
@@ -91,10 +91,10 @@ def test_forward_agrees_with_the_reference(seed):
         np.testing.assert_allclose(out[b], ref, atol=2e-4, rtol=0)
 
 
-def test_the_gains_and_the_clamp_bind():
+def test_the_gains_and_the_clamp_bind(built):
     """What the comparison above can see: w of the gains is far from 0,
     and the clamp cuts a share of the dense layer's gate products."""
-    model, w = tiny_model(11)
+    model, w = built(11)
     assert np.abs(np.asarray(w["model.layers.0.input_layernorm.weight"])
                   ).max() > 0.3
     ids = np.random.default_rng(11).integers(0, 256, 64)
@@ -189,12 +189,13 @@ def test_the_decode_kernel_matches_its_composition():
 
 
 @pytest.mark.parametrize("fault", ["decay", "beta", "interleave", "gate"])
-def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault):
+def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault,
+                                              fresh_programs, built):
     """The comparison above can see what it guards: the decay g left out
     (alpha = 1), beta left out (= 1), RoPE's pairs taken rotate-half, or
     the attention's output gate left out: the logits move by far more
     than its tolerance."""
-    model, w = tiny_model(5)
+    model, w = built(5)
     ids = np.random.default_rng(5).integers(0, 256, (1, 48))
     ref = fam.reference_rows(TINY, LAYERS, w, ids[0], np.arange(48))
     real = gd.delta_inputs
@@ -214,9 +215,8 @@ def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault):
             type(model.config), "block_spec",
             lambda self: dataclasses.replace(spec, mla=dataclasses.replace(
                 spec.mla, gated_attention=False)))
-    jax.clear_caches()
+    fresh_programs()                # the planted fault is traced anew
     out = np.asarray(model(paddle.to_tensor(ids))._data)[0]
-    jax.clear_caches()
     assert np.abs(out - ref).max() > 1e-2
 
 

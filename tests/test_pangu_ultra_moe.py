@@ -44,12 +44,12 @@ def tiny_model(seed, cfg=TINY, layers=LAYERS, scale=8.0):
 
 
 @pytest.mark.parametrize("seed", [11, 2 ** 31 + 3])
-def test_forward_agrees_with_the_reference(seed):
+def test_forward_agrees_with_the_reference(seed, built):
     """Logits of every position, float32. Tolerance 5e-5 on logits of
     size ~5: both sides are float32 at "highest" and differ in summation
     order alone (measured 5e-6); a planted fault (below) moves them by
     1e-1."""
-    model, w = tiny_model(seed)
+    model, w = built(seed)
     ids = np.random.default_rng(seed).integers(0, 256, (2, 48))
     out = np.asarray(model(paddle.to_tensor(ids))._data)
     assert out.shape == (2, 48, 256) and out.dtype == np.float32
@@ -83,12 +83,13 @@ def test_the_absorbed_form_agrees_with_the_expanded_one():
 
 
 @pytest.mark.parametrize("fault", ["rotary", "sandwich", "routed_scale"])
-def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault):
+def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault,
+                                              fresh_programs, built):
     """The comparison above can see what it guards: with the rotary part
     of the score left out (q_rope . kr = 0), with the sandwich norms
     skipped (x + y in place of x + RMS(y)), or with the routed scale 2.5
     left out, the same logits are off by far more than its tolerance."""
-    model, w = tiny_model(5)
+    model, w = built(5)
     ids = np.random.default_rng(5).integers(0, 256, (1, 48))
     ref = fam.reference_rows(TINY, LAYERS, w, ids[0], np.arange(48))
     if fault == "rotary":
@@ -103,9 +104,8 @@ def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault):
         monkeypatch.setattr(
             moe, "route_sigmoid_topk",
             lambda h, w_r, k, routed_scale=1.0: real(h, w_r, k))
-    jax.clear_caches()
+    fresh_programs()                # the planted fault is traced anew
     out = np.asarray(model(paddle.to_tensor(ids))._data)[0]
-    jax.clear_caches()
     assert np.abs(out - ref).max() > 1e-2
 
 

@@ -328,6 +328,10 @@ class TestPartitionTraining:
     #: (one full build+compile instead of two; batches are deterministic)
     _sep_ref: dict = {}
 
+    # eager discovery steps of two `partition()` configs over 8 virtual
+    # devices, one program per op and shard: 90-250 s on a loaded 8-core
+    # host (the first case also builds the shared reference)
+    @pytest.mark.time_limit(600)
     @pytest.mark.parametrize("impl", ["ring", "ulysses"])
     def test_sep_axis_train_parity(self, impl):
         """sep-axis configs route attention through the existing

@@ -91,13 +91,16 @@ class TestGeometric:
 
 
 class TestNewModels:
-    def test_mobilenet_v3(self):
+    # one case a factory, so that two workers can take them: together they
+    # took 60-105 s of eager compiles on a loaded 8-core host
+    @pytest.mark.parametrize("fac", ["mobilenet_v3_large",
+                                     "mobilenet_v3_small"])
+    def test_mobilenet_v3(self, fac):
         x = paddle.to_tensor(np.random.RandomState(0)
                              .rand(1, 3, 64, 64).astype("float32"))
-        for fac in (models.mobilenet_v3_large, models.mobilenet_v3_small):
-            m = fac(num_classes=7)
-            m.eval()
-            assert list(m(x).shape) == [1, 7]
+        m = getattr(models, fac)(num_classes=7)
+        m.eval()
+        assert list(m(x).shape) == [1, 7]
 
     def test_resnext_factories(self):
         m = models.resnext50_64x4d(num_classes=4)
