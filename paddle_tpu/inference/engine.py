@@ -40,6 +40,15 @@ TPU-native design:
     transfer costs the host a quarter of a millisecond whatever its size
     (PERF.md, PR 36), so a call makes one, not one a value. A `.build`
     span's `h2d` says how many its call made.
+  - ONE DECODE TICK IN FLIGHT: each slot's last token lives on the
+    device (`ServingEngine._last`, a column the decode and final-chunk
+    programs write and a decode row reads where the host packed -1), so
+    the host dispatches tick n+1 before it fetches tick n's tokens: a
+    step dispatches its chunks and its tick, then takes the tick an
+    earlier step dispatched, then its chunks. A slot whose last token is
+    in flight sits out the next tick; a request ended by eos in tick n
+    has its row of tick n+1 dropped. Where the host must read the tokens
+    before a call (a verify window's proposer) the step is synchronous.
   - Per-request sampling params thread as BATCHED arrays (temperature /
     top-k / top-p / greedy mask per slot), so mixed sampling configs share
     one program. An all-greedy call's program reads none of them and is
@@ -76,6 +85,7 @@ import functools
 import itertools
 import time
 from collections import OrderedDict, deque
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -217,21 +227,32 @@ def _scatter_rows(k, v, kc, vc, ksc, vsc, start, end, row, block_size,
 
 # ------------------------------------------------------- step programs
 
+def _row_tokens(host, slots, last):
+    """A decode row's token: the one the host packed, or, where it packed
+    -1 (the token is a tick still in flight), its slot's row of the
+    device's last-token column."""
+    return jnp.where(host >= 0, host, last[slots])
+
+
 def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
                       any_sample: bool, params, ints, kc, vc, ksc, vsc,
-                      samp, key):
+                      last, samp, key):
     """ONE decode step for a compacted slot bucket: every row consumes
     its token, appends K/V through its block table, attends over its own
-    length, and samples its next token with its own params. `ints` [B, 2
+    length, and samples its next token with its own params. `ints` [B, 3
     + pages], a row a slot (`_StackedPrograms.decode` packs it): its
-    token, its position, its block table row. Cache pools ride the layer
-    scan as its carry (`_scan_layers`), a layer's blocks addressed by
-    offset. `any_sample` is STATIC (part of the program key): an
-    all-greedy bucket — the common serving case — compiles to a bare
-    argmax instead of the sort/softmax/cumsum sampling machinery over
-    [B, V] every tick, and reads nothing of `samp`.
+    token (-1: read it from `last`), its position, its slot (padding:
+    the trash row `max_slots`), its block table row. `last` [max_slots +
+    1] is each slot's last token, kept on the device: the step reads a
+    row's token there and writes the row's new one back. Cache pools ride
+    the layer scan as its carry (`_scan_layers`), a layer's blocks
+    addressed by offset. `any_sample` is STATIC (part of the program
+    key): an all-greedy bucket — the common serving case — compiles to a
+    bare argmax instead of the sort/softmax/cumsum sampling machinery
+    over [B, V] every tick, and reads nothing of `samp`.
     """
-    tok, pos, tables = ints[:, 0], ints[:, 1], ints[:, 2:]
+    pos, slots, tables = ints[:, 1], ints[:, 2], ints[:, 3:]
+    tok = _row_tokens(ints[:, 0], slots, last)
     xt, rope = db.embed(params, tok, pos, spec)          # [B, H]
 
     def layer(xc, lw, tabs, *pools):
@@ -254,7 +275,7 @@ def _decode_step_impl(spec: _GenSpec, block_size: int, kv_mode: str,
                               samp["top_p"])
     else:
         nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-    return nxt, kc, vc, ksc, vsc, key
+    return nxt, kc, vc, ksc, vsc, last.at[slots].set(nxt), key
 
 
 def _prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
@@ -291,28 +312,31 @@ def _prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
 
 def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
                         any_sample: bool, emit_token: bool, ctx_pages: int,
-                        pages: int, params, ints, kc, vc, ksc, vsc, samp,
-                        key):
-    """Prefill ONE chunk of one prompt. `ints` [5 + pages + C]
+                        pages: int, params, ints, kc, vc, ksc, vsc, last,
+                        samp, key):
+    """Prefill ONE chunk of one prompt. `ints` [6 + pages + C]
     (`_StackedPrograms.chunk` packs it): start, true_end, last_idx,
-    cow_src, cow_dst, the slot's block table row, the chunk's ids padded
-    to the bucket C. Compute Q/K/V for positions [start, true_end),
+    cow_src, cow_dst, the slot, the slot's block table row, the chunk's
+    ids padded to the bucket C. Compute Q/K/V for positions [start, true_end),
     scatter the chunk's K/V through the block table
     (token-granular — a prefix-cache suffix may start mid-block), and
     attend each chunk position over the WHOLE context so far (cached
     prefix pages + earlier chunks + this chunk) gathered from the paged
     cache under a `kv_pos <= q_pos` mask. `emit_token` (static) is True
     only for the prompt's final chunk: it samples the first token from
-    the chunk-local index `last_idx`; earlier chunks skip the vocab
-    matmul entirely. `cow_src`/`cow_dst` implement copy-on-write: the
-    shared block a whole-prompt cache hit must partially overwrite is
+    the chunk-local index `last_idx` and writes it into the slot's row of
+    `last` (the decode step that follows reads it there); earlier chunks
+    skip the vocab matmul entirely. `cow_src`/`cow_dst` implement
+    copy-on-write: the shared block a whole-prompt cache hit must
+    partially overwrite is
     duplicated into a private block BEFORE any write (both TRASH_BLOCK
     = no-op). Context length is static via `ctx_pages` (bucketed): pages
     past the written watermark gather garbage the causal mask never
     reaches."""
-    start, true_end, last_idx, cow_src, cow_dst = (ints[i] for i in range(5))
-    table_row = ints[5:5 + pages]
-    ids = ints[5 + pages:]                               # [C]
+    start, true_end, last_idx, cow_src, cow_dst, slot = (ints[i]
+                                                         for i in range(6))
+    table_row = ints[6:6 + pages]
+    ids = ints[6 + pages:]                               # [C]
     c = ids.shape[0]
     kc = copy_block(kc, cow_src, cow_dst)
     vc = copy_block(vc, cow_src, cow_dst)
@@ -352,9 +376,10 @@ def _chunk_prefill_impl(spec: _GenSpec, block_size: int, kv_mode: str,
                                   samp["top_p"])
         else:
             tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        last = last.at[slot].set(tok[0])
     else:
         tok = jnp.zeros((1,), jnp.int32)
-    return tok, kc, vc, ksc, vsc, key
+    return tok, kc, vc, ksc, vsc, last, key
 
 
 def _verify_tokens(lg, proposed, samp, key, any_sample):
@@ -464,16 +489,16 @@ def _spec_verify_impl(spec: _GenSpec, block_size: int, kv_mode: str,
 
 # what the host decides a call (tokens, positions, tables, ids) reaches a
 # program as ONE int32 operand, unpacked by static slices: one transfer a
-# call, not one a value. Only the pools are donated.
+# call, not one a value. The pools and the last-token column are donated.
 _decode_step = functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(6, 7, 8, 9))(_decode_step_impl)
+    donate_argnums=(6, 7, 8, 9, 10))(_decode_step_impl)
 _prefill_step = functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3, 4),
     donate_argnums=(7, 8, 9, 10))(_prefill_impl)
 _chunk_prefill_step = functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
-    donate_argnums=(9, 10, 11, 12))(_chunk_prefill_impl)
+    donate_argnums=(9, 10, 11, 12, 13))(_chunk_prefill_impl)
 _spec_verify_step = functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3, 4),
     donate_argnums=(7, 8, 9, 10))(_spec_verify_impl)
@@ -513,11 +538,12 @@ class _StackedPrograms:
     """The dense side of `ServingEngine` (llama / gpt: layers stacked by
     `_stacked_params*`; one pool array `[L, N, H_kv, bs, D]`, which the
     step programs see as `[L * N, ...]`, carry through their layer scan
-    and address by `l * N + block id`: `_scan_layers`): for `_run_chunk`
-    and `_decode` each site's step function with its operands, and what
-    its result means. `inference/layered.LayeredPrograms` is its
-    counterpart for a model that declares its layers one by one, method
-    for method."""
+    and address by `l * N + block id`: `_scan_layers`): for
+    `_launch_chunk` and `_launch_decode` each site's step function with
+    its operands, what a dispatched call hands the engine (`*_sent`) and
+    what its result means once fetched (`*_done`).
+    `inference/layered.LayeredPrograms` is its counterpart for a model
+    that declares its layers one by one, method for method."""
 
     whole_prompt_prefill = True
 
@@ -544,47 +570,56 @@ class _StackedPrograms:
         """(step, number of static operands, operands)."""
         e, c = self.eng, self.eng.cache
         sample = req.do_sample and is_last
-        ints = np.zeros(5 + e.pages + c_bucket, np.int32)
-        ints[:5] = (start, start + n, req.prompt.size - 1 - start, *cow)
-        ints[5:5 + e.pages] = e._tables[slot]
-        ints[5 + e.pages:5 + e.pages + n] = req.prompt[start:start + n]
+        ints = np.zeros(6 + e.pages + c_bucket, np.int32)
+        ints[:6] = (start, start + n, req.prompt.size - 1 - start, *cow, slot)
+        ints[6:6 + e.pages] = e._tables[slot]
+        ints[6 + e.pages:6 + e.pages + n] = req.prompt[start:start + n]
         return _chunk_prefill_step, 7, (
             e.spec, e.block_size, e.kv_mode, sample, is_last, ctx_pages,
             e.pages, e.params, e._put(ints), c.k, c.v, c.k_scale,
-            c.v_scale, e._samp([req], 0, sample), e._key)
+            c.v_scale, e._last, e._samp([req], 0, sample), e._key)
 
-    def chunk_done(self, out, n, is_last, run):
-        """Take the program's result: swap the pools in, fetch the token
-        (None unless the prompt's last chunk)."""
+    def chunk_sent(self, out):
+        """Take the dispatched call's state (the pools, the key, the
+        last-token column) into the engine; returns what is left to fetch."""
         c = self.eng.cache
-        tok_arr, ck, cv, cks, cvs, self.eng._key = out
+        tok_arr, ck, cv, cks, cvs, self.eng._last, self.eng._key = out
         c.swap(ck, cv, cks, cvs)
+        return tok_arr
+
+    def chunk_done(self, tok_arr, n, is_last, run):
+        """The token (None unless the prompt's last chunk), once the
+        program has run."""
         if is_last:
             return int(jax.device_get(tok_arr)[0])
-        # non-final chunks fetch no token, so without an explicit barrier
-        # the span would end at async dispatch's enqueue time — block on
-        # the written cache so the observed wall (roofline utilization +
-        # the chunk's span) is the program's
-        jax.block_until_ready(c.k)
+        # a non-final chunk fetches no token: wait on its output, so that
+        # the span ends with the program
+        jax.block_until_ready(tok_arr)
         return None
 
     def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.eng.cache
         n = len(active)
-        # padded rows: token 0 at position 0 through the trash block
-        ints = np.zeros((bucket, 2 + e.pages), np.int32)
-        ints[n:, 2:] = TRASH_BLOCK
-        ints[:n, 0] = [r.tokens[-1] for r in reqs]
+        # padded rows: token 0 at position 0 of the trash slot, through
+        # the trash block
+        ints = np.zeros((bucket, 3 + e.pages), np.int32)
+        ints[n:, 2] = e.max_slots
+        ints[n:, 3:] = TRASH_BLOCK
+        ints[:n, 0] = e._packed_tokens(reqs)
         ints[:n, 1] = e._slot_pos[active]
-        ints[:n, 2:] = e._tables[active]
+        ints[:n, 2] = active
+        ints[:n, 3:] = e._tables[active]
         return _decode_step, 4, (
             e.spec, e.block_size, e.kv_mode, any_sample, e.params,
-            e._put(ints), c.k, c.v, c.k_scale, c.v_scale,
+            e._put(ints), c.k, c.v, c.k_scale, c.v_scale, e._last,
             e._samp(reqs, bucket - n, any_sample), e._key)
 
-    def decode_done(self, out, n_active, run):
-        nxt, ck, cv, cks, cvs, self.eng._key = out
+    def decode_sent(self, out, active):
+        nxt, ck, cv, cks, cvs, self.eng._last, self.eng._key = out
         self.eng.cache.swap(ck, cv, cks, cvs)
+        return nxt
+
+    def decode_done(self, nxt, n_active, run):
         return np.asarray(jax.device_get(nxt))
 
     def decode_jaxpr(self, bucket, samp):
@@ -592,14 +627,44 @@ class _StackedPrograms:
         fn = functools.partial(_decode_step_impl, e.spec, e.block_size,
                                e.kv_mode, False)
         return jax.make_jaxpr(fn)(
-            e.params, jnp.zeros((bucket, 2 + e.pages), jnp.int32),
-            c.k, c.v, c.k_scale, c.v_scale, samp, e._key)
+            e.params, jnp.zeros((bucket, 3 + e.pages), jnp.int32),
+            c.k, c.v, c.k_scale, c.v_scale, e._last, samp, e._key)
 
     def kv_steps(self, bucket):
         return self.eng._kv_steps(bucket)
 
     def update_gauges(self):
         """No gauge of its own: one kind of layer state."""
+
+
+@dataclass(slots=True)
+class _Tick:
+    """A decode call dispatched and not yet taken: its rows (slot and
+    request each), the attributes its spans carry, its `.build` span, its
+    cost-ledger entry, when it was dispatched, and what is left to fetch
+    (`programs.decode_sent`)."""
+    slots: list
+    reqs: list
+    at: dict
+    build: object
+    entry: object
+    sent_s: float
+    result: object
+
+
+@dataclass(slots=True)
+class _Chunk:
+    """A chunk call dispatched this step and not yet taken."""
+    slot: int
+    req: object
+    at: dict
+    entry: object
+    sent_s: float
+    result: object
+    cow: tuple | None
+    start: int
+    n: int
+    is_last: bool
 
 
 class Request:
@@ -610,7 +675,8 @@ class Request:
                  "tokens", "arrival_s", "admitted_s", "first_token_s",
                  "finished", "max_time_ms", "deadline_s", "finish_reason",
                  "cached_len", "prefill_pos", "prefill_done",
-                 "speculative", "_hashes", "_hash_ns", "_flight")
+                 "speculative", "in_flight", "_hashes", "_hash_ns",
+                 "_flight")
 
     def __init__(self, rid, prompt, max_new_tokens, do_sample, temperature,
                  top_k, top_p, eos_token_id, max_time_ms=None,
@@ -650,6 +716,10 @@ class Request:
         self.cached_len = 0
         self.prefill_pos = 0
         self.prefill_done = False
+        # tokens of this request that programs already dispatched will
+        # produce and the host has not fetched yet (a final chunk's, a
+        # decode tick's): the next tick reads its token on the device
+        self.in_flight = 0
         # memoized prefix-block hashes (a pool-blocked head-of-line
         # request is re-examined every scheduler tick; the sha256 chain
         # over an 8k prompt must not recompute per tick)
@@ -862,6 +932,14 @@ class ServingEngine:
                                               range(self.max_slots)]
         self._waiting: deque[Request] = deque()
         self._key = jax.random.PRNGKey(int(seed))
+        # each slot's last token, on the device: a decode tick reads a
+        # row's token here where the host has not fetched it yet, and
+        # writes the row's new one back; the last row is the trash row of
+        # padded rows. Made on the device, not handed over (`_put`)
+        self._last = jnp.zeros(self.max_slots + 1, jnp.int32)
+        # decode ticks dispatched and not yet taken, oldest first: at most
+        # one between steps (`step`)
+        self._ticks: deque[_Tick] = deque()
         self._next_id = 0
         # what a call's build need not do again: host arrays handed to
         # the device so far (`_put`; a `.build` span's `h2d` is its
@@ -910,6 +988,11 @@ class ServingEngine:
             "per-tick win)")
         self._m_decode_tokens = reg.counter(
             "serving_decode_tokens_total", "tokens emitted by decode ticks")
+        self._m_decode_ahead = reg.counter(
+            "serving_decode_ahead_total", "decode rows dispatched while an "
+            "earlier tick's tokens were still on the device (the sum of "
+            "`ahead_slots` over `serving.decode.run`): the host queued the "
+            "next tick before it fetched the last")
         self._m_prefill_tokens = reg.counter(
             "serving_prefill_tokens_total", "prompt tokens prefilled")
         self._m_completed = reg.counter(
@@ -1062,12 +1145,14 @@ class ServingEngine:
         # fingerprints the param avals — the key addresses REAL
         # executables (_SERVING_EXECUTABLES), so two models sharing a
         # _GenSpec but differing in vocab/intermediate width must not
-        # collide onto one compiled program.
+        # collide onto one compiled program; nor two slot counts, whose
+        # last-token columns differ in length.
         params_fp = tuple((tuple(p.shape), str(p.dtype))
                           for p in jax.tree_util.tree_leaves(self.params))
         self._prog_key_base = hash(
             (self.spec, self.layered, self.block_size, self.kv_mode,
-             self.pages, self.allocator.num_blocks, kv_dtype, params_fp))
+             self.pages, self.allocator.num_blocks, self.max_slots,
+             kv_dtype, params_fp))
         self._warmed = False
         self._draining = False
         # D15 owner-thread contract (binds on the first driving call,
@@ -1075,6 +1160,9 @@ class ServingEngine:
         from ..core import lockdep as _lockdep
 
         self.contract = _lockdep.ThreadContract("ServingEngine")
+        # `close` takes a tick left in flight; concurrent closes, once
+        self._close_lock = _lockdep.make_lock(
+            "inference.ServingEngine._close_lock")
         self.cache.contract = self.contract
         self.prefix_cache.contract = self.contract
         self.allocator.contract = self.contract
@@ -1210,22 +1298,35 @@ class ServingEngine:
         return len(self._waiting)
 
     def has_work(self) -> bool:
-        return bool(self._waiting) or self.num_active > 0
+        """Requests queued or in slots, or a decode tick still in flight
+        (its tokens are taken by the next `step`)."""
+        return bool(self._waiting) or self.num_active > 0 \
+            or bool(self._ticks)
 
     def step(self):
         """One scheduler tick: expire deadlined requests, admit joining
         requests (small cache-cold prompts prefill whole, long or
-        cache-hit prompts enter the chunk ladder), advance every
-        PREFILLING slot by one chunk, then advance every DECODING slot
-        one token — chunked prefill interleaves with decode instead of
-        head-of-line blocking it. Returns a list of (request_id, token,
-        finished) for tokens emitted this tick; a request finished by
-        its deadline emits a terminal ``(request_id, None, True)`` —
+        cache-hit prompts enter the chunk ladder), dispatch one chunk of
+        every PREFILLING slot, then one decode tick that advances every
+        DECODING slot one token — chunked prefill interleaves with decode
+        instead of head-of-line blocking it. The host does not wait for a
+        tick's tokens before it queues the next: the tick dispatched here
+        reads each row's token from the device's last-token column, the
+        tick an earlier step dispatched is taken (its tokens fetched and
+        emitted) after it, and this step's chunks after that. So one tick
+        is in flight between steps, and a token reaches the caller a step
+        after the tick that made it was dispatched. Where the host must
+        see the tokens before it builds a call (a proposer reads them for
+        a verify window) the step takes everything in flight first and
+        its own tick before it returns. Returns a list of (request_id,
+        token, finished) for tokens emitted this step; a request finished
+        by its deadline emits a terminal ``(request_id, None, True)`` —
         streaming consumers see every completion, timeout included."""
         self.contract.check("step")
         # the spans of a tick (obs/trace.py): `serving.step` bounds it;
-        # inside it a site's host preparation is `.build`, its dispatch up
-        # to the result on the host `.run`, the bookkeeping after `.emit`
+        # inside it a site's host preparation and dispatch is `.build`,
+        # the wait for its result on the host `.run` (in the step that
+        # takes it), the bookkeeping after `.emit`
         with _span("serving.step", active=self.num_active,
                    waiting=len(self._waiting)):
             with _span("serving.expire"):
@@ -1234,9 +1335,18 @@ class ServingEngine:
             with _span("serving.admit") as sp:
                 emitted.extend(self._admit())
                 sp.attrs["admitted"] = waiting - len(self._waiting)
-            emitted.extend(self._chunk_phase())
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None and r.prefill_done]
+            chunks = [self._launch_chunk(slot)
+                      for slot in sorted(self._slot_chunk)]
+            active = self._decode_slots()
+            sync = self.proposer is not None and any(
+                self._slot_req[i].speculative is not False for i in active)
+            if sync:
+                # the proposer reads every candidate's tokens
+                emitted.extend(self._take_ticks())
+                emitted.extend(self._take_chunks(chunks))
+                chunks = []
+                active = self._decode_slots()
+            tick = None
             if active:
                 # partition: speculating slots ride the verify window, the
                 # rest (opt-outs, empty proposals, non-spec engine) take
@@ -1248,17 +1358,31 @@ class ServingEngine:
                 else:
                     plain = active
                 if plain:
-                    emitted.extend(self._decode(plain))
+                    tick = self._launch_decode(plain)
+                    if sync:
+                        emitted.extend(self._take_ticks())
                 if spec_slots:
                     emitted.extend(self._spec_decode(spec_slots, props))
                 self.steps += 1
                 self.active_slot_steps += len(active)
                 self._m_active.set(len(active))
+            # the tick an earlier step dispatched; this step's stays in
+            # flight (device order is dispatch order: it runs after the
+            # chunks, which run after the tick taken here)
+            emitted.extend(self._take_ticks(keep=tick))
+            emitted.extend(self._take_chunks(chunks))
             if self._draining:
                 done = sum(1 for _rid, _tok, fin in emitted if fin)
                 if done:
                     self._m_drained.inc(done)
         return emitted
+
+    def _decode_slots(self):
+        """Slots a decode tick advances: prefilled, and not ending with a
+        token already in flight."""
+        return [i for i, r in enumerate(self._slot_req)
+                if r is not None and r.prefill_done
+                and len(r.tokens) + r.in_flight < r.max_new_tokens]
 
     def run(self, max_steps=100000):
         """Drive the engine until every queued request completes; returns
@@ -1395,10 +1519,15 @@ class ServingEngine:
         deliberately OUTSIDE the owner-thread contract: teardown comes
         from whoever is shutting the process down. The swap-to-local
         below means a double close can at worst unregister twice (an
-        idempotent pop), never call through None."""
+        idempotent pop), never call through None. A decode tick still in
+        flight is taken (its tokens reach their requests) by the first
+        close to hold the lock."""
         srv, self._metrics_server = self._metrics_server, None
         if srv is not None:
             srv.unregister_engine(self._engine_name)
+        with self._close_lock:
+            # a tick left in flight: its tokens reach their requests
+            self._take_ticks()
 
     def _program(self, site: str, jitted, n_static: int, bucket: int,
                  any_sample: bool, extra, args):
@@ -1740,43 +1869,17 @@ class ServingEngine:
                         {"ttft_s": req.ttft_s, "slo_s": self._slo_ttft_s})
             self._anomaly("slo_breach")
 
-    def _chunk_phase(self):
-        """Advance every prefilling slot by ONE chunk. A slot whose final
-        chunk completes emits its first token and joins the decode set
-        next tick — chunks and decode ticks share the scheduler loop, so
-        a long prompt costs in-flight decodes one chunk per tick, never
-        its whole prefill."""
-        emitted = []
-        for slot in sorted(self._slot_chunk):
-            req = self._slot_req[slot]
-            tok, t_end = self._run_chunk(slot, req, self._slot_chunk[slot])
-            if tok is None:
-                continue
-            del self._slot_chunk[slot]
-            s = req.prompt.size
-            req.prefill_done = True
-            req.first_token_s = t_end       # the token reached the host
-            self._m_prefill.observe(req.prefill_s)
-            self._m_ttft.observe(req.ttft_s)
-            self.ttfts.append(req.ttft_s)
-            req.tokens.append(tok)
-            self._slot_pos[slot] = s
-            self._first_token(req)
-            self._register_full_blocks(slot)
-            done = self._check_done(req, tok)
-            emitted.append((req.rid, tok, done))
-            if done:
-                self._finish(slot)
-        return emitted
-
-    def _run_chunk(self, slot, req, state):
-        """One chunk-prefill program invocation for one slot. Returns
-        (the first token when this was the prompt's final chunk, else
-        None; the end of the chunk's `.run` span). The chunk program is
-        keyed by (chunk-length bucket,
-        context-pages bucket, emit_token): chunk lengths bucket like
-        prompt lengths, context pages like slot counts, so a stream
-        compiles O(log S * log pages) chunk programs."""
+    def _launch_chunk(self, slot):
+        """Build and dispatch ONE chunk-prefill program for one
+        prefilling slot; its result is taken by `_take_chunks` at the end
+        of the step. A prompt's final chunk samples the first token and
+        writes it into the device's last-token column, so the slot joins
+        the decode tick dispatched in the same step. The chunk program is
+        keyed by (chunk-length bucket, context-pages bucket, emit_token):
+        chunk lengths bucket like prompt lengths, context pages like slot
+        counts, so a stream compiles O(log S * log pages) chunk
+        programs."""
+        req, state = self._slot_req[slot], self._slot_chunk[slot]
         s = req.prompt.size
         start = req.prefill_pos
         n = s - start if self.chunk_tokens <= 0 \
@@ -1798,28 +1901,61 @@ class ServingEngine:
                 "serving.chunk_prefill", step, n_static, c_bucket,
                 req.do_sample and is_last, (ctx_pages, bool(is_last)),
                 args)
+            result = self.programs.chunk_sent(prog(*args[n_static:]))
+            sent_s = time.perf_counter()
             build.attrs["h2d"] = self._h2d - h2d
-        with _span("serving.chunk.run", **at) as run:
-            tok = self.programs.chunk_done(prog(*args[n_static:]), n,
-                                           is_last, run)
-        t_run, t_end = run.start, run.end
-        entry.observe(t_end - t_run)
-        fl = req._flight
-        fl.chunks += 1
-        fl.add_span("prefill_chunk", t_run, t_end,
-                    {"start": int(start), "tokens": int(n),
-                     "last": bool(is_last), "cow": cow is not None,
-                     "program": entry.program})
-        if cow is not None:
-            # the copy executed (device order is program order): drop the
-            # admission-time ref that kept the source from being evicted
-            self.prefix_cache.release([cow_src])
-            self._slot_extra_refs[slot].remove(cow_src)
-            self._update_pool_gauges()
         req.prefill_pos = start + n
         self._m_chunks.inc()
         self._m_prefill_tokens.inc(n)
-        return tok, t_end
+        if is_last:
+            del self._slot_chunk[slot]
+            req.prefill_done = True
+            req.in_flight += 1
+            self._slot_pos[slot] = s
+        return _Chunk(slot, req, at, entry, sent_s, result, cow, start, n,
+                      is_last)
+
+    def _take_chunks(self, chunks):
+        """Wait for this step's chunks, in dispatch order. A slot whose
+        final chunk ran emits its first token (stamped for TTFT when it
+        reaches the host); it has joined the decode tick dispatched in
+        this step."""
+        emitted = []
+        for ch in chunks:
+            req, slot = ch.req, ch.slot
+            with _span("serving.chunk.run", **ch.at) as run:
+                tok = self.programs.chunk_done(ch.result, ch.n, ch.is_last,
+                                               run)
+            t_run, t_end = run.start, run.end
+            ch.entry.observe(t_end - ch.sent_s)
+            fl = req._flight
+            fl.chunks += 1
+            fl.add_span("prefill_chunk", t_run, t_end,
+                        {"start": int(ch.start), "tokens": int(ch.n),
+                         "last": bool(ch.is_last), "cow": ch.cow is not None,
+                         "program": ch.entry.program})
+            if ch.cow is not None:
+                # the copy executed (device order is program order): drop
+                # the admission-time ref that kept the source from being
+                # evicted
+                self.prefix_cache.release([ch.cow[0]])
+                self._slot_extra_refs[slot].remove(ch.cow[0])
+                self._update_pool_gauges()
+            if tok is None:
+                continue
+            req.in_flight -= 1
+            req.first_token_s = t_end       # the token reached the host
+            self._m_prefill.observe(req.prefill_s)
+            self._m_ttft.observe(req.ttft_s)
+            self.ttfts.append(req.ttft_s)
+            req.tokens.append(tok)
+            self._first_token(req)
+            self._register_full_blocks(slot)
+            done = self._check_done(req, tok)
+            emitted.append((req.rid, tok, done))
+            if done:
+                self._finish(slot)
+        return emitted
 
     def _kv_steps(self, bucket):
         """Grid steps a layer of the `paged_decode` kernel over K and V
@@ -1840,16 +1976,21 @@ class ServingEngine:
         return pd.kv_steps(bucket, self.pages, pool[2], pool[1], pool[3],
                            kv_dt.itemsize)
 
-    def _decode(self, active):
+    def _launch_decode(self, active):
+        """Build and dispatch one decode tick over `active`; a row whose
+        token is still in flight reads it on the device. Returns the
+        tick, which `_take_ticks` takes."""
         from ..jit.api import default_buckets
 
         bucket = min(default_buckets(len(active)), self.max_slots)
         if bucket not in self._kv_steps_of:
             self._kv_steps_of[bucket] = self.programs.kv_steps(bucket)
+        # rows dispatched while an earlier tick's tokens are on the device
+        ahead = len(active) if self._ticks else 0
         at = {"active": len(active), "bucket": int(bucket),
               "live_pages": int(
                   (self._slot_pos[active] // self.block_size + 1).sum()),
-              "kv_steps": self._kv_steps_of[bucket]}
+              "kv_steps": self._kv_steps_of[bucket], "ahead_slots": ahead}
         with _span("serving.decode.build", **at) as build:
             h2d = self._h2d
             reqs = [self._slot_req[i] for i in active]
@@ -1858,38 +1999,64 @@ class ServingEngine:
                 active, reqs, bucket, any_sample)
             prog, entry = self._program("serving.decode", step, n_static,
                                         bucket, any_sample, (), args)
+            result = self.programs.decode_sent(prog(*args[n_static:]),
+                                               active)
+            sent_s = time.perf_counter()
             build.attrs["h2d"] = self._h2d - h2d
-        with _span("serving.decode.run", **at) as run:
-            nxt = self.programs.decode_done(prog(*args[n_static:]),
-                                            len(active), run)
-        with _span("serving.decode.emit", **at):
-            t_run, t_end = run.start, run.end
-            entry.observe(t_end - t_run)
-            self.flight.tick_span("decode_tick", t_run, t_end,
-                                  active=len(active), bucket=int(bucket),
-                                  program=entry.program)
-            # the tick as its two spans time it (the span machinery's own
-            # microsecond between them left out)
-            self._m_decode_step.observe(
-                (build.end - build.start) + (t_end - t_run))
-            emitted = []
-            for j, slot in enumerate(active):
-                req = self._slot_req[slot]
-                t = int(nxt[j])
-                req.tokens.append(t)
-                fl = req._flight
-                fl.tokens += 1
-                # TPOT as this request's user sees it: the gap since its
-                # previous token, whatever ran in between (a prefill chunk
-                # of another slot, the scheduler), one observation a token
-                self._m_tpot.observe(t_end - fl.last_token_s)
-                fl.last_token_s = t_end
-                self._slot_pos[slot] += 1
-                done = self._check_done(req, t)
-                emitted.append((req.rid, t, done))
-                if done:
-                    self._finish(slot)
-            self._m_decode_tokens.inc(len(active))
+        for r in reqs:
+            r.in_flight += 1
+        self._slot_pos[active] += 1
+        self._m_decode_ahead.inc(ahead)
+        tick = _Tick(active, reqs, at, build, entry, sent_s, result)
+        self._ticks.append(tick)
+        return tick
+
+    def _take_ticks(self, keep=None):
+        """Fetch and emit every tick in flight, oldest first, but `keep`.
+        A row whose request finished while its tick was in flight (by eos
+        in the tick before, or by its deadline) is dropped: its K/V landed
+        inside the pages the request held, and the programs that reuse
+        them run after it."""
+        emitted = []
+        while self._ticks and self._ticks[0] is not keep:
+            tick = self._ticks.popleft()
+            at = tick.at
+            with _span("serving.decode.run", **at) as run:
+                nxt = self.programs.decode_done(tick.result,
+                                                len(tick.slots), run)
+            with _span("serving.decode.emit", **at):
+                t_run, t_end = run.start, run.end
+                tick.entry.observe(t_end - tick.sent_s)
+                self.flight.tick_span("decode_tick", t_run, t_end,
+                                      active=at["active"],
+                                      bucket=at["bucket"],
+                                      program=tick.entry.program)
+                # the tick as its two spans time it (the span machinery's
+                # own microsecond between them left out)
+                build = tick.build
+                self._m_decode_step.observe(
+                    (build.end - build.start) + (t_end - t_run))
+                n = 0
+                for j, (slot, req) in enumerate(zip(tick.slots, tick.reqs)):
+                    req.in_flight -= 1
+                    if req.finished:
+                        continue
+                    t = int(nxt[j])
+                    req.tokens.append(t)
+                    fl = req._flight
+                    fl.tokens += 1
+                    # TPOT as this request's user sees it: the gap since
+                    # its previous token, whatever ran in between (a
+                    # prefill chunk of another slot, the scheduler), one
+                    # observation a token
+                    self._m_tpot.observe(t_end - fl.last_token_s)
+                    fl.last_token_s = t_end
+                    done = self._check_done(req, t)
+                    emitted.append((req.rid, t, done))
+                    n += 1
+                    if done:
+                        self._finish(slot)
+                self._m_decode_tokens.inc(n)
         return emitted
 
     def _spec_proposals(self, active):
@@ -2018,6 +2185,12 @@ class ServingEngine:
                               emitted=int(n_tokens), bucket=int(bucket),
                               program=entry.program)
         return emitted
+
+    def _packed_tokens(self, reqs):
+        """The token column of a decode call's packed rows: a request's
+        last token where the host has it, -1 where it is still in flight
+        (the program reads it from the device's last-token column)."""
+        return [r.tokens[-1] if not r.in_flight else -1 for r in reqs]
 
     def _put(self, host):
         """Hand one host array to the device. Every operand a step

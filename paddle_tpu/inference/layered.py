@@ -42,12 +42,19 @@ request it admits), runs the chunk in the WY form, sub-chunks of
 they found it), and writes the state and the conv's last real inputs
 back. Its decode advances every row of the bucket one token through
 `ops/pallas_gated_delta.gated_delta_decode`, in place, through the
-slot's index (a trailing int32 column of the packed operand, in the
-chunk's too); rows that hold no request name the trash slot.
+slot's index; rows that hold no request name the trash slot.
 
-`LayeredPrograms` is what `ServingEngine._run_chunk` and `_decode` ask
-for these programs and their operands (`engine._StackedPrograms` answers
-for the dense architectures' stacked ones).
+Every model's packed operand ends in the slot's index (the chunk's and
+each decode row's), because every step keeps the engine's last-token
+column: a decode row whose token is a tick still in flight reads it
+there by its slot, the step writes the new ones back, and a prompt's
+final chunk writes its first token.
+
+`LayeredPrograms` is what `ServingEngine._launch_chunk` and
+`_launch_decode` ask for these programs and their operands, and what
+`_take_chunks` and `_take_ticks` ask of their results
+(`engine._StackedPrograms` answers for the dense architectures' stacked
+ones).
 """
 from __future__ import annotations
 
@@ -284,22 +291,28 @@ def _sample(lg, any_sample, samp, key):
 
 
 def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
-                 vs, samp, key):
+                 vs, last, samp, key):
     """ONE decode step for a compacted slot bucket. `ints` [B, 4 + pages
-    + R (+ 1)], a row a slot (`LayeredPrograms.decode` packs it): its
-    token, its position, 1 for a live row (0: padding, whose tables are
-    the trash block), its ring view's base page, the full layers' block
-    table [pages], the ring view [R] (R = 0 for a model without window
-    layers) and, for a model with linear layers, its slot (padding: the
-    trash slot). ks/vs: a layer's arrays (`LayeredKVCache`; None where
-    the kind has no second). Returns (next tokens [B], the expert counts
-    [3, L] (local picks, the largest held expert's, held experts picked;
-    a layer), ks, vs, key)."""
+    + R + 1], a row a slot (`LayeredPrograms.decode` packs it): its
+    token (-1: read it from `last`), its position, 1 for a live row (0:
+    padding, whose tables are the trash block), its ring view's base
+    page, the full layers' block table [pages], the ring view [R] (R = 0
+    for a model without window layers) and its slot (padding: the trash
+    slot). ks/vs: a layer's arrays (`LayeredKVCache`; None where the kind
+    has no second). `last` [slots + 1]: each slot's last token on the
+    device, read where the row says -1 and written with the new ones.
+    Returns (next tokens [B], the expert counts [3, L] (local picks, the
+    largest held expert's, held experts picked; a layer), ks, vs, last,
+    key)."""
+    from .engine import _row_tokens
+
     blk, bs = spec.block, spec.block_size
     mod = _BLOCKS[type(blk)]
     kinds = _kinds(blk)
-    end = ints.shape[1] - (gd.RECURRENT in kinds)
-    tok, pos, valid, wbase = (ints[:, i] for i in range(4))
+    end = ints.shape[1] - 1
+    slots = ints[:, -1]
+    tok = _row_tokens(ints[:, 0], slots, last)
+    pos, valid, wbase = (ints[:, i] for i in range(1, 4))
     ftables = ints[:, 4:end - spec.window_pages]
     wtables = ints[:, end - spec.window_pages:end]
     rows = jnp.arange(ints.shape[0])
@@ -317,7 +330,7 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
             attend = _latent_decode_attend(blk, ks, li, ftables, pos, rows,
                                            bs)
         elif kinds[li] == gd.RECURRENT:
-            attend = _recurrent_decode_attend(blk, ks, vs, li, ints[:, -1])
+            attend = _recurrent_decode_attend(blk, ks, vs, li, slots)
         else:
             def attend(q, k, v):
                 bid = tables[rows, p // bs]
@@ -337,31 +350,31 @@ def _decode_impl(spec: LayeredSpec, any_sample: bool, params, ints, ks,
         counts.append(jnp.stack(layer_counts))
     lg = mod.head(x, params, blk)
     nxt, key = _sample(lg, any_sample, samp, key)
-    return nxt, jnp.stack(counts, axis=1), tuple(ks), tuple(vs), key
+    return (nxt, jnp.stack(counts, axis=1), tuple(ks), tuple(vs),
+            last.at[slots].set(nxt), key)
 
 
 def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
-                ctx_pages: int, params, ids, ints, ks, vs, samp, key):
+                ctx_pages: int, params, ids, ints, ks, vs, last, samp, key):
     """Prefill ONE chunk of one prompt: positions [start, true_end) of
-    ids [1, C] (the rest is padding). `ints` [4 + pages + R (+ 1)]
+    ids [1, C] (the rest is padding). `ints` [4 + pages + R + 1]
     (`LayeredPrograms.chunk` packs it): start, true_end, last_idx, the
     ring view's base page, the full layers' block table, the ring view
-    and, for a model with linear layers, the slot. Each layer scatters
-    the chunk's K/V through its table and attends every chunk position over what its kind
-    sees: a full layer the first `ctx_pages` (static, bucketed) pages of
-    `ftable` under `kv <= q`, a window layer the whole ring view under
+    and the slot. Each layer scatters the chunk's K/V through its table
+    and attends every chunk position over what its kind sees: a full
+    layer the first `ctx_pages` (static, bucketed) pages of `ftable`
+    under `kv <= q`, a window layer the whole ring view under
     `0 <= q - kv < window`. Scores are computed one KV head's group at a
     time (`parallel_block.grouped_attention`), so the largest temporary
     is [heads a KV head, C, context] in float32. A latent layer:
     `_latent_chunk_attend` (`ctx_pages` is then the whole table and not
     read); a linear layer: `_recurrent_chunk_attend`. `emit_token`
-    (static):
-    the prompt's final chunk samples the first token from chunk row
-    `last_idx`."""
+    (static): the prompt's final chunk samples the first token from chunk
+    row `last_idx` and writes it into the slot's row of `last`."""
     blk, bs = spec.block, spec.block_size
     mod = _BLOCKS[type(blk)]
     kinds = _kinds(blk)
-    end = ints.shape[0] - (gd.RECURRENT in kinds)
+    end = ints.shape[0] - 1
     start, true_end, last_idx, wbase = (ints[i] for i in range(4))
     ftable = ints[4:end - spec.window_pages]
     wtable = ints[end - spec.window_pages:end]
@@ -404,9 +417,10 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
         x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=0)
         lg = mod.head(x_last, params, blk)
         tok, key = _sample(lg, any_sample, samp, key)
+        last = last.at[ints[-1]].set(tok[0])
     else:
         tok = jnp.zeros((1,), jnp.int32)
-    return tok, jnp.stack(counts, axis=1), tuple(ks), tuple(vs), key
+    return tok, jnp.stack(counts, axis=1), tuple(ks), tuple(vs), last, key
 
 
 class _Step:
@@ -425,18 +439,19 @@ class _Step:
 
 
 decode_step = _Step(functools.partial(
-    jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5))(_decode_impl))
+    jax.jit, static_argnums=(0, 1), donate_argnums=(4, 5, 6))(_decode_impl))
 chunk_step = _Step(functools.partial(
     jax.jit, static_argnums=(0, 1, 2, 3),
-    donate_argnums=(7, 8))(_chunk_impl))
+    donate_argnums=(7, 8, 9))(_chunk_impl))
 
 
 class LayeredPrograms:
     """The per-layer side of `ServingEngine`: the window layers' ring
-    (where the model has window layers), the pools, and for `_run_chunk`
-    / `_decode` each site's step function with its operands and what its
-    result means. The counterpart of `engine._StackedPrograms`, method
-    for method."""
+    (where the model has window layers), the pools, and for
+    `_launch_chunk` / `_launch_decode` each site's step function with its
+    operands, what a dispatched call hands the engine and what its result
+    means once fetched. The counterpart of `engine._StackedPrograms`,
+    method for method."""
 
     #: every prompt goes through the chunk program (no whole-prompt one)
     whole_prompt_prefill = False
@@ -531,21 +546,27 @@ class LayeredPrograms:
         ints = np.concatenate([
             np.array([start, start + n, req.prompt.size - 1 - start, wbase],
                      np.int32), e._tables[slot], wrow,
-            np.full(int(bool(self.linear_layers)), slot, np.int32)])
+            np.array([slot], np.int32)])
         return chunk_step, 4, (
             self.spec, sample, is_last, ctx_pages, e.params,
-            e._put(ids), e._put(ints), c.k, c.v,
+            e._put(ids), e._put(ints), c.k, c.v, e._last,
             e._samp([req], 0, sample), e._key)
 
-    def chunk_done(self, out, n, is_last, run):
-        """Take the program's result: swap the pools in, fetch the token
-        (None unless the prompt's last chunk). The counts come with the
-        token: one fetch, which is also the barrier a non-final chunk's
-        span needs."""
-        tok, counts, ck, cv, self.eng._key = out
+    def chunk_sent(self, out):
+        """Take the dispatched call's state (the pools, the key, the
+        last-token column) into the engine; returns what is left to fetch
+        and the span attributes read from the host's state as the call
+        was made."""
+        tok, counts, ck, cv, self.eng._last, self.eng._key = out
         self.cache.swap(ck, cv)
+        return tok, counts, self._held_attrs()
+
+    def chunk_done(self, sent, n, is_last, run):
+        """The token (None unless the prompt's last chunk) and the
+        chunk's counts, in one fetch, onto the span."""
+        tok, counts, held = sent
         tok, counts = jax.device_get((tok, counts))
-        run.attrs.update(self._moe_attrs(n, counts))
+        run.attrs.update(self._moe_attrs(n, counts), **held)
         if self.latent:
             # every chunk position attends the positions up to its own
             start = run.attrs["start"]
@@ -560,42 +581,44 @@ class LayeredPrograms:
 
     def _ints_width(self):
         """Columns of a decode row: token, position, live, ring base,
-        the table, the ring view, and the slot where there is a state."""
-        return (4 + self.eng.pages + self.spec.window_pages
-                + bool(self.linear_layers))
+        the table, the ring view, the slot."""
+        return 5 + self.eng.pages + self.spec.window_pages
 
     def decode(self, active, reqs, bucket, any_sample):
         e, c = self.eng, self.cache
         n = len(active)
         ints = np.zeros((bucket, self._ints_width()), np.int32)
         ints[:, 4:] = TRASH_BLOCK
-        ints[:n, 0] = [r.tokens[-1] for r in reqs]
+        ints[:n, 0] = e._packed_tokens(reqs)
         ints[:n, 1], ints[:n, 2] = e._slot_pos[active], 1
         ints[:n, 4:4 + e.pages] = e._tables[active]
         for j, slot in enumerate(active):
             ints[j, 4 + e.pages:4 + e.pages + self.spec.window_pages], \
                 ints[j, 3] = self._ring_view(slot, e._slot_pos[slot])
-        if self.linear_layers:
-            ints[:, -1] = e.max_slots                 # the trash slot
-            ints[:n, -1] = active
+        ints[:, -1] = e.max_slots                     # the trash slot
+        ints[:n, -1] = active
         return decode_step, 2, (
             self.spec, any_sample, e.params, e._put(ints), c.k, c.v,
-            e._samp(reqs, bucket - n, any_sample), e._key)
+            e._last, e._samp(reqs, bucket - n, any_sample), e._key)
 
-    def decode_done(self, out, n_active, run):
-        nxt, counts, ck, cv, self.eng._key = out
+    def decode_sent(self, out, active):
+        """As `chunk_sent`, before the tick's positions advance."""
+        nxt, counts, ck, cv, self.eng._last, self.eng._key = out
         self.cache.swap(ck, cv)
-        nxt, counts = jax.device_get((nxt, counts))
-        run.attrs.update(self._moe_attrs(n_active, counts))
+        held = self._held_attrs()
         if self.latent:
-            e = self.eng
-            live = [i for i, r in enumerate(e._slot_req)
-                    if r is not None and r.prefill_done]
-            run.attrs["ctx_tokens"] = self._latent_ctx(
-                int(e._slot_pos[live].sum()) + len(live))
+            held["ctx_tokens"] = self._latent_ctx(
+                int(self.eng._slot_pos[active].sum()) + len(active))
+        if self.linear_layers:
+            held["state_bytes_held"] = self._state_bytes()
+        return nxt, counts, held
+
+    def decode_done(self, sent, n_active, run):
+        nxt, counts, held = sent
+        nxt, counts = jax.device_get((nxt, counts))
+        run.attrs.update(self._moe_attrs(n_active, counts), **held)
         if self.linear_layers:
             run.attrs["state_slots"] = n_active * self.linear_layers
-            run.attrs["state_bytes_held"] = self._state_bytes()
             self.eng._m_state_slots.inc(run.attrs["state_slots"])
         return np.asarray(nxt)
 
@@ -603,7 +626,8 @@ class LayeredPrograms:
         e, c = self.eng, self.cache
         ints = jnp.zeros((bucket, self._ints_width()), jnp.int32)
         fn = functools.partial(_decode_impl, self.spec, False)
-        return jax.make_jaxpr(fn)(e.params, ints, c.k, c.v, samp, e._key)
+        return jax.make_jaxpr(fn)(e.params, ints, c.k, c.v, e._last, samp,
+                                  e._key)
 
     def kv_held(self):
         """(full-history blocks allocated to live requests, bytes of the
@@ -654,21 +678,28 @@ class LayeredPrograms:
         return n
 
     def _moe_attrs(self, tokens, counts):
-        """Span attributes of one step, and the registry's share:
-        `counts` [3, L] are the program's counts a layer (local picks, the
-        largest held expert's, held experts with a pick; 0 on a layer
-        without experts), `tokens` the step's real tokens."""
+        """Span attributes of one step's expert layers, and the
+        registry's share: `counts` [3, L] are the program's counts a layer
+        (local picks, the largest held expert's, held experts with a
+        pick; 0 on a layer without experts), `tokens` the step's real
+        tokens."""
         e = self.eng
         routed = int(tokens) * self.expert_layers
         picks, loads, read = (int(c) for c in counts.sum(axis=1))
         e._m_moe_picks.inc(picks)
         e._m_moe_tokens.inc(routed)
         e._m_moe_read.inc(read)
-        full_blocks, window_bytes = self.kv_held()
         return {"moe_tokens": routed, "moe_local_picks": picks,
                 "moe_max_load": loads, "moe_experts_read": read,
-                "moe_experts_held": self.experts_held,
-                "kv_bytes_held": int(self._paged_bytes(full_blocks)
+                "moe_experts_held": self.experts_held}
+
+    def _held_attrs(self):
+        """Span attributes of the cache the live requests hold, read as
+        a call is made (its `.run` span, which carries them, may lie in a
+        later step)."""
+        e = self.eng
+        full_blocks, window_bytes = self.kv_held()
+        return {"kv_bytes_held": int(self._paged_bytes(full_blocks)
                                      + window_bytes),
                 "live_tokens": int(sum(
                     e._slot_pos[i] if r.prefill_done else r.prefill_pos

@@ -363,7 +363,7 @@ def test_layered_decode_program_holds_the_grouped_kernel_once(
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                        sharding=one_chip),
-        (eng.params, ints, lp.cache.k, lp.cache.v,
+        (eng.params, ints, lp.cache.k, lp.cache.v, eng._last,
          eng._samp([], 16, False), eng._key))
     text = layered.decode_step.lower(lp.spec, False, *args).as_text()
     assert text.count("tpu_custom_call") == 1
@@ -432,15 +432,16 @@ def _dense_programs(kv, sharding):
                 "top_k": sds((b,), i32), "top_p": sds((b,), jnp.float32)}
 
     key = sds((2,), jnp.uint32)
-    # one packed int32 operand a call: [token, position, table row] a
-    # slot; [5 scalars, table row, the chunk's ids]
+    last = sds((slots + 1,), i32)           # each slot's last token
+    # one packed int32 operand a call: [token, position, slot, table row]
+    # a slot; [6 scalars, table row, the chunk's ids]
     yield "decode", pool, E._decode_step.lower(
-        spec, bs, mode, False, params, sds((slots, 2 + pages), i32), pool,
-        pool, scale, scale, samp(slots), key)
+        spec, bs, mode, False, params, sds((slots, 3 + pages), i32), pool,
+        pool, scale, scale, last, samp(slots), key)
     yield "chunk", pool, E._chunk_prefill_step.lower(
         spec, bs, mode, False, False, g["ctx_pages"], pages, params,
-        sds((5 + pages + g["chunk"],), i32), pool, pool, scale, scale,
-        samp(1), key)
+        sds((6 + pages + g["chunk"],), i32), pool, pool, scale, scale,
+        last, samp(1), key)
 
 
 def _pool_sized_copies(text, pool):

@@ -65,6 +65,7 @@ def _site_programs(arch):
     pool = _sds((N_L, BLOCKS, nkv, BS, HD))
     dense = _sds((N_L, B, T, nkv, HD))
     key = _sds((2,), jnp.uint32)
+    last = _sds((B + 1,), i32)             # each slot's last token
 
     def samp(b):
         return {"do_sample": _sds((b,), jnp.bool_),
@@ -85,13 +86,13 @@ def _site_programs(arch):
             (params, _sds((B, S), i32), key, _sds((), i32)), (B, H)),
         "paged_decode": (
             functools.partial(engine._decode_step_impl, *paged),
-            (params, _sds((B, 2 + PAGES), i32), pool, pool, None, None,
-             samp(B), key), (B, H)),
+            (params, _sds((B, 3 + PAGES), i32), pool, pool, None, None,
+             last, samp(B), key), (B, H)),
         "paged_chunk": (
             functools.partial(engine._chunk_prefill_impl, *paged, True, 2,
                               PAGES),
-            (params, _sds((5 + PAGES + S,), i32), pool, pool, None, None,
-             samp(1), key), (S, H)),
+            (params, _sds((6 + PAGES + S,), i32), pool, pool, None, None,
+             last, samp(1), key), (S, H)),
         "paged_verify": (
             functools.partial(engine._spec_verify_impl, *paged, PAGES),
             (params, _sds((B, 2 + PAGES + C), i32), pool, pool, None, None,

@@ -276,14 +276,14 @@ def test_the_chunk_program_names_its_kernel(chunk_kernel_forced):
     eng = _engine(model)
     lp, c = eng.programs, eng.cache
     ids = jnp.zeros((1, CHUNK), jnp.int32)
-    ints = jnp.zeros(4 + eng.pages, jnp.int32)
+    ints = jnp.zeros(5 + eng.pages, jnp.int32)
     samp = eng._samp([], 1, False)
 
     def calls(emit_token):
         fn = functools.partial(layered._chunk_impl, lp.spec, False,
                                emit_token, eng.pages)
         return _kernel_calls(jax.make_jaxpr(fn)(
-            eng.params, ids, ints, c.k, c.v, samp, eng._key))
+            eng.params, ids, ints, c.k, c.v, eng._last, samp, eng._key))
 
     for emit_token in (False, True):
         assert calls(emit_token) == {plc.NAME: [5] * LAYERS}

@@ -127,7 +127,8 @@ def test_decode_spans_are_the_decode_step_observation(served):
     for b, r in zip(build, run):
         assert b.end <= r.start
         assert set(r.attrs) == {"active", "bucket", "live_pages",
-                                "kv_steps"}
+                                "kv_steps", "ahead_slots"}
+        assert r.attrs["ahead_slots"] in (0, r.attrs["active"])
         # the build says besides how many host arrays it handed over
         assert b.attrs == {**r.attrs, "h2d": b.attrs["h2d"]}
         assert 1 <= b.attrs["active"] <= b.attrs["bucket"] <= 4

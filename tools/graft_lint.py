@@ -436,7 +436,10 @@ REQUIRED_SERVING_METRICS = (
     "serving_state_slot_steps_total", "serving_state_tokens_total",
     "serving_recurrent_state_bytes_held",
     # held experts with a live pick (zero on a dense model)
-    "serving_moe_experts_read_total")
+    "serving_moe_experts_read_total",
+    # decode rows dispatched while the tick before was in flight (zero
+    # where a proposer reads every tick's tokens)
+    "serving_decode_ahead_total")
 
 #: process-default-registry rows the README "process-default registry"
 #: catalog names (compile watchdog + cost attribution). The meta-test in
