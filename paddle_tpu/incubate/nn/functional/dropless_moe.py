@@ -10,7 +10,13 @@ experts, as one chip of an expert-parallel deployment computes it:
     shared = (1/S) * sum_j Wd'_j( silu(Wg'_j h) * (Wu'_j h) )
 
 A model may clamp every SwiGLU (`swiglu_limit` L > 0: silu(min(Wg h, L))
-* clip(Wu h, -L, L)); 0 leaves the products as written above.
+* clip(Wu h, -L, L)); 0 leaves the products as written above. Two static
+options of `moe_forward` give the "softmax top-k" layer with a gated
+shared expert (Qwen3-Next):
+
+    p      = softmax(h Wr) over ALL E experts, in float32
+    T      = the k largest of p ;  g_e = p_e / sum_{e' in T} p_e'         `router="softmax"`
+    shared = sigmoid(h w_sg) * Wd'( silu(Wg' h) * (Wu' h) )             `shared_gate=True`: one scalar a token
 
 No capacity factor and no token dropped: every pick that lands on a held
 expert is computed. The weights `g_e` are normalised over all k picks,
@@ -58,6 +64,20 @@ def route_sigmoid_topk(h, w_router, top_k: int, routed_scale: float = 1.0):
         gates = top / jnp.sum(top, axis=-1, keepdims=True)
         if routed_scale != 1.0:
             gates = gates * jnp.float32(routed_scale)
+        return idx.astype(jnp.int32), gates
+
+
+def route_softmax_topk(h, w_router, top_k: int):
+    """(idx [T, k] int32, gates [T, k] float32): the k most probable
+    experts of each token under a softmax over all experts, their
+    probabilities normalised over the k (`norm_topk_prob`). In float32 at
+    full matmul precision, as `route_sigmoid_topk`."""
+    with jax.named_scope("moe.softmax_router"):
+        probs = jax.nn.softmax(jnp.dot(
+            h.astype(_F32), w_router.astype(_F32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        top, idx = jax.lax.top_k(probs, top_k)
+        gates = top / jnp.sum(top, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), gates
 
 
@@ -116,19 +136,35 @@ def shared_experts_mean(h, w_gate, w_up, w_down, num_shared: int,
         return y / num_shared
 
 
+def shared_expert_gate(h, w_gate):
+    """sigmoid(h w_sg) [T, 1] float32: the shared expert's weight a token,
+    at full matmul precision."""
+    with jax.named_scope("moe.shared_gate"):
+        return jax.nn.sigmoid(jnp.dot(
+            h.astype(_F32), w_gate.astype(_F32),
+            precision=jax.lax.Precision.HIGHEST))
+
+
 def moe_forward(h, lw, *, top_k, first_expert, num_local, num_shared,
                 valid=None, h_router=None, routed_scale=1.0,
-                swiglu_limit=0.0):
+                swiglu_limit=0.0, router="sigmoid", shared_gate=False):
     """(routed + shared [T, H] float32, picks, max_load, read) for one
     layer's weights `lw` (keys `router`, `experts_gate|up|down`,
     `shared_gate|up|down`). `h_router`: the same activations before they
     were rounded to the matmuls' dtype, for the float32 router;
     `routed_scale`: the model's factor on the normalised gates (the
     shared experts are not scaled); `swiglu_limit`: the SwiGLU clamp of
-    every expert, routed and shared. `read`: the held experts whose
-    weights the layer read (every one where the scan runs)."""
-    idx, gates = route_sigmoid_topk(h if h_router is None else h_router,
-                                    lw["router"], top_k, routed_scale)
+    every expert, routed and shared; `router`: "sigmoid" or "softmax"
+    (`route_softmax_topk`, which takes no `routed_scale`); `shared_gate`:
+    the shared expert scaled by `shared_expert_gate` (weights
+    `shared_expert_gate` [H, 1]). `read`: the held experts whose weights
+    the layer read (every one where the scan runs)."""
+    h_r = h if h_router is None else h_router
+    if router == "softmax":
+        idx, gates = route_softmax_topk(h_r, lw["router"], top_k)
+    else:
+        idx, gates = route_sigmoid_topk(h_r, lw["router"], top_k,
+                                        routed_scale)
     gate_mat = local_gates(idx, gates, first_expert, num_local)
     picks, max_load, read = local_load(gate_mat, valid)
     if not pge.use_grouped_experts(h, lw["experts_gate"]):
@@ -138,4 +174,8 @@ def moe_forward(h, lw, *, top_k, first_expert, num_local, num_shared,
                            swiglu_limit, valid)
     shared = shared_experts_mean(h, lw["shared_gate"], lw["shared_up"],
                                  lw["shared_down"], num_shared, swiglu_limit)
+    if shared_gate:
+        return (routed + shared.astype(_F32)
+                * shared_expert_gate(h_r, lw["shared_expert_gate"]),
+                picks, max_load, read)
     return routed + shared.astype(_F32), picks, max_load, read

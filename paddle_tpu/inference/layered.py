@@ -29,6 +29,11 @@ only, so it is the same in either frame. Everything the host decides a
 step (tokens, positions, tables, ring views) reaches a program as ONE
 int32 array: one transfer a program call, not one a value.
 
+A hybrid's full layers over K/V pages (`gated_attention_block`) attend a
+chunk in the same blocks of the context, with an online softmax
+(`gated_attention_block.blocked_attention`: a traced count of blocks, ONE
+chunk program), and decode through `paged_decode` as any full layer.
+
 A latent layer's decode is the absorbed form over its pages
 (`ops/pallas_decode.paged_latent_decode`); its chunk attention is the
 expanded form over blocks of the context, as many as reach the chunk's
@@ -70,6 +75,7 @@ from ..ops import pallas_decode as pd
 from ..ops import pallas_gated_delta as pgd
 from ..ops import pallas_latent_chunk as plc
 from ..ops.pallas_decode import paged_decode_attention
+from ..text.models import gated_attention_block as ga
 from ..text.models import gated_delta_block as gd
 from ..text.models import latent_block as lb
 from ..text.models import parallel_block as pb
@@ -82,15 +88,22 @@ from ..text.paged_cache import (TRASH_BLOCK, LayeredKVCache, WindowRing,
 _BLOCKS = {pb.BlockSpec: pb, lb.BlockSpec: lb, gd.BlockSpec: gd}
 
 
-def _mla(block):
-    """The spec a latent layer's attention reads (a hybrid model's
-    `mla`)."""
-    return getattr(block, "mla", block)
+def _full(block):
+    """The spec a full layer's attention reads (a hybrid model's
+    `full`)."""
+    return getattr(block, "full", block)
 
 
 def _kinds(block) -> list:
     mod = _BLOCKS[type(block)]
-    return [mod.cache_kind(k) for k in block.layer_types]
+    return [mod.cache_kind(k, block) for k in block.layer_types]
+
+
+def _blocked_full(block) -> bool:
+    """A hybrid's full layers over K/V pages attend a chunk in context
+    blocks (one chunk program for every context length); the
+    parallel-block model's take a bucketed context whole."""
+    return isinstance(block, gd.BlockSpec)
 
 
 @dataclass(frozen=True)
@@ -163,7 +176,7 @@ def _latent_decode_attend(blk, pools, li, tables, pos, rows, bs):
     """`attend` of a latent layer in a decode step: append each slot's
     row through its table, then the absorbed form over the slot's pages
     (`paged_latent_decode`: every head against the one cached row)."""
-    blk = _mla(blk)
+    blk = _full(blk)
 
     def attend(q_nope, q_rope, c, kr, w_kvb):
         pools[li] = append_rows(pools[li], _latent_row(c, kr, pools[li]),
@@ -185,7 +198,7 @@ def _chunk_kernel_block(blk, dtype, q_rows, ctx_rows, width) -> int:
     0 where the composition runs instead (`plc.chunk_gate_reason` says
     why). The program and the engine's `attn_kernel_blocks` both ask
     here."""
-    blk = _mla(blk)
+    blk = _full(blk)
     if not plc.use_latent_chunk_kernel(
             dtype, q_rows, ctx_rows, blk.num_heads, blk.qk_nope_dim,
             blk.qk_rope_dim, blk.v_dim, blk.kv_rank, width):
@@ -209,7 +222,7 @@ def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
     table where it holds fewer), which is also the kernel's oracle."""
     block = min(lb.CTX_BLOCK, table.shape[0] * bs)
     per = block // bs
-    blk = _mla(blk)
+    blk = _full(blk)
 
     def attend(q_nope, q_rope, c, kr, w_kvb):
         pools[li] = scatter_chunk_rows(
@@ -230,6 +243,32 @@ def _latent_chunk_attend(blk, pools, li, table, start, true_end, pos, bs):
 
         return lb.expanded_attention(q_nope, q_rope, rows_of, n_blocks,
                                      block, w_kvb, pos, blk)
+    return attend
+
+
+def _full_chunk_attend(ks, vs, li, table, start, true_end, pos, bs):
+    """`attend` of a hybrid's full layer over K/V pages in a chunk step:
+    scatter the chunk's K and V through the table, then attend over the
+    pages that hold positions [0, start + C), `ga.CTX_BLOCK` positions a
+    step with an online softmax (a traced count of steps: ONE program for
+    every context length; the float32 scores held are [heads, C,
+    block])."""
+    block = min(ga.CTX_BLOCK, table.shape[0] * bs)
+    per = block // bs
+
+    def attend(q, k, v):
+        ks[li] = scatter_chunk_rows(ks[li], k, start, true_end, table, bs)
+        vs[li] = scatter_chunk_rows(vs[li], v, start, true_end, table, bs)
+        n_blocks = jnp.minimum((start + q.shape[0] + block - 1) // block,
+                               table.shape[0] // per)
+
+        def kv_of(j):
+            ids = jax.lax.dynamic_slice_in_dim(table, j * per, per)
+            return tuple(p[ids].transpose(0, 2, 1, 3).reshape(
+                block, p.shape[1], p.shape[3]) for p in (ks[li], vs[li]))
+
+        return ga.blocked_attention(q, kv_of, n_blocks, block, pos,
+                                    k.shape[1])
     return attend
 
 
@@ -368,7 +407,8 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
     time (`parallel_block.grouped_attention`), so the largest temporary
     is [heads a KV head, C, context] in float32. A latent layer:
     `_latent_chunk_attend` (`ctx_pages` is then the whole table and not
-    read); a linear layer: `_recurrent_chunk_attend`. `emit_token`
+    read); a hybrid's full layer: `_full_chunk_attend` (likewise); a
+    linear layer: `_recurrent_chunk_attend`. `emit_token`
     (static): the prompt's final chunk samples the first token from chunk
     row `last_idx` and writes it into the slot's row of `last`."""
     blk, bs = spec.block, spec.block_size
@@ -394,6 +434,9 @@ def _chunk_impl(spec: LayeredSpec, any_sample: bool, emit_token: bool,
         if kinds[li] == lb.LATENT:
             attend = _latent_chunk_attend(blk, ks, li, ftable, start,
                                           true_end, pos, bs)
+        elif kinds[li] == pb.FULL and _blocked_full(blk):
+            attend = _full_chunk_attend(ks, vs, li, ftable, start, true_end,
+                                        pos, bs)
         elif kinds[li] == gd.RECURRENT:
             attend = _recurrent_chunk_attend(blk, ks, vs, li, ints[-1],
                                              start, true_end, valid)
@@ -463,10 +506,13 @@ class LayeredPrograms:
         self.latent = lb.LATENT in kinds
         self.latent_layers = kinds.count(lb.LATENT)
         self.linear_layers = kinds.count(gd.RECURRENT)
+        #: a hybrid's full layers over K/V pages (`_full_chunk_attend`)
+        self.blocked_full_layers = kinds.count(pb.FULL) \
+            if _blocked_full(block) else 0
         self.expert_layers = sum(map(mod.expert_layer, block.layer_types))
         #: held experts over the expert layers: what a step could read
         self.experts_held = (self.expert_layers
-                             * _mla(block).num_local_experts)
+                             * _full(block).num_local_experts)
         sliding = [k == pb.SLIDING for k in kinds]
         self.ring = WindowRing(eng.max_slots, block.window,
                                eng.chunk_tokens, eng.block_size) \
@@ -477,14 +523,16 @@ class LayeredPrograms:
         cos, sin = mod.rope_for(block, eng.max_model_len)
         eng.params.update(rope_cos=jnp.asarray(cos), rope_sin=jnp.asarray(sin))
         heads, width = block.num_kv_heads, block.head_dim
-        if self.latent:
-            step = min(lb.CTX_BLOCK, eng.max_model_len)
+        if self.latent or self.blocked_full_layers:
+            step = min(lb.CTX_BLOCK if self.latent else ga.CTX_BLOCK,
+                       eng.max_model_len)
             if step % eng.block_size or eng.max_model_len % step:
                 raise ValueError(
-                    f"a chunk attends its latent context {step} positions "
-                    f"a step: kv_block_size {eng.block_size} must divide "
+                    f"a chunk attends its context {step} positions a "
+                    f"step: kv_block_size {eng.block_size} must divide "
                     f"that, and that max_model_len {eng.max_model_len}")
-            heads, width = 1, latent_row_width(_mla(block).latent_width)
+        if self.latent:
+            heads, width = 1, latent_row_width(_full(block).latent_width)
         recurrent = {}
         if self.linear_layers:
             # the engine's slots and the trash slot (index max_slots)
@@ -507,7 +555,7 @@ class LayeredPrograms:
         e, pool = self.eng, self.full_pool()
         if not self.latent:
             return e._kv_steps(bucket)
-        blk = _mla(self.spec.block)
+        blk = _full(self.spec.block)
         q = jax.ShapeDtypeStruct((bucket, blk.num_heads, pool.shape[-1]),
                                  pool.dtype)
         tables = jax.ShapeDtypeStruct((bucket, e.pages), jnp.int32)
@@ -521,9 +569,10 @@ class LayeredPrograms:
         in powers of two from the window up, a handful of programs, where
         attention over the padding is a small part of a chunk's work. A
         latent cache: ONE program, its attention's steps counted in the
-        program from the chunk's own end."""
+        program from the chunk's own end; so too a hybrid's full layers
+        over K/V pages."""
         e = self.eng
-        if self.latent:
+        if self.latent or self.blocked_full_layers:
             return e.chunk_tokens, e.pages
         floor = blocks_for(self.spec.block.window, e.block_size)
         return e.chunk_tokens, min(e.pages, max(
@@ -574,6 +623,10 @@ class LayeredPrograms:
                 n * start + n * (n + 1) // 2)
             run.attrs["attn_kernel_blocks"] = self._chunk_kernel_blocks(
                 start, run.attrs["bucket"])
+        elif self.blocked_full_layers:
+            start = run.attrs["start"]
+            run.attrs["attn_pairs"] = self.blocked_full_layers * (
+                n * start + n * (n + 1) // 2)
         if self.linear_layers:
             run.attrs["state_tokens"] = n * self.linear_layers
             self.eng._m_state_tokens.inc(run.attrs["state_tokens"])
@@ -608,6 +661,9 @@ class LayeredPrograms:
         held = self._held_attrs()
         if self.latent:
             held["ctx_tokens"] = self._latent_ctx(
+                int(self.eng._slot_pos[active].sum()) + len(active))
+        elif self.blocked_full_layers:
+            held["ctx_tokens"] = self.blocked_full_layers * (
                 int(self.eng._slot_pos[active].sum()) + len(active))
         if self.linear_layers:
             held["state_bytes_held"] = self._state_bytes()

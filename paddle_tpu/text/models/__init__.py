@@ -18,5 +18,6 @@ from .pangu_ultra_moe import (
     pangu_ultra_moe_tiny_config,
 )
 from .gigachat3_5 import GigaChat35Config, GigaChat35ForCausalLM
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 
 __all__ = [n for n in dir() if not n.startswith("_")]

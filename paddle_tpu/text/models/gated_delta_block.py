@@ -1,9 +1,17 @@
-"""GigaChat3.5's hybrid decoder layer as pure functions of arrays: Gated
-DeltaNet linear attention (Yang et al., arXiv:2412.06464, in the published
-Qwen3-Next form) on most layers, latent attention (`latent_block.py`, its
-GigaChat options on) on every fourth, the sandwich norms and the FFN of
-`latent_block.ffn_sublayer` on both. ONE definition, called by the
-Layer's `forward` (`gigachat3_5.py`) and by the serving engine's programs
+"""A Gated DeltaNet hybrid decoder layer as pure functions of arrays:
+Gated DeltaNet linear attention (Yang et al., arXiv:2412.06464, in the
+published Qwen3-Next form) on most layers and a full-attention layer
+among every few, of one of two kinds, which the spec's `full` names:
+
+  - latent attention (`latent_block.BlockSpec`: `latent_block.py` with
+    its GigaChat options on), sandwich norms and the FFN of
+    `latent_block.ffn_sublayer` on every layer (GigaChat3.5);
+  - gated GQA attention over ordinary K/V pages
+    (`gated_attention_block.BlockSpec`), pre-norms and that module's
+    expert FFN on every layer (Qwen3-Next).
+
+ONE definition, called by the Layer's `forward` (`gigachat3_5.py`,
+`qwen3_next.py`) and by the serving engine's programs
 (`inference/layered.py`).
 
 A linear layer (ZRMS(x; w) = RMS(x) (1 + w); every gain is zero-centred):
@@ -16,12 +24,20 @@ A linear layer (ZRMS(x; w) = RMS(x) (1 + w); every gain is zero-centred):
     beta = sigmoid(b) ;  g = -exp(A_log) softplus(a + dt_bias) ;  alpha = exp(g)
     S_t = alpha_t S_{t-1} + k_t (beta_t (v_t - alpha_t S_{t-1}^T k_t))^T     S [dk, dv] a value head, float32
     o_t = S_t^T q_t
-    o   = RMS(o; o_eps) (1 + w_o) gate_scale sigmoid(z)   a head
-    x1  = x + ZRMS(o W_out; w_post_attn)
+    o   = RMS(o; o_eps) (1 + w_o) gate_scale sigmoid(z)   a head (latent hybrids: `gate_sigmoid`)
+        | RMS(o; o_eps) w_o silu(z)                       a head (GQA hybrids, a plain gain: `gate_silu`)
+    x1  = x + ZRMS(o W_out; w_post_attn)                  sandwich (latent hybrids)
+        | x + o W_out                                     pre-norm (GQA hybrids)
+
+W_qkvz and W_ba are plain concatenations (latent hybrids: `split_plain`),
+or (GQA hybrids, Qwen3-Next's checkpoint layout) grouped by key head: key
+head g's columns are [q_g | k_g | v, z of its nv / nk value heads] and
+[b | a of those heads] (`split_grouped`). The kind of the spec's `full`
+layers decides all three (`_full`).
 
 What differs between the callers is where the two states live (the conv's
 last inputs and S), so a linear layer's `attend(mixed, b, a, lw) -> o`
-is the caller's, as a latent layer's is: it runs the conv over the
+is the caller's, as a full layer's is: it runs the conv over the
 state's inputs and the new ones (`delta_inputs`), then the recurrence
 from the state (`recurrent`: token by token; `chunked`: the WY form a
 sub-chunk at a time, for prefill; on the chip a decode step is
@@ -30,7 +46,7 @@ beta = 0 and g = 0: it leaves S as it found it.
 
 A layer's kind is the pair (attention, FFN): `(LINEAR | FULL, DENSE |
 EXPERTS)`; a linear layer keeps the `RECURRENT` state, a full one the
-latent rows.
+latent rows or the K/V pages of its kind (`cache_kind`).
 """
 from __future__ import annotations
 
@@ -40,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from ...ops.pallas_gated_delta import delta_step
+from . import gated_attention_block as ga
 from . import latent_block as lb
 
 _F32 = jnp.float32
@@ -53,9 +70,10 @@ RECURRENT = "recurrent"
 @dataclass(frozen=True)
 class BlockSpec:
     """The block's static sizes (hashable: it keys compiled programs).
-    `mla`: the latent layers' spec, whose `layer_types` are every layer's
-    FFN kinds; `attn_types`: every layer's attention kind."""
-    mla: lb.BlockSpec
+    `full`: the full layers' spec (latent or gated GQA), whose
+    `layer_types` are every layer's FFN kinds; `attn_types`: every
+    layer's attention kind."""
+    full: lb.BlockSpec | ga.BlockSpec
     attn_types: tuple
     num_k_heads: int
     num_v_heads: int
@@ -63,13 +81,14 @@ class BlockSpec:
     v_dim: int
     conv_kernel: int
     o_eps: float
-    gate_scale: float
+    #: the output gate's scale (`gate_sigmoid`: latent hybrids)
+    gate_scale: float = 1.0
     #: tokens a step of the chunked (WY) form takes
     wy_chunk: int = 64
 
     @property
     def layer_types(self) -> tuple:
-        return tuple(zip(self.attn_types, self.mla.layer_types))
+        return tuple(zip(self.attn_types, self.full.layer_types))
 
     @property
     def conv_dim(self) -> int:
@@ -89,27 +108,34 @@ class BlockSpec:
     # what `ServingEngine` and the latent programs ask of the spec
     @property
     def eps(self) -> float:
-        return self.mla.eps
+        return self.full.eps
 
     @property
     def num_heads(self) -> int:
-        return self.mla.num_heads
+        return self.full.num_heads
 
     @property
     def num_kv_heads(self) -> int:
-        return 1
+        return self.full.num_kv_heads
 
     @property
     def head_dim(self) -> int:
-        return self.mla.head_dim
+        return self.full.head_dim
 
     @property
     def rope_theta(self) -> float:
-        return self.mla.rope_theta
+        return self.full.rope_theta
 
 
-def cache_kind(kind) -> str:
-    return RECURRENT if kind[0] == LINEAR else lb.LATENT
+def _full(spec: BlockSpec):
+    """The module of the spec's full layers: their block and FFN."""
+    return lb if isinstance(spec.full, lb.BlockSpec) else ga
+
+
+def cache_kind(kind, spec: BlockSpec) -> str:
+    if kind[0] == LINEAR:
+        return RECURRENT
+    return _full(spec).cache_kind(kind[1])
 
 
 def expert_layer(kind) -> bool:
@@ -117,11 +143,11 @@ def expert_layer(kind) -> bool:
 
 
 def rope_for(spec: BlockSpec, positions: int):
-    return lb.rope_for(spec.mla, positions)
+    return _full(spec).rope_for(spec.full, positions)
 
 
 def head(x, params, spec: BlockSpec):
-    return lb.head(x, params, spec.mla)
+    return lb.head(x, params, spec.full)
 
 
 # ------------------------------------------------------- linear attention
@@ -242,40 +268,91 @@ def chunked(s, q, k, v, beta, g, chunk: int):
     return o.swapaxes(1, 2).reshape(t + pad, nv, dv)[:t], s
 
 
+def split_plain(qkvz, ba, spec: BlockSpec):
+    """(mixed, z, b, a) from projections that are plain concatenations:
+    qkvz = [q | k | v | z], ba = [b | a]."""
+    nv = spec.num_v_heads
+    return (qkvz[:, :spec.conv_dim], qkvz[:, spec.conv_dim:], ba[:, :nv],
+            ba[:, nv:])
+
+
+def split_grouped(qkvz, ba, spec: BlockSpec):
+    """(mixed [..., conv_dim] = q | k | v, z [..., nv dv], b, a [..., nv])
+    from projections grouped by key head: key head g's columns of qkvz
+    are [q_g (dk) | k_g (dk) | v (r dv) | z (r dv)] and of ba [b (r) | a
+    (r)], r = nv / nk value heads of its own (Qwen3-Next's
+    `fix_query_key_value_ordering`): value head g r + i is the i-th of
+    key head g's."""
+    nk, nv, dk, dv = spec.num_k_heads, spec.num_v_heads, spec.k_dim, \
+        spec.v_dim
+    r, lead = nv // nk, qkvz.shape[:-1]
+    g = qkvz.reshape(lead + (nk, 2 * dk + 2 * r * dv))
+    q, k = g[..., :dk], g[..., dk:2 * dk]
+    v, z = g[..., 2 * dk:2 * dk + r * dv], g[..., 2 * dk + r * dv:]
+    mixed = jnp.concatenate([y.reshape(lead + (-1,)) for y in (q, k, v)],
+                            axis=-1)
+    gba = ba.reshape(lead + (nk, 2 * r))
+    return (mixed, z.reshape(lead + (nv * dv,)),
+            gba[..., :r].reshape(lead + (nv,)),
+            gba[..., r:].reshape(lead + (nv,)))
+
+
+def gate_sigmoid(o, z, w, spec: BlockSpec):
+    """The normed output o [T, nv, dv] float32 times (1 + w) gate_scale
+    sigmoid(z) (z [T, nv dv])."""
+    return o * (w.astype(_F32) + 1.0) * (spec.gate_scale * jax.nn.sigmoid(
+        z.astype(_F32).reshape(o.shape)))
+
+
+def gate_silu(o, z, w, spec: BlockSpec):
+    """The normed output o [T, nv, dv] float32 times w silu(z) (z [T, nv
+    dv]): a plain gain."""
+    return o * w.astype(_F32) * jax.nn.silu(z.astype(_F32).reshape(o.shape))
+
+
+def _add_mixer(x, out, lw, spec: BlockSpec):
+    """x + a linear layer's output as the model's norms have it: through
+    the post-attention norm (sandwich, latent hybrids) or as it is
+    (pre-norm, GQA hybrids)."""
+    if _full(spec) is ga:
+        return (x.astype(_F32) + out.astype(_F32)).astype(x.dtype)
+    return lb.sandwich_add(x, out, lb.gain(lw["ln_post_attn"], spec.full),
+                           spec.eps)
+
+
 def linear_attention(x, lw, spec: BlockSpec, attend):
     """The linear-attention sublayer: x [T, H] -> x1 [T, H]."""
     t = x.shape[0]
     nv, dv, eps = spec.num_v_heads, spec.v_dim, spec.eps
-    h = lb.rms_norm(x, lb.gain(lw["ln_in"], spec.mla), eps)
+    h = lb.rms_norm(x, lb.gain(lw["ln_in"], spec.full), eps)
     with jax.named_scope("attn.linear.proj"):
         # float32 out: the recurrence and the output norm read these
         # projections, and the norm renormalises v - S^T k, a difference
         qkvz = jnp.dot(h, lw["qkvz"], preferred_element_type=_F32)
         ba = jnp.dot(h, lw["ba"], preferred_element_type=_F32)
-    mixed, z = qkvz[:, :spec.conv_dim], qkvz[:, spec.conv_dim:]
+    gqa = _full(spec) is ga
+    mixed, z, b, a = (split_grouped if gqa else split_plain)(qkvz, ba, spec)
     with jax.named_scope("attn.linear"):
-        o = attend(mixed, ba[:, :nv], ba[:, nv:], lw)     # [T, nv, dv]
+        o = attend(mixed, b, a, lw)                        # [T, nv, dv]
     with jax.named_scope("attn.linear.out"):
         o = o.astype(_F32)
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                               + spec.o_eps)
-        o = o * (lw["o_norm"].astype(_F32) + 1.0) * (
-            spec.gate_scale * jax.nn.sigmoid(
-                z.astype(_F32).reshape(t, nv, dv)))
+        o = (gate_silu if gqa else gate_sigmoid)(o, z, lw["o_norm"], spec)
         out = o.reshape(t, nv * dv).astype(x.dtype) @ lw["out"]
-    return lb.sandwich_add(x, out, lb.gain(lw["ln_post_attn"], spec.mla),
-                           eps)
+    return _add_mixer(x, out, lw, spec)
 
 
 def block(x, lw, spec: BlockSpec, kind, attend, rope=None, valid=None):
     """One layer over a block of tokens x [T, H] (`latent_block.block`'s
     contract; `attend` the kind's: see the module's docstring)."""
     attn, ffn = kind
+    mod = _full(spec)
     if attn == FULL:
-        return lb.block(x, lw, spec.mla, ffn, attend, rope=rope,
-                        valid=valid)
+        return mod.block(x, lw, spec.full, ffn, attend, rope=rope,
+                         valid=valid)
     x1 = linear_attention(x, lw, spec, attend)
-    return lb.ffn_sublayer(x1, lw, spec.mla, ffn, valid)
+    return mod.ffn_sublayer(x1, lw, spec.full, ffn, valid)
 
 
 def attend_linear_sequence(spec: BlockSpec, form=chunked):
@@ -298,6 +375,6 @@ def forward_sequence(params, ids, spec: BlockSpec, form=chunked):
     tables = tuple(jnp.asarray(y) for y in rope_for(spec, ids.shape[0]))
     for lw, kind in zip(params["layers"], spec.layer_types):
         attend = (attend_linear_sequence(spec, form) if kind[0] == LINEAR
-                  else lb.attend_sequence(spec.mla))
+                  else _full(spec).attend_sequence(spec.full))
         x, *_ = block(x, lw, spec, kind, attend, rope=tables)
     return head(x, params, spec)

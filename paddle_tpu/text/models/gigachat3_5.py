@@ -6,8 +6,10 @@ SwiGLU and expert layers after them (sigmoid top-k over all experts,
 normalised and scaled by `routed_scaling_factor`, one shared expert), every
 SwiGLU clamped at `swiglu_limit`, and a head untied from the embedding.
 
-The Layer holds the parameters; the mathematics is
-`gated_delta_block.py`'s (and, for the MLA layers, `latent_block.py`'s
+The Layer holds the parameters (a linear layer's are
+`qwen3_next.GatedDeltaNetWeights`, zero-centred); the mathematics is the
+hybrid block's, `gated_delta_block.py` with a `latent_block.BlockSpec`
+for the full layers (`latent_block.py`'s
 with GigaChat's options: interleaved YaRN RoPE, the mscale^2 softmax
 factor, the gated attention output), which the serving engine's programs
 call too. The expert layers may be one chip's share of an expert-parallel
@@ -33,12 +35,11 @@ import numpy as np
 
 from ... import nn
 from ...core.dispatch import op_call
-from ...core.rng import next_key
-from ...nn.initializer import Initializer
 from . import gated_delta_block as gd
 from . import latent_block as lb
 from .pangu_ultra_moe import (PanguUltraMoESparseMoe, _attention, _gain,
                               _Weights)
+from .qwen3_next import GatedDeltaNetWeights
 
 
 def _yarn():
@@ -141,7 +142,7 @@ class GigaChat35Config:
         yarn = () if not r else (
             float(r["factor"]), int(r["original_max_position_embeddings"]),
             float(r["beta_fast"]), float(r["beta_slow"]))
-        mla = lb.BlockSpec(
+        full = lb.BlockSpec(
             hidden_size=self.hidden_size,
             num_heads=self.num_attention_heads,
             qk_nope_dim=self.qk_nope_head_dim,
@@ -159,7 +160,7 @@ class GigaChat35Config:
             gated_attention=True, zero_centred=True,
             swiglu_limit=float(self.swiglu_limit))
         return gd.BlockSpec(
-            mla=mla, attn_types=self.attn_types,
+            full=full, attn_types=self.attn_types,
             num_k_heads=self.linear_num_key_heads,
             num_v_heads=self.linear_num_value_heads,
             k_dim=self.linear_key_head_dim,
@@ -167,37 +168,6 @@ class GigaChat35Config:
             conv_kernel=self.linear_conv_kernel_dim,
             o_eps=float(self.linear_attn_o_norm_eps),
             gate_scale=float(self.linear_sigmoid_gate_scale))
-
-
-class _LogUniform(Initializer):
-    """log U(low, high): Qwen3-Next's initialiser of `A_log`."""
-
-    def __init__(self, low, high):
-        self.low, self.high = low, high
-
-    def _generate(self, shape, dtype):
-        return jnp.log(jax.random.uniform(next_key(), shape, jnp.float32,
-                                          self.low, self.high)).astype(dtype)
-
-
-class _LinearAttention(_Weights):
-    """A Gated DeltaNet layer's parameters, matrices [in, out]; `conv1d`
-    is [K, channels] (tap j multiplies the input K - 1 - j positions
-    back); `A_log` = log U(1, 16) and `dt_bias` 0 (Qwen3-Next's
-    initialiser), `norm` the zero-centred output norm's w."""
-
-    def __init__(self, c: GigaChat35Config):
-        nk, nv = c.linear_num_key_heads, c.linear_num_value_heads
-        dk, dv = c.linear_key_head_dim, c.linear_value_head_dim
-        super().__init__({
-            "in_proj_qkvz": (c.hidden_size, 2 * nk * dk + 2 * nv * dv),
-            "in_proj_ba": (c.hidden_size, 2 * nv),
-            "conv1d": (c.linear_conv_kernel_dim, 2 * nk * dk + nv * dv),
-            "dt_bias": (nv,), "norm": (dv,),
-            "out_proj": (nv * dv, c.hidden_size)}, c,
-            zeros=("dt_bias", "norm"))
-        self.A_log = self.create_parameter(
-            [nv], dtype=c.dtype, default_initializer=_LogUniform(1.0, 16.0))
 
 
 class GigaChat35DecoderLayer(nn.Layer):
@@ -212,7 +182,8 @@ class GigaChat35DecoderLayer(nn.Layer):
         if kind[0] == gd.FULL:
             self.self_attn = _attention(c, zero_centred=True, gated=True)
         else:
-            self.linear_attn = _LinearAttention(c)
+            self.linear_attn = GatedDeltaNetWeights(
+                c, zeros=("dt_bias", "norm"))
         if kind[1] == lb.DENSE:
             f = c.intermediate_size
             self.mlp = _Weights({"gate_proj": (h, f), "up_proj": (h, f),
@@ -236,11 +207,7 @@ class GigaChat35DecoderLayer(nn.Layer):
                        o=get(a.o_proj))
             out["attn_gate"] = get(a.gate_proj)
         else:
-            w = self.linear_attn
-            out.update(qkvz=get(w.in_proj_qkvz), ba=get(w.in_proj_ba),
-                       conv=get(w.conv1d), A_log=get(w.A_log),
-                       dt_bias=get(w.dt_bias), o_norm=get(w.norm),
-                       out=get(w.out_proj))
+            out.update(self.linear_attn.arrays(get))
         if self.kind[1] == lb.DENSE:
             out.update(gate=get(m.gate_proj), up=get(m.up_proj),
                        down=get(m.down_proj))

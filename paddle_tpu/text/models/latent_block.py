@@ -113,7 +113,7 @@ class BlockSpec:
             * self.mscale ** 2
 
 
-def cache_kind(kind: str) -> str:
+def cache_kind(kind: str, spec=None) -> str:
     """The layer state a layer of FFN kind `kind` keeps."""
     return LATENT
 

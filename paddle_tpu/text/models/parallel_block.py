@@ -52,7 +52,7 @@ class BlockSpec:
         return self.head_dim
 
 
-def cache_kind(kind: str) -> str:
+def cache_kind(kind: str, spec=None) -> str:
     """The layer state a layer of attention kind `kind` keeps: its own
     name (full history, or a window)."""
     return kind

@@ -168,6 +168,19 @@ def test_paged_decode_two_kinds(for_chip, kind):
     assert ("paged_window_decode" in text) == windowed
 
 
+def test_paged_decode_hybrid(for_chip):
+    """What qwen3-next-80b-a3b-instruct.serve-longctx's full layer runs a
+    decode tick: 16 query heads on 2 KV heads at head_dim 256 (8 a KV
+    head), 16 slots x 1024 pages of 128 positions (131,072 a slot)."""
+    from paddle_tpu.ops.pallas_decode import paged_decode_attention_raw
+
+    cache = ((1 + 16 * 1024, 2, 128, 256), BF16)
+    text = for_chip(paged_decode_attention_raw, ((16, 16, 256), BF16),
+                    cache, cache, ((16, 1024), jnp.int32),
+                    ((16,), jnp.int32)).as_text()
+    assert "paged_decode" in text
+
+
 def test_paged_latent_decode(for_chip):
     """What openpangu-ultra-moe-718b.serve-longdoc runs: 128 query heads
     on ONE cached row of 640 (512 latent + 64 rotary + padding), 16 slots
@@ -220,11 +233,12 @@ def test_gated_delta_decode(for_chip, monkeypatch):
     assert pgd.head_group(64, 128, 128) == 32
 
 
-# (hidden, FFN width, held experts) of the three sparse cells' models:
+# (hidden, FFN width, held experts) of the four sparse cells' models:
 # command-a-plus-05-2026 (rank 0 of 8), openpangu-ultra-moe-718b and
-# gigachat3.5-432b-a28b (rank 0 of 32)
+# gigachat3.5-432b-a28b (rank 0 of 32), qwen3-next-80b-a3b-instruct (rank
+# 0 of 2: 256 held, 512 wide)
 HELD_EXPERTS = {"cohere": (4096, 4096, 16), "pangu": (7680, 2048, 8),
-                "gigachat": (7168, 2048, 8)}
+                "gigachat": (7168, 2048, 8), "qwen3next": (2048, 512, 256)}
 
 
 @pytest.mark.parametrize("rows", [16, 64, 512],

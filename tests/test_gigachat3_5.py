@@ -213,8 +213,8 @@ def test_a_planted_fault_is_seen_at_this_size(monkeypatch, fault,
         spec = model.config.block_spec()
         monkeypatch.setattr(
             type(model.config), "block_spec",
-            lambda self: dataclasses.replace(spec, mla=dataclasses.replace(
-                spec.mla, gated_attention=False)))
+            lambda self: dataclasses.replace(spec, full=dataclasses.replace(
+                spec.full, gated_attention=False)))
     fresh_programs()                # the planted fault is traced anew
     out = np.asarray(model(paddle.to_tensor(ids))._data)[0]
     assert np.abs(out - ref).max() > 1e-2
@@ -227,7 +227,7 @@ def test_yarn_and_the_softmax_factor():
     from paddle_tpu.text.models import GigaChat35Config
 
     c = GigaChat35Config(num_hidden_layers=4, full_attention_layers=(3,))
-    spec = c.block_spec().mla
+    spec = c.block_spec().full
     assert spec.rope_yarn == (8.0, 32768, 32.0, 1.0)
     assert spec.scale == pytest.approx((0.1 * np.log(8) + 1) ** 2
                                        / np.sqrt(192))
@@ -315,7 +315,7 @@ def test_the_model_is_built_lazily_and_names_its_parameters():
                                 ("linear_attention", "experts"),
                                 ("linear_attention", "experts"),
                                 ("full_attention", "experts"))
-    assert [gd.cache_kind(k) for k in spec.layer_types] == [
+    assert [gd.cache_kind(k, spec) for k in spec.layer_types] == [
         "recurrent"] * 3 + ["latent"]
     assert spec.state_shape == (4, 16, 16) and spec.conv_shape == (3, 128)
     assert params["model.layers.0.linear_attn.in_proj_qkvz"].shape == [
